@@ -17,7 +17,10 @@ MAX_AVATARS_PER_SPACE = 100
 
 class MySpace(Space):
     def on_space_init(self):
-        self.enable_aoi(AOI_DISTANCE)
+        # sized once for its avatars and their monsters: a device AOI
+        # bucket then never regrows, and each capacity it grows through
+        # is another program to compile in the middle of the tick loop
+        self.enable_aoi(AOI_DISTANCE, capacity=3 * MAX_AVATARS_PER_SPACE)
 
     def on_entity_enter_space(self, e):
         if e.type_name == "Player":

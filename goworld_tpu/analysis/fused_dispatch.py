@@ -14,7 +14,7 @@ A/B: the fused row keeps winning on dispatch COUNT while losing the
 wall-clock it was built to reclaim.
 
 Entry points walked (the shared ProjectIndex call graph -- index.py --
-one sync taxonomy shared with host-sync and flush-phase):
+one sync classification shared with host-sync and flush-phase):
 
 * every module function of ops/aoi_fused.py (the fused programs and
   their lazy impl builders);

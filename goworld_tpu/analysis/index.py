@@ -260,7 +260,7 @@ class ProjectIndex:
 # -- the shared no-host-sync call-graph walk ---------------------------------
 
 def sync_msg(node: ast.Call) -> str | None:
-    """The host-sync detection (one taxonomy: host-sync, flush-phase,
+    """The host-sync detection (one classification: host-sync, flush-phase,
     fused-dispatch all agree on what a blocking fetch is)."""
     name = call_name(node)
     if name in _SYNC_CALLS:

@@ -73,10 +73,11 @@ def _read_pids(rundir: str) -> dict[str, int]:
     return out
 
 
-def _spawn(rundir: str, name: str, argv: list[str]) -> tuple[int, int]:
-    """Returns (pid, log_offset): the log size before this process appends,
-    so readiness watching ignores tags left by previous runs in the same
-    rundir."""
+def _spawn(rundir: str, name: str,
+           argv: list[str]) -> tuple[subprocess.Popen, int]:
+    """Returns (process, log_offset): the log size before this process
+    appends, so readiness watching ignores tags left by previous runs in
+    the same rundir."""
     path = _logfile(rundir, name)
     offset = os.path.getsize(path) if os.path.exists(path) else 0
     log = open(path, "ab")
@@ -86,16 +87,23 @@ def _spawn(rundir: str, name: str, argv: list[str]) -> tuple[int, int]:
     )
     with open(_pidfile(rundir, name), "w") as f:
         f.write(str(proc.pid))
-    return proc.pid, offset
+    return proc, offset
 
 
-def _wait_ready(rundir: str, name: str, offset: int = 0,
-                timeout: float = 30.0) -> bool:
+# A game reports ready only after its AOI engine booted, and with
+# aoi_backend=tpu that includes importing JAX and starting the TPU.
+GAME_READY_S = 120.0
+
+
+def _wait_ready(rundir: str, name: str, proc: subprocess.Popen,
+                offset: int = 0, timeout: float = 30.0) -> bool:
     """Watch the component's log (past ``offset``) for the readiness tag.
-    Only content this run appended counts -- logs accumulate across runs."""
+    Only content this run appended counts -- logs accumulate across runs.
+    A component that exits before its tag fails at once, not at timeout."""
     path = _logfile(rundir, name)
     deadline = time.time() + timeout
     while time.time() < deadline:
+        dead = proc.poll() is not None
         try:
             with open(path, "rb") as f:
                 f.seek(offset)
@@ -103,14 +111,24 @@ def _wait_ready(rundir: str, name: str, offset: int = 0,
                     return True
         except OSError:
             pass
+        if dead:
+            return False
         time.sleep(0.05)
     return False
 
 
-def _fail_and_teardown(rundir: str, what: str) -> int:
-    """A component never became ready: kill everything already spawned so a
-    retried start doesn't stack duplicate processes on the same ports."""
-    print(f"{what}; tearing down partial cluster", file=sys.stderr)
+def _fail_and_teardown(rundir: str, name: str) -> int:
+    """A component never became ready: show the end of its log, then kill
+    everything already spawned so a retried start doesn't stack duplicate
+    processes on the same ports."""
+    try:
+        with open(_logfile(rundir, name), "rb") as f:
+            tail = f.read()[-2000:].decode("utf-8", "replace")
+        print(f"--- end of {name}.log ---\n{tail}", file=sys.stderr)
+    except OSError:
+        pass
+    print(f"{name} failed to become ready; tearing down partial cluster",
+          file=sys.stderr)
     _signal_kind(rundir, "gate", signal.SIGTERM)
     _signal_kind(rundir, "game", signal.SIGTERM)
     _signal_kind(rundir, "dispatcher", signal.SIGTERM)
@@ -130,33 +148,37 @@ def cmd_start(args) -> int:
         return 1
     py = sys.executable
 
-    offsets: dict[str, int] = {}
+    spawned: dict[str, tuple[subprocess.Popen, int]] = {}
     for i in cfg.dispatchers:
         name = f"dispatcher{i}"
-        _pid, offsets[name] = _spawn(
+        spawned[name] = _spawn(
             args.dir, name, [py, "-m", "goworld_tpu.components.dispatcher",
                              "-dispid", str(i), "-configfile", config_abs])
     for i in cfg.dispatchers:
-        if not _wait_ready(args.dir, f"dispatcher{i}", offsets[f"dispatcher{i}"]):
-            return _fail_and_teardown(args.dir, f"dispatcher{i} failed to become ready")
+        name = f"dispatcher{i}"
+        if not _wait_ready(args.dir, name, *spawned[name]):
+            return _fail_and_teardown(args.dir, name)
     for i in cfg.games:
         name = f"game{i}"
         argv = [py, "-m", "goworld_tpu.components.game", "-gid", str(i),
                 "-configfile", config_abs, "-script", script_abs, "-dir", "."]
         if args.restore:
             argv.append("-restore")
-        _pid, offsets[name] = _spawn(args.dir, name, argv)
+        spawned[name] = _spawn(args.dir, name, argv)
     for i in cfg.games:
-        if not _wait_ready(args.dir, f"game{i}", offsets[f"game{i}"]):
-            return _fail_and_teardown(args.dir, f"game{i} failed to become ready")
+        name = f"game{i}"
+        if not _wait_ready(args.dir, name, *spawned[name],
+                           timeout=GAME_READY_S):
+            return _fail_and_teardown(args.dir, name)
     for i in cfg.gates:
         name = f"gate{i}"
-        _pid, offsets[name] = _spawn(
+        spawned[name] = _spawn(
             args.dir, name, [py, "-m", "goworld_tpu.components.gate",
                              "-gateid", str(i), "-configfile", config_abs])
     for i in cfg.gates:
-        if not _wait_ready(args.dir, f"gate{i}", offsets[f"gate{i}"]):
-            return _fail_and_teardown(args.dir, f"gate{i} failed to become ready")
+        name = f"gate{i}"
+        if not _wait_ready(args.dir, name, *spawned[name]):
+            return _fail_and_teardown(args.dir, name)
     print(f"cluster up: {len(cfg.dispatchers)} dispatcher(s), "
           f"{len(cfg.games)} game(s), {len(cfg.gates)} gate(s)")
     return 0
@@ -229,16 +251,17 @@ def cmd_reload(args) -> int:
     config_abs = os.path.abspath(args.config)
     script_abs = os.path.abspath(args.script)
     py = sys.executable
-    offsets: dict[str, int] = {}
+    spawned: dict[str, tuple[subprocess.Popen, int]] = {}
     for i in cfg.games:
         name = f"game{i}"
-        _pid, offsets[name] = _spawn(
+        spawned[name] = _spawn(
             args.dir, name,
             [py, "-m", "goworld_tpu.components.game", "-gid", str(i),
              "-configfile", config_abs, "-script", script_abs,
              "-dir", ".", "-restore"])
     for i in cfg.games:
-        if not _wait_ready(args.dir, f"game{i}", offsets[f"game{i}"]):
+        if not _wait_ready(args.dir, f"game{i}", *spawned[f"game{i}"],
+                           timeout=GAME_READY_S):
             print(f"game{i} failed to restore", file=sys.stderr)
             return 1
     print("reload complete")

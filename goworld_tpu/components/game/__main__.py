@@ -19,6 +19,7 @@ import sys
 import threading
 
 from ... import config as gwconfig
+from ...chip import use_compile_cache
 from ...utils import gwlog
 from .service import GameService
 
@@ -40,6 +41,7 @@ def main(argv=None, default_script: str | None = None):
     ap.add_argument("-log", default="info")
     ap.add_argument("-dir", default=".", help="runtime dir (freeze files, storage)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     script = args.script or default_script
     if not script:
         ap.error("-script is required")

@@ -16,10 +16,13 @@ Example (tests/ and examples/ ship real ones):
     port = 16001
 
     [game_common]
-    aoi_backend = tpu
+    aoi_backend = cpp
     position_sync_interval_ms = 100
 
+    # one game per TPU chip: a second tpu game on a one-chip host fails
+    # at boot (the chip belongs to one process)
     [game1]
+    aoi_backend = tpu
     [game2]
 
     [gate1]

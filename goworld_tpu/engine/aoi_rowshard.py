@@ -55,7 +55,6 @@ from ..ops import aoi_emit as AE
 from .aoi import (_Bucket, _CapDecay, _build_snapshot, _device_fault,
                   _emit_expand, _kernelish_fault, _packed_predicate,
                   _paged_absorb_chip, _unpack_positions)
-from ..parallel.compat import shard_map
 
 _LANES = 128
 
@@ -214,7 +213,6 @@ class _RowShardTPUBucket(_Bucket):
             from jax.sharding import PartitionSpec as PS
 
             from ..ops.aoi_stage import delta_scatter_1d
-            from ..parallel.compat import shard_map
 
             cl = self.c_local
             axis = self.mesh.axis
@@ -227,7 +225,7 @@ class _RowShardTPUBucket(_Bucket):
                 return xs, zs, xr, zr
 
             spec, rep = PS(axis), PS()
-            local = shard_map(_local, mesh=self.mesh.mesh,
+            local = jax.shard_map(_local, mesh=self.mesh.mesh,
                               in_specs=(spec, spec, rep, rep, rep, rep, rep),
                               out_specs=(spec, spec, rep, rep),
                               check_vma=False)
@@ -362,7 +360,7 @@ class _RowShardTPUBucket(_Bucket):
                             sub)
                 return out + (xs, zs, xr, zr)
 
-            local = shard_map(
+            local = jax.shard_map(
                 _local,
                 mesh=self.mesh.mesh,
                 in_specs=(spec,) * 10 + (rep,) * 7,
@@ -372,7 +370,7 @@ class _RowShardTPUBucket(_Bucket):
             fn = jax.jit(local,
                          donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 10, 11))
         else:
-            local = shard_map(
+            local = jax.shard_map(
                 _body,
                 mesh=self.mesh.mesh,
                 in_specs=(spec,) * 10 + (rep, rep, rep, rep),
@@ -414,7 +412,7 @@ class _RowShardTPUBucket(_Bucket):
 
         spec = PS(self.mesh.axis)
         rep = PS()
-        local = shard_map(
+        local = jax.shard_map(
             _local, mesh=self.mesh.mesh,
             in_specs=(spec, rep, rep, rep), out_specs=spec,
             check_vma=False)

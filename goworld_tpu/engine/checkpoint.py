@@ -829,6 +829,10 @@ def crash_restart_scenario(base_dir: str, cap: int = 256,
               "--world", str(world), "--tier", tier, "--mode", mode,
               "--interval", str(interval), "--seed", str(seed)]
     env = dict(os.environ)
+    if tier != "tpu":
+        # host tiers import JAX but must never reach for the chip, which
+        # the caller may hold (one process per chip)
+        env["JAX_PLATFORMS"] = "cpu"
     rc_oracle = subprocess.run(
         common + ["--journal", oracle_j, "--no-checkpoint"],
         env=env).returncode
@@ -872,4 +876,7 @@ def crash_restart_scenario(base_dir: str, cap: int = 256,
 
 
 if __name__ == "__main__":
+    from ..chip import use_compile_cache
+
+    use_compile_cache()
     raise SystemExit(_driver())

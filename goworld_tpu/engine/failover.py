@@ -335,7 +335,11 @@ def host_failover_scenario(base_dir: str, cap: int = 48,
                  "--game-id", str(gid), "--space", spaces[gid],
                  "--cap", str(cap), "--ticks", str(ticks), "--tier", tier,
                  "--ckpt-dir", ck_dir, "--journal-dir", j_dirs[gid]],
-                env={**os.environ, **(worker_env or {})})
+                env={**os.environ,
+                     # host tiers import JAX but must never reach for the
+                     # chip, which the caller may hold (one per chip)
+                     **({} if tier == "tpu" else {"JAX_PLATFORMS": "cpu"}),
+                     **(worker_env or {})})
         if not _poll(lambda: len(disp.entities) >= 2 * cap, 60.0):
             raise RuntimeError("workers failed to register")
         gate = GWConnection(PacketConnection(
@@ -428,6 +432,9 @@ def host_failover_scenario(base_dir: str, cap: int = 48,
 
 
 if __name__ == "__main__":
+    from ..chip import use_compile_cache
+
+    use_compile_cache()
     if "--worker" in sys.argv[1:]:
         sys.exit(_worker_main(sys.argv[1:]))
     import argparse

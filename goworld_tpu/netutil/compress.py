@@ -13,17 +13,12 @@ Available codecs:
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 import zlib
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
-# GW_SANITIZED_NATIVE=1 loads the ASAN+UBSAN build (make sanitize) instead
-_GWLZ_SO_NAME = ("libgwlz.san.so"
-                 if os.environ.get("GW_SANITIZED_NATIVE") == "1"
-                 else "libgwlz.so")
-_SO_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, _GWLZ_SO_NAME))
+from ..utils import native
+
+_GWLZ_SO_NAME = native.so_name("libgwlz")
 
 _build_lock = threading.Lock()
 _gwlz = None
@@ -179,24 +174,19 @@ class LZWCompressor(Compressor):
 def _load_gwlz():
     """Load (building if needed) the native codec; None if unavailable."""
     global _gwlz, _gwlz_tried
-    if _gwlz is not None or _gwlz_tried:
+    if _gwlz is not None:
         return _gwlz
+    # _gwlz_tried is read under the lock only: the attempt (a make run) holds the
+    # lock throughout, so a thread that finds _gwlz_tried set there sees its result
     with _build_lock:
         if _gwlz is not None or _gwlz_tried:
             return _gwlz
         _gwlz_tried = True
-        if not os.path.exists(_SO_PATH):
-            try:
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR, "-s", _GWLZ_SO_NAME],
-                    check=True,
-                    capture_output=True,
-                    timeout=120,
-                )
-            except Exception:
-                return None
+        path = native.build(_GWLZ_SO_NAME)
+        if path is None:
+            return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(path)
         except OSError:
             return None
         lib.gwlz_max_compressed.restype = ctypes.c_size_t

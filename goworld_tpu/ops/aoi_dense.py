@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from .aoi_predicate import WORD_BITS, words_per_row
+from ..chip import interpret_for
 
 
 def interest_words_dense(x, z, radius, active):
@@ -92,8 +93,8 @@ def aoi_step_chg(x, z, radius, active, prev_words, cols=None, row_ids=None,
     On TPU -> the Pallas kernel; anywhere else -> this module's dense
     formulation (one fused XLA program -- interpret-mode Pallas walks its
     grid step-by-step in Python).  ``platform`` defaults to
-    ``jax.default_backend()``; mesh callers pass their mesh's platform
-    (which may differ from the default under a pinned dryrun)."""
+    ``jax.default_backend()`` and decides the kernel's interpret mode too,
+    so a compile for a described TPU from a CPU process reaches Mosaic."""
     if platform is None:
         platform = jax.default_backend()
     if platform != "tpu":
@@ -102,4 +103,5 @@ def aoi_step_chg(x, z, radius, active, prev_words, cols=None, row_ids=None,
     from .aoi_pallas import aoi_step_pallas
 
     return aoi_step_pallas(x, z, radius, active, prev_words, emit="chg",
-                           cols=cols, row_ids=row_ids)
+                           cols=cols, row_ids=row_ids,
+                           interpret=interpret_for(platform))

@@ -29,12 +29,11 @@ rejects any blocking device fetch.
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 
 import numpy as np
 
+from ..utils import native
 from .aoi_predicate import words_per_row
 
 EMIT_MODES = ("native", "vector", "host")
@@ -42,14 +41,7 @@ EMIT_MODES = ("native", "vector", "host")
 # demoted (native 0 -> vector 1 -> host 2)
 EMIT_LEVEL = {"native": 0, "vector": 1, "host": 2}
 
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native",
-)
-_SO_NAME = ("libgwemit.san.so"
-            if os.environ.get("GW_SANITIZED_NATIVE") == "1"
-            else "libgwemit.so")
-_SO_PATH = os.path.join(_NATIVE_DIR, _SO_NAME)
+_SO_NAME = native.so_name("libgwemit")
 _lib = None
 _tried = False
 _build_lock = threading.Lock()
@@ -57,22 +49,19 @@ _build_lock = threading.Lock()
 
 def _load():
     global _lib, _tried
-    if _lib is not None or _tried:
+    if _lib is not None:
         return _lib
+    # _tried is read under the lock only: the attempt (a make run) holds the
+    # lock throughout, so a thread that finds _tried set there sees its result
     with _build_lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO_PATH):
-            try:
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR, "-s", _SO_NAME],
-                    check=True, capture_output=True, timeout=120,
-                )
-            except Exception:
-                return None
+        path = native.build(_SO_NAME)
+        if path is None:
+            return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(path)
         except OSError:
             return None
         u32p = ctypes.POINTER(ctypes.c_uint32)

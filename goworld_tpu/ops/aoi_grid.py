@@ -40,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .aoi_predicate import WORD_BITS, words_per_row
+from ..chip import interpret_for
 
 _INF = float("inf")
 
@@ -124,7 +125,7 @@ def _legal_blocks(c, w, block_rows, col_words, interpret):
     while w % wb:
         wb //= 2
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_for()
     if not interpret and wb < 128:
         # Mosaic lane rule: the column/out blocks ride the lane dim, so the
         # word window must be >= 128 -- i.e. this kernel needs W >= 128

@@ -15,24 +15,14 @@ whether the library could be loaded; callers fall back to the Python oracle.
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 
 import numpy as np
 
+from ..utils import native
 from . import aoi_predicate as P
 
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native",
-)
-# GW_SANITIZED_NATIVE=1 loads the ASAN+UBSAN build (make sanitize) instead
-# -- the sanitizer harness runs the same python callers against it
-_SO_NAME = ("libgwaoi.san.so"
-            if os.environ.get("GW_SANITIZED_NATIVE") == "1"
-            else "libgwaoi.so")
-_SO_PATH = os.path.join(_NATIVE_DIR, _SO_NAME)
+_SO_NAME = native.so_name("libgwaoi")
 _lib = None
 _tried = False
 _build_lock = threading.Lock()
@@ -40,22 +30,19 @@ _build_lock = threading.Lock()
 
 def _load():
     global _lib, _tried
-    if _lib is not None or _tried:
+    if _lib is not None:
         return _lib
+    # _tried is read under the lock only: the attempt (a make run) holds the
+    # lock throughout, so a thread that finds _tried set there sees its result
     with _build_lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO_PATH):
-            try:
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR, "-s", _SO_NAME],
-                    check=True, capture_output=True, timeout=120,
-                )
-            except Exception:
-                return None
+        path = native.build(_SO_NAME)
+        if path is None:
+            return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(path)
         except OSError:
             return None
         f32p = ctypes.POINTER(ctypes.c_float)

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .aoi_predicate import WORD_BITS, words_per_row
+from ..chip import interpret_for
 
 _INF = float("inf")
 
@@ -208,7 +209,7 @@ def aoi_step_pallas(x, z, radius, active, prev_words, *, block_rows=128,
         if ti == 0 or c_rows % ti != 0:
             ti = c_rows
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_for()
 
     # Fold activity into coordinates/radius (exact; see module docstring).
     # The [S, 1, C] layout keeps every block's trailing dims either equal to

@@ -389,8 +389,8 @@ def extract_triples(chg, new, capacity: int, max_triples: int):
     Two-pass compaction sized by an exact popcount (NOT a silent cap):
     pass 1 compacts the nonzero WORDS of the flat change grid (there are at
     most ``count`` of them, so the same ``max_triples`` budget covers both
-    passes on every non-overflow tick); pass 2 expands the surviving words
-    into a [max_triples, 32] bit matrix and compacts the set BITS.  When
+    passes on every non-overflow tick); pass 2 finds, for each output row,
+    the surviving word and bit holding the set BIT of that rank.  When
     ``count > max_triples`` the triple buffer is incomplete and the caller
     must fall back (a counted, per-tick event -- bucket ``decode_overflow``
     stat), which is why the dropped pass-1 words never matter.
@@ -416,18 +416,32 @@ def extract_triples(chg, new, capacity: int, max_triples: int):
     wsel = jnp.maximum(widx, 0)
     wvals = jnp.where(widx >= 0, flat_c[wsel], jnp.uint32(0))
     nvals = jnp.where(widx >= 0, flat_n[wsel], jnp.uint32(0))
+    # pass 2: row r of the output is the bit whose rank among all set bits
+    # in (word, bit) order is r: its word is found by a binary search of
+    # the words' running popcount, its bit as the one with rank
+    # r - (bits in earlier words) inside that word.  Gathers over
+    # [max_triples] and [max_triples, 32] only.  (A nonzero over the
+    # [max_triples, 32] bit matrix gives the same rows, but its prefix sum
+    # over 32x the elements cost the TPU compiler ~20 s per cap size,
+    # stalling a served game's tick loop.)
     shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)[None, :]
-    bits = (wvals[:, None] >> shifts) & jnp.uint32(1)
-    (sel,) = jnp.nonzero(bits.reshape(-1) != 0, size=max_triples,
-                         fill_value=-1)
-    sp = jnp.maximum(sel, 0)
-    slot = sp // WORD_BITS
-    k = (sp % WORD_BITS).astype(jnp.uint32)
+    pc = jax.lax.population_count(wvals).astype(jnp.int32)
+    end = jnp.cumsum(pc)
+    r = jnp.arange(max_triples, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(end, r, side="right"),
+                       max_triples - 1)
+    wv = wvals[slot]
+    nth = r - (end[slot] - pc[slot])
+    below = jax.lax.population_count(
+        wv[:, None] & ((jnp.uint32(1) << shifts) - jnp.uint32(1)))
+    hit = (((wv[:, None] >> shifts) & jnp.uint32(1)) != 0) \
+        & (below.astype(jnp.int32) == nth[:, None])
+    k = jnp.argmax(hit, axis=1).astype(jnp.uint32)
     g = widx[slot]
     obs = g // w
     j = k.astype(jnp.int32) * w + g % w
     kind = ((nvals[slot] >> k) & jnp.uint32(1)).astype(jnp.int32)
-    valid = sel >= 0
+    valid = r < end[-1]
     tri = jnp.stack([jnp.where(valid, obs, -1),
                      jnp.where(valid, j, -1),
                      jnp.where(valid, kind, -1)], axis=1).astype(jnp.int32)

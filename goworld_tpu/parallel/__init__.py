@@ -1,4 +1,3 @@
 """Multi-chip space sharding over a jax device mesh."""
 
-from .compat import shard_map  # noqa: F401
 from .mesh import SpaceMesh, make_sharded_aoi_step, multichip_devices  # noqa: F401

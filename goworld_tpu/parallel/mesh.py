@@ -23,43 +23,23 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from ..ops.aoi_pallas import aoi_step_pallas
 from ..ops.aoi_dense import aoi_step_dense_batched
-from .compat import shard_map
+from ..chip import interpret_for
 
 
 def multichip_devices(n: int | None = None):
-    """Devices for a space mesh: the default backend if it has enough chips,
-    else the host-CPU backend (8 virtual devices under
-    ``--xla_force_host_platform_device_count=8`` -- the single-real-chip dev
-    setup).  ``n=None`` means "as many as the default backend offers"."""
-    def _cpu_devices():
-        try:
-            return jax.devices("cpu")
-        except Exception:
-            # A JAX_PLATFORMS entry whose plugin failed to load poisons every
-            # backend query; dropping to the host platform alone recovers the
-            # virtual-device dryrun path.
-            try:
-                jax.config.update("jax_platforms", "cpu")
-                return jax.devices("cpu")
-            except Exception:
-                return []
-
-    try:
-        devs = jax.devices()
-    except Exception:
-        # Default backend failed to initialize (e.g. a libtpu/plugin mismatch
-        # in a CPU-only dryrun container) -- fall through to the CPU backend.
-        devs = []
+    """The first ``n`` devices of JAX's default backend (all of them for
+    ``n=None``).  Too few is an error, never a drop to host CPU devices:
+    virtual CPU devices come only from a process that pinned
+    ``JAX_PLATFORMS=cpu`` with ``--xla_force_host_platform_device_count``
+    (the tests and the dryrun), where they ARE the default backend."""
+    devs = jax.devices()
     if n is None:
-        return devs if devs else _cpu_devices()
-    if len(devs) >= n:
-        return devs[:n]
-    cpu = _cpu_devices()
-    if len(cpu) >= n:
-        return cpu[:n]
-    raise RuntimeError(
-        f"need {n} devices; default backend has {len(devs)}, cpu has {len(cpu)}"
-    )
+        return devs
+    if len(devs) < n:
+        raise RuntimeError(
+            f"a {n}-device mesh needs {n} devices; the default backend "
+            f"({devs[0].platform}) has {len(devs)}")
+    return devs[:n]
 
 
 class SpaceMesh:
@@ -108,9 +88,7 @@ def make_sharded_aoi_step(space_mesh: SpaceMesh, *, use_pallas: bool = True,
     """
     mesh = space_mesh.mesh
     axis = space_mesh.axis
-    # Interpret must follow the MESH's platform, not the default backend --
-    # a cpu mesh under a tpu-default process still needs interpret mode.
-    interpret = space_mesh.platform != "tpu"
+    interpret = interpret_for(space_mesh.platform)
 
     def _kernel(x, z, r, act, prev):
         if use_pallas:
@@ -157,7 +135,7 @@ def make_sharded_aoi_step(space_mesh: SpaceMesh, *, use_pallas: bool = True,
         ev_spec = (spec, spec, spec, spec, spec)
         out_specs = (spec, ev_spec, ev_spec, PS())
 
-    step = shard_map(
+    step = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec),

@@ -49,7 +49,7 @@ def accelerator_absent() -> bool:
 
 def _accelerator_collect() -> list[Sample]:
     # always-on (registered at import, served even with telemetry off):
-    # the "no accelerator since BENCH_r04" condition must be scrapeable
+    # a process running without its accelerator must be scrapeable
     # from /debug/metrics, not just a stdout banner (docs/observability.md)
     return [Sample("accelerator_absent", "gauge",
                    1.0 if accelerator_absent() else 0.0,

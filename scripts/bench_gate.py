@@ -17,9 +17,9 @@ result) and gates each metric series on its LATEST run:
   "recovery"``, e.g. ticks-to-recover) are lower-is-better and regress
   when ``latest > previous / threshold``.
 * Thresholds are pinned per config below -- noise is a property of the
-  config, not of the gate run.  The pins are calibrated so the real
-  r01-r09 history passes; a synthetic halved record must fail
-  (tests/test_cluster_trace.py exercises both).
+  config, not of the gate run.  A green history must pass and a
+  synthetic halved record must fail (tests/test_cluster_trace.py
+  exercises both).
 
 Exit 0: no regression (or nothing comparable).  Exit 1: regression(s),
 one line each.  ``--json`` dumps the full comparison table for tooling.

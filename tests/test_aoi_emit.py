@@ -334,3 +334,35 @@ def test_fanout_triples_vector_matches_host_expansion():
         xe, xl = AE.expand_words_native(chg_vals, ent_vals, gidx, cap)
         np.testing.assert_array_equal(xe, we)
         np.testing.assert_array_equal(xl, wl)
+
+
+@pytest.mark.parametrize("max_triples", [4096, 256], ids=["fits", "capped"])
+def test_extract_triples_matches_loop_reference(max_triples):
+    """Device triple compaction vs a plain loop: the rows are every set
+    bit of the change grid in (word, bit) order, (observer, observed,
+    kind), -1-filled -- and the first ``max_triples`` of them when the
+    tick overflows (the caller then falls back on ``count``)."""
+    import jax.numpy as jnp
+
+    from goworld_tpu.ops.aoi_predicate import words_per_row
+
+    rng = np.random.default_rng(5)
+    s, c = 2, 256
+    w = words_per_row(c)
+    chg = np.where(rng.random((s, c, w)) < 0.05,
+                   rng.integers(1, 2**32, (s, c, w), dtype=np.uint64),
+                   0).astype(np.uint32)
+    new = rng.integers(0, 2**32, (s, c, w), dtype=np.uint64).astype(np.uint32)
+    ref = []
+    flat_c, flat_n = chg.reshape(-1), new.reshape(-1)
+    for g in np.nonzero(flat_c)[0]:
+        for k in range(32):
+            if (int(flat_c[g]) >> k) & 1:
+                ref.append((g // w, k * w + g % w, (int(flat_n[g]) >> k) & 1))
+    tri, count = EV.extract_triples(jnp.asarray(chg), jnp.asarray(new), c,
+                                    max_triples)
+    tri = np.asarray(tri)
+    assert int(count) == len(ref)
+    n = min(len(ref), max_triples)
+    assert tri[:n].tolist() == [list(r) for r in ref[:n]]
+    assert (tri[n:] == -1).all()

@@ -282,11 +282,25 @@ def _write_record(d, run, rows):
         json.dump({"n": run, "cmd": "bench", "rc": 0, "tail": tail}, fh)
 
 
-def test_bench_gate_passes_real_history():
-    """The pinned per-config thresholds are calibrated so the repo's own
-    BENCH_r01..r09 history is green -- the gate must not cry wolf."""
+def test_bench_gate_passes_green_history(tmp_path):
+    """Swings inside each config's pinned threshold, a recovery that got
+    faster and a chip-less record beside the accelerated series: a green
+    history -- the gate must not cry wolf."""
     bg = _load_bench_gate()
-    assert bg.main([]) == 0
+    eng = {"config": "engine", "metric": "moves_per_s", "value": 100.0,
+           "unit": "moves/s", "n_entities": 512}
+    uni = {**eng, "config": "uniform", "n_entities": 65536}
+    rec = {"config": "engine_restart", "metric": "ticks_to_recover",
+           "value": 4.0, "unit": "ticks", "rate_kind": "recovery",
+           "n_entities": 64}
+    _write_record(str(tmp_path), 1, [eng, uni, rec])
+    _write_record(str(tmp_path), 2, [{**eng, "value": 82.0},
+                                     {**uni, "value": 93.0},
+                                     {**rec, "value": 2.0},
+                                     {**uni, "value": 1.0,
+                                      "accelerator_absent": True}])
+    pattern = os.path.join(str(tmp_path), "BENCH_r*.json")
+    assert bg.main(["--records", pattern]) == 0
 
 
 def test_bench_gate_fails_synthetic_regression(tmp_path, capsys):

@@ -63,6 +63,46 @@ def test_compressor_roundtrip(fmt):
         assert c.decompress(c.compress(data)) == data
 
 
+def test_gwlz_concurrent_first_use_never_falls_back(monkeypatch):
+    """Connections opened by many threads at once (a bot swarm) while the
+    native codec is still being built must all get gwlz: a thread that
+    gave up while another built it fell back to flate, and its peer then
+    read corrupt frames."""
+    import sys
+    import time
+
+    from goworld_tpu.netutil import compress
+    from goworld_tpu.utils import native
+
+    build = native.build
+
+    def slow_build(name):
+        time.sleep(0.2)  # the window a concurrent make run holds open
+        return build(name)
+
+    monkeypatch.setattr(native, "build", slow_build)
+    monkeypatch.setattr(compress, "_gwlz", None)
+    monkeypatch.setattr(compress, "_gwlz_tried", False)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    got, start = [], threading.Barrier(16)
+
+    def open_one():
+        start.wait()
+        got.append(compress.new_compressor("gwlz").name)
+
+    try:
+        threads = [threading.Thread(target=open_one) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert got == ["gwlz"] * 16
+
+
 def test_lzw_hard_cases():
     # dictionary resets (incompressible data fills the 4096-entry table
     # fast), the KwKwK pattern, and width-boundary sizes
