@@ -1444,7 +1444,8 @@ def bench_engine(cfg, backend=None, pipeline=False, bulk=False, watchers=1,
         ph: round(span_s.get(nm, 0.0) / total_ticks * 1e3, 3)
         for ph, nm in (("stage", "aoi.stage"), ("kernel", "aoi.kernel"),
                        ("diff", "aoi.diff"), ("fetch", "aoi.fetch"),
-                       ("decode", "aoi.decode"), ("emit", "aoi.emit"),
+                       ("decode", "aoi.decode"), ("fanout", "aoi.fanout"),
+                       ("emit", "aoi.emit"),
                        ("dispatch", "aoi.dispatch"),
                        ("harvest", "aoi.harvest"))
     }

@@ -47,7 +47,8 @@ from .core import Context, Finding
 RULE = "telemetry"
 
 # call shapes that declare a telemetry name via their first string arg
-_NAMING_ATTRS = ("span", "lap", "counter", "gauge", "histogram", "Operation")
+_NAMING_ATTRS = ("span", "lap", "begin", "counter", "gauge", "histogram",
+                 "Operation")
 # device/host-boundary calls that must never appear inside the telemetry
 # package (they synchronize or copy -- the tick would pay for its own
 # measurement)
@@ -59,7 +60,8 @@ def _telemetry_name(node: ast.Call) -> str | None:
     """The name literal of a telemetry-naming call, if that's what this is.
 
     Matches attribute spellings (``trace.span("x")``, ``_T.lap("x", t0)``,
-    ``telemetry.counter("x")``, ``opmon.Operation("x")``) plus the bare
+    ``seg.begin("x", key)``, ``telemetry.counter("x")``,
+    ``opmon.Operation("x")``) plus the bare
     ``Sample("x", ...)`` constructor collectors emit."""
     if isinstance(node.func, ast.Attribute):
         if node.func.attr not in _NAMING_ATTRS and node.func.attr != "Sample":

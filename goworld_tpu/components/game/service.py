@@ -21,6 +21,7 @@ import time
 
 from ... import consts, faults, telemetry
 from ...telemetry import flight, tracectx
+from ...telemetry import trace as _trace
 from ...config import ClusterConfig
 from ...consts import COMPONENT_QUEUE_MAX
 from ...dispatchercluster import DispatcherCluster
@@ -771,12 +772,14 @@ class GameService:
         if not records:
             return
         per_gate: dict[int, Packet] = {}
-        for client_id, gate_id, eid, x, y, z, yaw in records:
-            p = per_gate.get(gate_id)
-            if p is None:
-                p = GWConnection.make_sync_on_clients_packet(gate_id)
-                per_gate[gate_id] = p
-            GWConnection.append_sync_record(p, client_id, eid, x, y, z, yaw)
+        with _trace.span("game.sync_pack"):
+            for client_id, gate_id, eid, x, y, z, yaw in records:
+                p = per_gate.get(gate_id)
+                if p is None:
+                    p = GWConnection.make_sync_on_clients_packet(gate_id)
+                    per_gate[gate_id] = p
+                GWConnection.append_sync_record(p, client_id, eid, x, y, z,
+                                                yaw)
         traced = telemetry.enabled()
         for gate_id, p in per_gate.items():
             conn = self.cluster.by_gate(gate_id)

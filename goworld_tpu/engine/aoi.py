@@ -75,14 +75,14 @@ def _batched_clear(prev_all, row_slots, row_ents, col_slots, col_words,
         import jax
 
         @functools.partial(jax.jit, donate_argnums=(0,))
-        def impl(prev_all, row_slots, row_ents, col_slots, col_words,
-                 col_masks):
+        def aoi_clear(prev_all, row_slots, row_ents, col_slots, col_words,
+                      col_masks):
             prev_all = prev_all.at[row_slots, row_ents, :].set(0)
             cols = prev_all[col_slots, :, col_words] & col_masks[:, None]
             prev_all = prev_all.at[col_slots, :, col_words].set(cols)
             return prev_all
 
-        _clear_impl = impl
+        _clear_impl = aoi_clear
     return _clear_impl(prev_all, row_slots, row_ents, col_slots, col_words,
                        col_masks)
 
@@ -278,9 +278,10 @@ def _fused_bucket_step(prev_all, *args):
             static_argnames=("max_chunks", "kcap", "max_gaps", "max_exc",
                              "platform"),
             donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-        def impl(prev_all, new_buf, chg_buf, vals_buf, nv_buf, lane_buf,
-                 csel_buf, slot_idx, x_all, z_all, r_all, act_all, sub_all,
-                 max_chunks, kcap, max_gaps, max_exc, platform=None):
+        def aoi_step_stream(prev_all, new_buf, chg_buf, vals_buf, nv_buf,
+                            lane_buf, csel_buf, slot_idx, x_all, z_all, r_all,
+                            act_all, sub_all, max_chunks, kcap, max_gaps,
+                            max_exc, platform=None):
             prev_rows = prev_all[slot_idx]
             x = x_all[slot_idx]
             z = z_all[slot_idx]
@@ -319,7 +320,7 @@ def _fused_bucket_step(prev_all, *args):
                     csel_buf, rowb, bitpos, woff, esc_rows, exc_gidx,
                     exc_chg, exc_new, scalars)
 
-        _fused_impl = impl
+        _fused_impl = aoi_step_stream
     return _fused_impl(prev_all, *args)
 
 
@@ -350,9 +351,9 @@ def _fused_bucket_step_tri(prev_all, *args):
             jax.jit,
             static_argnames=("max_triples", "platform"),
             donate_argnums=(0, 1, 2, 3))
-        def impl(prev_all, new_buf, chg_buf, tri_buf, slot_idx, x_all,
-                 z_all, r_all, act_all, sub_all, max_triples,
-                 platform=None):
+        def aoi_step_tri(prev_all, new_buf, chg_buf, tri_buf, slot_idx, x_all,
+                         z_all, r_all, act_all, sub_all, max_triples,
+                         platform=None):
             prev_rows = prev_all[slot_idx]
             x = x_all[slot_idx]
             z = z_all[slot_idx]
@@ -371,7 +372,7 @@ def _fused_bucket_step_tri(prev_all, *args):
             return (prev_all, new_buf, chg_buf, tri_buf,
                     count.reshape(1))
 
-        _fused_tri_impl = impl
+        _fused_tri_impl = aoi_step_tri
     return _fused_tri_impl(prev_all, *args)
 
 
@@ -407,9 +408,10 @@ def _fused_bucket_step_paged(prev_all, *args):
             static_argnames=("page_words", "bin_words", "max_spill",
                              "platform"),
             donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-        def impl(prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
-                 free, slot_idx, x_all, z_all, r_all, act_all, sub_all,
-                 page_words, bin_words, max_spill, platform=None):
+        def aoi_step_paged(prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
+                           free, slot_idx, x_all, z_all, r_all, act_all,
+                           sub_all, page_words, bin_words, max_spill,
+                           platform=None):
             prev_rows = prev_all[slot_idx]
             x = x_all[slot_idx]
             z = z_all[slot_idx]
@@ -431,7 +433,7 @@ def _fused_bucket_step_paged(prev_all, *args):
             return (prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
                     page_tab, free_next, spill_bins, scalars)
 
-        _fused_paged_impl = impl
+        _fused_paged_impl = aoi_step_paged
     return _fused_paged_impl(prev_all, *args)
 
 
@@ -1666,14 +1668,12 @@ class _CPUBucket(_Bucket):
         self._last.pop(slot, None)
 
     def flush(self) -> None:
-        t0 = time.perf_counter()
-        _ts = _T.t()
-        for slot, (x, z, r, act) in self._staged.items():
-            self._events[slot] = self._oracles[slot].step(x, z, r, act)
-            self._last[slot] = (x, z, r, act)
-        self._staged.clear()
-        _T.lap("aoi.kernel", _ts)
-        self.perf["calc_s"] += time.perf_counter() - t0
+        with _T.Segments(self.perf) as seg:
+            seg.begin("aoi.kernel", "calc_s")
+            for slot, (x, z, r, act) in self._staged.items():
+                self._events[slot] = self._oracles[slot].step(x, z, r, act)
+                self._last[slot] = (x, z, r, act)
+            self._staged.clear()
 
     def export_snapshot(self, slot: int) -> dict:
         """Live-migration wire image of one slot (docs/robustness.md): the
@@ -2193,9 +2193,17 @@ class _TPUBucket(_Bucket):
             if self._inflight is not None:
                 self._sched = ("inflight",)
             return
+        with _T.Segments(self.perf) as seg:
+            seg.begin("aoi.stage", "stage_s")
+            self._dispatch_step(seg)
 
-        t_stage0 = time.perf_counter()
-        _ts = _T.t()
+    def _dispatch_step(self, seg) -> None:
+        """Stage this tick's inputs and enqueue its step program: the rest
+        of :meth:`_dispatch_device`, timed by ``seg`` into ``stage_s``
+        (the ``aoi.stage`` segment is open on entry)."""
+        import jax.numpy as jnp
+
+        c = self.capacity
         slots = sorted(self._staged)
         s_n = len(slots)
         sl = np.array(slots, np.intp)
@@ -2275,11 +2283,10 @@ class _TPUBucket(_Bucket):
             self._mirror_stale.update(s for s in slots if s in self._unsub)
         if self.fused and self._dispatch_fused(
                 slots, sl, slot_idx, key, scratch, sub, old_x, old_z,
-                old_r, old_act, tri_mode, t_stage0, _ts):
+                old_r, old_act, tri_mode, seg):
             return
         self._stage_inputs(sl, old_x, old_z, old_r, old_act)
-        _T.lap("aoi.stage", _ts)
-        _tk = _T.t()
+        seg.begin("aoi.kernel", "stage_s")
         self._fault_phase = "kernel"
         faults.check("aoi.kernel")
         all_unsub = not sub.any()
@@ -2294,7 +2301,7 @@ class _TPUBucket(_Bucket):
             )
             (self.prev, new, chg, pg, pc, pn, page_tab, self._page_free,
              spill_bins, scalars) = out
-            _T.lap("aoi.kernel", _tk)
+            seg.begin(None, "stage_s")
             if not all_unsub:
                 scalars.copy_to_host_async()
                 page_tab.copy_to_host_async()
@@ -2321,7 +2328,6 @@ class _TPUBucket(_Bucket):
                     a.copy_to_host_async()
                 rec["prefetch"] = (ndp, sl_pg)
             prev_rec, self._inflight = self._inflight, rec
-            self.perf["stage_s"] += time.perf_counter() - t_stage0
             if self._defer:
                 if prev_rec is not None:
                     self._sched = ("rec", prev_rec)
@@ -2329,15 +2335,15 @@ class _TPUBucket(_Bucket):
                 self._sched = ("inflight",)
             return
         if tri_mode:
+            platform = "cpu" if self._calc_level >= 1 else None
             DC.record()
+            DC.record_key("aoi.step_tri", (self.prev.shape, key, platform))
             out = _fused_bucket_step_tri(
                 self.prev, *scratch, slot_idx, self._dev["x"],
                 self._dev["z"], self._dev["r"], self._dev["act"],
-                self._dev["sub"], mt,
-                "cpu" if self._calc_level >= 1 else None
-            )
+                self._dev["sub"], mt, platform)
             (self.prev, new, chg, tri, scalars) = out
-            _T.lap("aoi.kernel", _tk)
+            seg.begin(None, "stage_s")
             if not all_unsub:
                 scalars.copy_to_host_async()
             rec = {
@@ -2357,7 +2363,6 @@ class _TPUBucket(_Bucket):
                 sl_tri.copy_to_host_async()
                 rec["prefetch"] = (ndp, sl_tri)
             prev_rec, self._inflight = self._inflight, rec
-            self.perf["stage_s"] += time.perf_counter() - t_stage0
             if self._defer:
                 if prev_rec is not None:
                     self._sched = ("rec", prev_rec)
@@ -2374,7 +2379,7 @@ class _TPUBucket(_Bucket):
         (self.prev, new, chg, g_vals, g_nv, g_lane, g_csel,
          rowb, bitpos, woff, esc_rows, exc_gidx, exc_chg, exc_new,
          scalars) = out
-        _T.lap("aoi.kernel", _tk)
+        seg.begin(None, "stage_s")
         if not all_unsub:
             scalars.copy_to_host_async()
         rec = {
@@ -2409,7 +2414,6 @@ class _TPUBucket(_Bucket):
                 a.copy_to_host_async()
             rec["prefetch"] = (ndp, escp, excp, slices)
         prev_rec, self._inflight = self._inflight, rec
-        self.perf["stage_s"] += time.perf_counter() - t_stage0
         if self._defer:
             # tick T dispatched; T-1's record (whose D2H was prefetched at
             # its own dispatch) harvests in phase 2
@@ -2420,7 +2424,7 @@ class _TPUBucket(_Bucket):
 
     def _dispatch_fused(self, slots, sl, slot_idx, key, scratch, sub,
                         old_x, old_z, old_r, old_act, tri_mode,
-                        t_stage0, _ts) -> bool:
+                        seg) -> bool:
         """Attempt the ONE-DISPATCH fused tick (ops/aoi_fused, ROADMAP
         #3): packet scatter + kernel + diff + extraction/paging as a
         single jitted program, so the steady tick is one enqueue + one
@@ -2479,8 +2483,7 @@ class _TPUBucket(_Bucket):
             zf = np.zeros(0, np.float32)
             pkt = (zi, zi, zf, zf)  # zero movers: in-program no-op scatter
         self.stats["delta_flushes"] += 1
-        _T.lap("aoi.stage", _ts)
-        _tk = _T.t()
+        seg.begin("aoi.kernel", "stage_s")
         all_unsub = not sub.any()
         platform = "cpu" if self._calc_level >= 1 else None
         DC.record()
@@ -2493,8 +2496,7 @@ class _TPUBucket(_Bucket):
                 PG.MAX_SPILL, platform)
             (self.prev, new, chg, pg, pc, pn, self._page_free, bundle,
              self._dev["x"], self._dev["z"]) = out
-            _T.lap("aoi.kernel", _tk)
-            _T.lap("aoi.fused", _tk)
+            seg.begin(None, "stage_s")
             if not all_unsub:
                 bundle.copy_to_host_async()
             rec = {
@@ -2525,8 +2527,7 @@ class _TPUBucket(_Bucket):
                 self._dev["sub"], mt, platform)
             (self.prev, new, chg, tri, scalars,
              self._dev["x"], self._dev["z"]) = out
-            _T.lap("aoi.kernel", _tk)
-            _T.lap("aoi.fused", _tk)
+            seg.begin(None, "stage_s")
             if not all_unsub:
                 scalars.copy_to_host_async()
             rec = {
@@ -2545,7 +2546,6 @@ class _TPUBucket(_Bucket):
                 rec["prefetch"] = (ndp, sl_tri)
         self.stats["fused_dispatches"] += 1
         prev_rec, self._inflight = self._inflight, rec
-        self.perf["stage_s"] += time.perf_counter() - t_stage0
         if self._defer:
             if prev_rec is not None:
                 self._sched = ("rec", prev_rec)
@@ -2821,12 +2821,20 @@ class _TPUBucket(_Bucket):
                           gidx, s_n)
             self._apply_deferred_mirror_ops()
             return
-        if rec.get("mode") == "paged":
-            self._harvest_paged(rec)
-            return
-        if rec.get("mode") == "tri":
-            self._harvest_tri(rec)
-            return
+        with _T.Segments(self.perf) as seg:
+            seg.begin("aoi.fetch", "fetch_s")
+            faults.check("aoi.fetch")  # stallable: a delayed host sync
+            if rec.get("mode") == "paged":
+                self._harvest_paged(rec, seg)
+            elif rec.get("mode") == "tri":
+                self._harvest_tri(rec, seg)
+            else:
+                self._harvest_words(rec, seg)
+
+    def _harvest_words(self, rec, seg) -> None:  # gwlint: allow[host-sync] -- classic-path drain point: fetches the encoded word stream once per flush
+        """Harvest one classic tick: fetch the control scalars and the
+        encoded word stream, decode it, XOR the mirror and publish.  The
+        ``aoi.fetch`` segment of ``seg`` is open on entry."""
         slots, s_n, mc = rec["slots"], rec["s_n"], rec["mc"]
         kcap = rec["kcap"]
         c = self.capacity
@@ -2836,14 +2844,14 @@ class _TPUBucket(_Bucket):
         # ONE tiny fetch for all control scalars (each synchronous fetch
         # pays a device round trip); under the pipeline it was issued async
         # at dispatch and is local by now
-        faults.check("aoi.fetch")  # stallable: a delayed host sync
-        t_f0 = time.perf_counter()
-        _tf = _T.t()
         poisoned = False
+        hb = None
         if rec.get("all_unsub"):
             nd = mcc = base_row = n_esc = exc_n = 0
         else:
-            raw = faults.filter("aoi.scalars", np.asarray(rec["scalars"]))
+            with _T.span("aoi.wait"):
+                scalars = np.asarray(rec["scalars"])
+            raw = faults.filter("aoi.scalars", scalars)
             nd, mcc, base_row, n_esc, exc_n = (int(v) for v in raw)
             nw = s_n * c * self.W
             if not (0 <= nd <= nw // _LANES and 0 <= mcc <= _LANES
@@ -2874,16 +2882,12 @@ class _TPUBucket(_Bucket):
             gidx = np.nonzero(chg_h)[0]
             chg_vals = chg_h[gidx]
             ent_vals = chg_vals & new_h[gidx]
-            self.perf["fetch_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.fetch", _tf)
         elif nd == 0 and exc_n == 0:
             # quiet tick (or every staged slot unsubscribed): the stream is
             # empty by construction -- the scalars above are the ONLY fetch
             chg_vals = np.empty(0, np.uint32)
             ent_vals = np.empty(0, np.uint32)
             gidx = np.empty(0, np.int64)
-            self.perf["fetch_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.fetch", _tf)
         elif nd > mc or mcc > kcap:
             # caps exceeded: recover this tick from the full diff, then grow
             # the caps so the next tick extracts on device again
@@ -2897,8 +2901,6 @@ class _TPUBucket(_Bucket):
             gidx = np.nonzero(chg_h)[0]
             chg_vals = chg_h[gidx]
             ent_vals = chg_vals & new_h[gidx]
-            self.perf["fetch_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.fetch", _tf)
         elif n_esc > self._max_gaps or exc_n > self._max_exc:
             # encode overflow (pathological churn): rebuild from the raw
             # grids kept on device
@@ -2912,8 +2914,6 @@ class _TPUBucket(_Bucket):
             chg_vals = vh[valid]
             ent_vals = chg_vals & nh[valid]
             gidx = (ch[:, None].astype(np.int64) * _LANES + lh)[valid]
-            self.perf["fetch_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.fetch", _tf)
         else:
             # the common path fetches the ENCODED stream: ~5 B per dirty
             # chunk + 12 B per exception, overlapped slice transfers
@@ -2931,17 +2931,11 @@ class _TPUBucket(_Bucket):
                 for a in slices:
                     a.copy_to_host_async()
                 hb = [np.asarray(a) for a in slices]
-            self.perf["fetch_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.fetch", _tf)
-            t_f0 = time.perf_counter()
-            _td = _T.t()
+        seg.begin("aoi.diff", "decode_s")
+        if hb is not None:
             chg_vals, ent_vals, gidx = EV.decode_row_stream(
                 hb[0], hb[1], hb[2].astype(np.uint16), base_row, nd,
                 _LANES, hb[3], hb[4], hb[5], hb[6])
-            self.perf["decode_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.diff", _td)
-        t_f0 = time.perf_counter()
-        _td = _T.t()
         # refit the next dispatch's optimistic prefetch to this tick
         self._pred = (
             max(512, -(-nd * 5 // 4 // 128) * 128),
@@ -2952,8 +2946,6 @@ class _TPUBucket(_Bucket):
         # the harvested scratch set returns to the pool for reuse
         self._scratch.setdefault(rec["key"], rec["scratch"])
         self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx, s_n)
-        self.perf["decode_s"] += time.perf_counter() - t_f0
-        _T.lap("aoi.diff", _td)
 
     def _mirror_xor_stream(self, slots, epochs, gidx, chg_vals) -> None:  # gwlint: allow[host-sync] -- harvest-phase mirror upkeep on already-fetched host arrays
         """Apply one harvested word stream to the host mirror (then run the
@@ -3030,7 +3022,7 @@ class _TPUBucket(_Bucket):
         if self._pages is not None:
             self._pages.reset_after_growth()
 
-    def _harvest_paged(self, rec) -> None:  # gwlint: allow[host-sync] -- paged-path drain point: fetches the used page prefix once per flush
+    def _harvest_paged(self, rec, seg) -> None:  # gwlint: allow[host-sync] -- paged-path drain point: fetches the used page prefix once per flush
         """Harvest one paged tick: fetch the used page prefix + page table
         + scalars, validate the allocator's page table, merge any spilled
         bins' words re-read from the kept change grid, XOR the mirror, and
@@ -3041,15 +3033,13 @@ class _TPUBucket(_Bucket):
         a counted whole-tick spill from the raw grids and re-arms the
         pool; a corrupt page table (``aoi.pages`` poison, or real
         allocator rot) re-raises as RESOURCE_EXHAUSTED to ride
-        :meth:`_recover_harvest`'s rebuild-from-host-shadows."""
+        :meth:`_recover_harvest`'s rebuild-from-host-shadows.  The
+        ``aoi.fetch`` segment of ``seg`` is open on entry."""
         slots, s_n = rec["slots"], rec["s_n"]
         n_pages, bw = rec["n_pages"], rec["bin_words"]
         c = self.capacity
         (new, chg, pg, pc, pn) = rec["scratch"]
         nw = s_n * c * self.W
-        faults.check("aoi.fetch")  # stallable: a delayed host sync
-        t_f0 = time.perf_counter()
-        _tf = _T.t()
         poisoned = False
         n_used = n_spill = 0
         page_spec = page_fault = None
@@ -3059,11 +3049,13 @@ class _TPUBucket(_Bucket):
                 # fused tick: scalars + page_tab + spill_bins ride ONE
                 # int32 bundle -- a single blocking fetch replaces the
                 # unfused path's three (ops/aoi_fused)
-                bun_h = np.asarray(rec["bundle"])
+                with _T.span("aoi.wait"):
+                    bun_h = np.asarray(rec["bundle"])
                 raw = faults.filter("aoi.scalars", bun_h[:4])
             else:
-                raw = faults.filter("aoi.scalars",
-                                    np.asarray(rec["scalars"]))
+                with _T.span("aoi.wait"):
+                    scalars = np.asarray(rec["scalars"])
+                raw = faults.filter("aoi.scalars", scalars)
             n_used, n_spill, nz_fit, nz_total = (int(v) for v in raw)
             n_bins = -(-nw // bw)
             if not (0 <= n_used <= n_pages and 0 <= n_spill <= n_bins
@@ -3119,16 +3111,11 @@ class _TPUBucket(_Bucket):
             gidx = np.nonzero(chg_h)[0]
             chg_vals = chg_h[gidx]
             ent_vals = chg_vals & new_h[gidx]
-            self.perf["fetch_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.fetch", _tf)
-            t_f0 = time.perf_counter()
-            _td = _T.t()
+            seg.begin("aoi.diff", "decode_s")
             self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
             self._scratch.setdefault(rec["key"], rec["scratch"])
             self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx,
                           s_n)
-            self.perf["decode_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.diff", _td)
             return
         if n_used == 0:
             pg_h = np.empty((0, PG.PAGE_WORDS), np.int32)
@@ -3143,13 +3130,10 @@ class _TPUBucket(_Bucket):
                 for a in slices:
                     a.copy_to_host_async()
                 pg_h, pc_h, pn_h = (np.asarray(a)[:n_used] for a in slices)
-        self.perf["fetch_s"] += time.perf_counter() - t_f0
-        _T.lap("aoi.fetch", _tf)
+        seg.begin("aoi.pages", "decode_s")
         # refit the next dispatch's optimistic page prefetch to this tick
         self._pred_pages = max(
             64, min(self._n_pages, -(-n_used * 5 // 4 // 16) * 16))
-        t_f0 = time.perf_counter()
-        _tp = _T.t()
         if n_used:
             # page-table integrity: the table is the allocator's word of
             # which logical pages back this tick; a duplicate, out-of-range
@@ -3187,29 +3171,27 @@ class _TPUBucket(_Bucket):
             self._grow_pool(nw, bw)
         ent_vals = chg_vals & new_vals
         self.stats["page_occupancy"] = (n_used / n_pages) if n_pages else 0.0
-        _T.lap("aoi.pages", _tp)
-        _td = _T.t()
+        seg.begin("aoi.diff", "decode_s")
         self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
         self._scratch.setdefault(rec["key"], rec["scratch"])
         self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx, s_n)
-        self.perf["decode_s"] += time.perf_counter() - t_f0
-        _T.lap("aoi.diff", _td)
 
-    def _harvest_tri(self, rec) -> None:  # gwlint: allow[host-sync] -- triples-path drain point: fetches the compact triple buffer once per flush
+    def _harvest_tri(self, rec, seg) -> None:  # gwlint: allow[host-sync] -- triples-path drain point: fetches the compact triple buffer once per flush
         """Harvest one tri-mode tick: fetch the compact (observer, observed,
         kind) triples + count scalar, XOR the mirror, and fan the pairs out
-        through the native/vector emit layer (docs/perf.md emit paths)."""
+        through the native/vector emit layer (docs/perf.md emit paths).
+        The ``aoi.fetch`` segment of ``seg`` is open on entry."""
         slots, s_n, mt = rec["slots"], rec["s_n"], rec["mt"]
         c = self.capacity
         (new, chg, tri) = rec["scratch"]
-        faults.check("aoi.fetch")  # stallable: a delayed host sync
-        t_f0 = time.perf_counter()
-        _tf = _T.t()
         poisoned = False
         if rec.get("all_unsub"):
             count = 0
         else:
-            raw = faults.filter("aoi.scalars", np.asarray(rec["scalars"]))
+            # the first blocking read: the host waits here for the step
+            with _T.span("aoi.wait"):
+                scalars = np.asarray(rec["scalars"])
+            raw = faults.filter("aoi.scalars", scalars)
             count = int(raw[0])
             if not 0 <= count <= s_n * c * c:
                 from ..utils import gwlog
@@ -3240,16 +3222,11 @@ class _TPUBucket(_Bucket):
             gidx = np.nonzero(chg_h)[0]
             chg_vals = chg_h[gidx]
             ent_vals = chg_vals & new_h[gidx]
-            self.perf["fetch_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.fetch", _tf)
-            t_f0 = time.perf_counter()
-            _td = _T.t()
+            seg.begin("aoi.diff", "decode_s")
             self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
             self._scratch.setdefault(rec["key"], rec["scratch"])
             self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx,
                           s_n)
-            self.perf["decode_s"] += time.perf_counter() - t_f0
-            _T.lap("aoi.diff", _td)
             return
         if count == 0:
             tri_h = np.empty((0, 3), np.int32)
@@ -3259,25 +3236,23 @@ class _TPUBucket(_Bucket):
                 tri_h = np.asarray(pf[1])[:count]
             else:
                 ndp = min(mt, -(-count // 256) * 256)
+                # an eager slice: one launch, and one compile per new
+                # count bucket
+                DC.record()
+                DC.record_key("aoi.tri_slice", (tri.shape, ndp))
                 sl_tri = tri[:ndp]
                 sl_tri.copy_to_host_async()
                 tri_h = np.asarray(sl_tri)[:count]
-        self.perf["fetch_s"] += time.perf_counter() - t_f0
-        _T.lap("aoi.fetch", _tf)
+        seg.begin("aoi.decode", "decode_s")
         # refit the next dispatch's optimistic prefetch to this tick
         self._pred_tri = max(
             2048, min(self._max_triples, -(-count * 5 // 4 // 256) * 256))
-        t_f0 = time.perf_counter()
-        _td = _T.t()
         if self._mirror is not None:
             if len(tri_h):
                 self._mirror_xor_triples(slots, rec["epochs"], tri_h)
             self._apply_deferred_mirror_ops()
         self._scratch.setdefault(rec["key"], rec["scratch"])
-        self.perf["decode_s"] += time.perf_counter() - t_f0
-        _T.lap("aoi.decode", _td)
-        t_f0 = time.perf_counter()
-        _te = _T.t()
+        seg.begin("aoi.fanout", "emit_s")
         try:
             faults.check("aoi.emit")
             pe, pl = AE.fanout_triples(tri_h, c,
@@ -3296,8 +3271,6 @@ class _TPUBucket(_Bucket):
         else:
             self._publish_pairs(slots, rec["epochs"], _split_rows(pe),
                                 _split_rows(pl))
-        self.perf["emit_s"] += time.perf_counter() - t_f0
-        _T.lap("aoi.emit", _te)
 
     def _apply_deferred_mirror_ops(self) -> None:
         """Clears issued after a tick's dispatch apply now, AFTER its

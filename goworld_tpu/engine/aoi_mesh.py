@@ -438,10 +438,10 @@ class _MeshTPUBucket(_Bucket):
 
             @functools.partial(jax.jit, donate_argnums=(0,),
                                out_shardings=self.mesh.sharding())
-            def impl(prev, slot, words):
+            def aoi_mesh_set_rows(prev, slot, words):
                 return prev.at[slot].set(words)
 
-            self._maint_cache["set_slot"] = fn = impl
+            self._maint_cache["set_slot"] = fn = aoi_mesh_set_rows
         return fn
 
     def _maintenance_fn(self):
@@ -455,8 +455,8 @@ class _MeshTPUBucket(_Bucket):
 
             @functools.partial(jax.jit, donate_argnums=(0,),
                                out_shardings=self.mesh.sharding())
-            def impl(prev, reset_slots, row_slots, row_ents, col_slots,
-                     col_words, col_masks):
+            def aoi_mesh_clear(prev, reset_slots, row_slots, row_ents,
+                               col_slots, col_words, col_masks):
                 # mode="drop": padding uses out-of-bounds indices as true
                 # no-ops.  The col pass MUST pad out of bounds too: an
                 # in-bounds fill that collides with a real (slot, word)
@@ -470,7 +470,7 @@ class _MeshTPUBucket(_Bucket):
                 return prev.at[col_slots, :, col_words].set(cols,
                                                             mode="drop")
 
-            self._maint_cache["maint"] = fn = impl
+            self._maint_cache["maint"] = fn = aoi_mesh_clear
         return fn
 
     def _apply_maintenance(self) -> None:
@@ -966,7 +966,6 @@ class _MeshTPUBucket(_Bucket):
          woff, esc_rows, exc_gidx, exc_chg, exc_new, scalars,
          self._dx, self._dz) = out
         _T.lap("aoi.kernel", _tk)
-        _T.lap("aoi.fused", _tk)
         self.prev = new
         all_unsub = bool(self._unsub) and all(s in self._unsub
                                               for s in staged_slots)
@@ -1518,4 +1517,4 @@ class _MeshTPUBucket(_Bucket):
         if rec["key"] == (self.s_max, self._max_chunks, self._kcap):
             self._scratch.setdefault(rec["key"], rec["scratch"])
         self.perf["emit_s"] += time.perf_counter() - t0
-        _T.lap("aoi.emit", _te)
+        _T.lap("aoi.fanout", _te)

@@ -701,7 +701,6 @@ class _RowShardTPUBucket(_Bucket):
          esc_rows, exc_gidx, exc_chg, exc_new, scalars,
          self._dxs, self._dzs, self._dxr, self._dzr) = out
         _T.lap("aoi.kernel", _tk)
-        _T.lap("aoi.fused", _tk)
         self.prev = new
         scalars.copy_to_host_async()
         pf = None
@@ -912,7 +911,7 @@ class _RowShardTPUBucket(_Bucket):
         if rec["key"] == (self._max_chunks, self._kcap):
             self._scratch.setdefault(rec["key"], rec["scratch"])
         self.perf["emit_s"] += time.perf_counter() - t0
-        _T.lap("aoi.emit", _te)
+        _T.lap("aoi.fanout", _te)
 
     # -- fault recovery (docs/robustness.md): no standing mirror at this
     # size, so the durable old state is reconstructed on demand -- the

@@ -37,6 +37,10 @@ from .timers import TimerQueue
 # a no-op while telemetry is disabled
 _TICK_SECONDS = telemetry.histogram(
     "tick.seconds", "whole-tick wall time (timers+aoi+sync+post)")
+# position-sync records the sync phase appended (one per mover and
+# watching client), counted once per tick
+_SYNC_RECORDS = telemetry.counter(
+    "sync.records", "position-sync records collected by the sync phase")
 
 # SLO gate: a tick over this budget trips the flight recorder (0 = off).
 # Env-configured -- the budget is an ops knob, not an engine parameter.
@@ -264,25 +268,31 @@ class Runtime:
         # one path -- exactly-once per entity per tick
         css = self._col_sync_spaces
         if css:
-            for sp in css:
-                sp.drain_column_sync()
-            css.clear()
+            with _trace.span("sync.columns"):
+                for sp in css:
+                    sp.drain_column_sync()
+                css.clear()
         ds = self._dirty_entities
         if not ds:
             return
-        dirty = list(ds)
-        ds.clear()
-        for e in dirty:
-            if e.destroyed:
-                continue
-            flags = e._sync_flags
-            if flags:
-                e._sync_flags = 0
-                if (e.client is not None or
-                        (flags & SYNC_NEIGHBORS and e._watcher_clients > 0)):
-                    self._collect_sync(e, flags)
-            if e._attr_deltas:
-                e._flush_attr_deltas()
+        n0 = len(self.sync_out)
+        with _trace.span("sync.collect"):
+            dirty = list(ds)
+            ds.clear()
+            for e in dirty:
+                if e.destroyed:
+                    continue
+                flags = e._sync_flags
+                if flags:
+                    e._sync_flags = 0
+                    if (e.client is not None or
+                            (flags & SYNC_NEIGHBORS
+                             and e._watcher_clients > 0)):
+                        self._collect_sync(e, flags)
+                if e._attr_deltas:
+                    e._flush_attr_deltas()
+        # once per tick, never per record: the count the span divides
+        _SYNC_RECORDS.inc(len(self.sync_out) - n0)
 
     def _collect_sync(self, e: Entity, flags: int):
         """One 16-byte-payload record per flagged entity per tick

@@ -36,8 +36,8 @@ def _get_step(capacity: int, cfg, full: bool):
         import jax
         import jax.numpy as jnp
 
-        def impl(x, z, r, act, team, vis, prev_final_words,
-                 prev_near_words, grid):
+        def interest_step(x, z, r, act, team, vis, prev_final_words,
+                          prev_near_words, grid):
             prev_final = K.unpack_words(prev_final_words, capacity, jnp)
             prev_near = K.unpack_words(prev_near_words, capacity, jnp)
             final, near = K.step_masks(x, z, r, act, team, vis,
@@ -45,7 +45,7 @@ def _get_step(capacity: int, cfg, full: bool):
                                        jnp, grid=grid)
             return K.pack_bool(final, jnp), K.pack_bool(near, jnp)
 
-        fn = jax.jit(impl)
+        fn = jax.jit(interest_step)
         _STEP_CACHE[key] = fn
     return fn
 

@@ -81,9 +81,9 @@ def fused_tri_step(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
             jax.jit,
             static_argnames=("max_triples", "platform"),
             donate_argnums=(0, 1, 2, 3, 4, 5))
-        def impl(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
-                 rows, cols, xv, zv, slot_idx, r_all, act_all, sub_all,
-                 max_triples, platform=None):
+        def aoi_fused_tri(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
+                          rows, cols, xv, zv, slot_idx, r_all, act_all,
+                          sub_all, max_triples, platform=None):
             x_all, z_all = AS.delta_scatter(x_all, z_all, rows, cols,
                                             xv, zv)
             prev_rows = prev_all[slot_idx]
@@ -104,7 +104,7 @@ def fused_tri_step(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
             return (prev_all, new_buf, chg_buf, tri_buf,
                     count.reshape(1), x_all, z_all)
 
-        _tri_impl = impl
+        _tri_impl = aoi_fused_tri
     # compile-key meter (steady-state recompiles = 0 pins): the static
     # args + every donated shape ARE the jit cache key
     DC.record_key("aoi.fused_tri", (prev_all.shape, new_buf.shape,
@@ -141,10 +141,10 @@ def fused_paged_step(prev_all, new_buf, chg_buf, pg_buf, pc_buf,
             static_argnames=("page_words", "bin_words", "max_spill",
                              "platform"),
             donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8))
-        def impl(prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
-                 free, x_all, z_all, rows, cols, xv, zv, slot_idx,
-                 r_all, act_all, sub_all, page_words, bin_words,
-                 max_spill, platform=None):
+        def aoi_fused_paged(prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
+                            free, x_all, z_all, rows, cols, xv, zv, slot_idx,
+                            r_all, act_all, sub_all, page_words, bin_words,
+                            max_spill, platform=None):
             x_all, z_all = AS.delta_scatter(x_all, z_all, rows, cols,
                                             xv, zv)
             prev_rows = prev_all[slot_idx]
@@ -169,7 +169,7 @@ def fused_paged_step(prev_all, new_buf, chg_buf, pg_buf, pc_buf,
             return (prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
                     free_next, bundle, x_all, z_all)
 
-        _paged_impl = impl
+        _paged_impl = aoi_fused_paged
     DC.record_key("aoi.fused_paged", (prev_all.shape, new_buf.shape,
                                       pg_buf.shape, rows.shape,
                                       page_words, bin_words, max_spill,

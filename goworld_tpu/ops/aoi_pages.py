@@ -172,11 +172,11 @@ def paged_extract(chg, new, free, page_words: int = PAGE_WORDS,
         @functools.partial(
             jax.jit,
             static_argnames=("page_words", "bin_words", "max_spill"))
-        def impl(chg, new, free, page_words, bin_words, max_spill):
+        def aoi_alloc_pages(chg, new, free, page_words, bin_words, max_spill):
             return allocate_pages(chg, new, free, page_words, bin_words,
                                   max_spill)
 
-        _extract_impl = impl
+        _extract_impl = aoi_alloc_pages
     if bin_words is None:
         bin_words = bin_words_for(chg.shape[-1])
     return _extract_impl(chg, new, free, page_words=page_words,

@@ -127,10 +127,10 @@ def apply_packet(dx, dz, rows, cols, xv, zv):
         import jax
 
         @functools.partial(jax.jit, donate_argnums=(0, 1))
-        def impl(dx, dz, rows, cols, xv, zv):
+        def aoi_apply_packet(dx, dz, rows, cols, xv, zv):
             return delta_scatter(dx, dz, rows, cols, xv, zv)
 
-        _apply_impl = impl
+        _apply_impl = aoi_apply_packet
     _th = _T.t()
     DC.record()
     DC.record_key("aoi.apply_packet", (dx.shape, rows.shape))

@@ -4,12 +4,13 @@ The fused-tick contract (ROADMAP #3, docs/perf.md "Fused tick") is "one
 enqueue + one D2H fetch per steady-state tick" -- and a contract nobody
 can measure is a contract that silently rots.  Every engine call site
 that launches a compiled XLA program (delta scatter, bucket step,
-maintenance scatter, fused step, sharded step) reports here via
-:func:`record`, and tests/test_fused.py plus scripts/fused_smoke.py
-bracket a tick with :func:`read` to pin the count: 1 for a fused
-single-chip bucket, 2 for the unfused delta-staged path (scatter +
-step), and the documented per-chip program counts for the sharded
-tiers.
+maintenance scatter, fused step, sharded step, the single-chip
+harvest's eager triples slice) reports here via :func:`record`, and
+tests/test_fused.py plus scripts/fused_smoke.py bracket a tick with
+:func:`read` to pin the count: 1 for a fused single-chip bucket, 2 for
+the unfused delta-staged path (scatter + step), one more for the slice
+on a tick with events, and the documented per-chip program counts for
+the sharded tiers.
 
 Counting is launch-side (did the host enqueue a program), not
 device-side (XLA may still fuse or cache internally) -- that is exactly
@@ -19,20 +20,27 @@ dispatches and are tracked separately as ``aoi.h2d_bytes``.
 
 Pure host-side integers: importing this module never loads jax, and
 recording is a plain increment, so the counter is safe inside
-``dispatch()`` (the gwlint flush-phase rule walks through it).
+``dispatch()`` (the gwlint flush-phase rule walks through it).  Each
+record also feeds the registry counter ``aoi.launches`` (a no-op while
+telemetry is off), so operators and the benchmark read the same count.
 """
 
 from __future__ import annotations
 
+from .. import telemetry
+
+_LAUNCHES = telemetry.counter(
+    "aoi.launches", "XLA program launches enqueued by the AOI engine")
 _n = 0
 _keys: set = set()
-_new_keys = 0
+_fresh: list = []  # the site of each key first seen since reset_keys
 
 
 def record(n=1):
     """Count ``n`` XLA program launches (call beside the jitted call)."""
     global _n
     _n += n
+    _LAUNCHES.inc(n)
 
 
 def read():
@@ -57,29 +65,27 @@ def record_key(site: str, key) -> bool:
     scripts/multispace_smoke.py) bracket the measured window with
     :func:`reset_keys` / :func:`new_keys`.  Returns True when the key is
     new since the last :func:`clear_keys` (i.e. this call compiles)."""
-    global _new_keys
     k = (site, key)
     if k in _keys:
         return False
     _keys.add(k)
-    _new_keys += 1
+    _fresh.append(site)
     return True
 
 
-def new_keys() -> int:
-    """Fresh compile keys observed since the last :func:`reset_keys`."""
-    return _new_keys
+def new_keys(site: str | None = None) -> int:
+    """Fresh compile keys observed since the last :func:`reset_keys`
+    (those of ``site`` alone when it is given)."""
+    return len(_fresh) if site is None else _fresh.count(site)
 
 
 def reset_keys():
     """Zero the new-key counter, KEEPING the seen set -- the warmup/
     measure bracket (warm keys must not count as steady recompiles)."""
-    global _new_keys
-    _new_keys = 0
+    _fresh.clear()
 
 
 def clear_keys():
     """Forget every seen key (full harness reset between configs)."""
-    global _new_keys
     _keys.clear()
-    _new_keys = 0
+    _fresh.clear()

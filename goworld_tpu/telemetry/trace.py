@@ -1,16 +1,22 @@
 """Tick tracing: spans -> bounded ring -> Chrome trace-event JSON.
 
 The span API times the tick pipeline (stage/h2d -> kernel -> diff -> fetch
--> event emit -> net flush) with two spellings matched to the call sites:
+-> event emit -> net flush) with three spellings matched to the call sites:
 
 * ``with trace.span("tick.aoi"): ...`` -- block-shaped phases (runtime tick
   phases, component handlers);
-* ``t0 = trace.t(); ...; trace.lap("aoi.fetch", t0)`` -- the engine buckets'
-  branchy segments, where a ``with`` block cannot bracket the interval.
+* ``with trace.Segments(perf) as seg: seg.begin("aoi.fetch", "fetch_s")``
+  -- consecutive segments of one bucket phase that also feed an always-on
+  seconds sum; one clock read per boundary serves both;
+* ``t0 = trace.t(); ...; trace.lap("aoi.fetch", t0)`` -- a completed
+  interval recorded after the fact (the multi-chip tiers' segments).
 
-Disabled (the default) both are near-free: ``span`` returns a shared no-op
-context manager, ``t`` returns 0.0 and ``lap`` does nothing -- one global
-load and an ``is None`` test each, the same contract as ``faults.check``.
+Disabled (the default) ``span``/``t``/``lap`` are near-free: ``span``
+returns a shared no-op context manager, ``t`` returns 0.0 and ``lap`` does
+nothing -- one global load and an ``is None`` test each, the same contract
+as ``faults.check``.  ``Segments`` reads ``time.perf_counter`` then, for
+its sums alone.  ``span`` and ``Segments`` open a profiler annotation per
+span while annotations are on; a ``lap`` never does.
 Tracing reads the clock and nothing else -- never device state -- so
 enabling it cannot perturb the bit-exact event stream.
 
@@ -163,6 +169,62 @@ def t() -> float:
     if tr is None:
         return 0.0
     return tr.clock()
+
+
+class Segments:
+    """Consecutive timed segments of one phase.  ``begin(name, key)`` ends
+    the open segment and starts the next; each segment's seconds add into
+    ``sink[key]`` (a bucket's always-on ``perf`` dict), and while tracing
+    is on a named segment is also a span, with a profiler annotation like
+    ``span``'s (``name`` None: the sum alone).  Each boundary reads the
+    clock once -- the tracer's while tracing is on, ``time.perf_counter``
+    otherwise -- and that stamp feeds the sum and the span alike.  Leaving
+    the ``with`` block ends the last segment on every exit (an exception
+    or an early return included), so no annotation is left open."""
+
+    __slots__ = ("sink", "tr", "clock", "name", "key", "t0", "annot")
+
+    def __init__(self, sink: dict):
+        tr = _TRACER
+        self.sink, self.tr = sink, tr
+        self.clock = tr.clock if tr is not None else time.perf_counter
+        self.name = self.key = self.annot = None
+
+    def __enter__(self):
+        return self
+
+    def begin(self, name: str | None, key: str) -> None:
+        t = self._end()
+        self.name, self.key, self.t0 = name, key, t
+        tr = self.tr
+        if tr is not None and name is not None:
+            factory = tr.annot_factory
+            if factory is not None:
+                self.annot = factory(name)
+                self.annot.__enter__()
+            _active_stack().append(name)
+
+    def _end(self) -> float:
+        t = self.clock()
+        if self.key is None:
+            return t
+        self.sink[self.key] += t - self.t0
+        self.key = None
+        name, tr = self.name, self.tr
+        if tr is not None and name is not None:
+            if self.annot is not None:
+                self.annot.__exit__(None, None, None)
+                self.annot = None
+            stack = _active_stack()
+            if stack and stack[-1] == name:
+                stack.pop()
+            tr.record(name, self.t0, t)
+        return t
+
+    def __exit__(self, *exc):
+        if self.key is not None:
+            self._end()
+        return False
 
 
 def lap(name: str, t0: float) -> float:

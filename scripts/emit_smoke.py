@@ -83,7 +83,7 @@ def main():
         phases[mode] = {
             ph: span_s.get(nm, 0.0) / ticks * 1e3
             for ph, nm in (("fetch", "aoi.fetch"), ("decode", "aoi.decode"),
-                           ("diff", "aoi.diff"), ("emit", "aoi.emit"))}
+                           ("diff", "aoi.diff"), ("fanout", "aoi.fanout"))}
 
     # forced overflow: the counted fallback must stay bit-exact and count
     crc, bucket, _ = run_mode(modes[0], frames, cap, shrink_tri=True)

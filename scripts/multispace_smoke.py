@@ -10,9 +10,11 @@ The ci.sh gate for the space-stacked megabatch (engine/aoi_cohort,
   bit-exactly every tick;
 * device dispatches per steady-state tick are counted through
   ``ops.dispatch_count``: the cohort side must take 1 (the whole
-  point), the solo side one per space -- and after warmup NEITHER side
-  may mint a new jit compile key (``DC.new_keys() == 0``: the pow2
-  ladder keeps the key set O(ladder));
+  point), the solo side one per space, beside one eager triples slice
+  per bucket whose tick has events -- and after warmup NEITHER side
+  may mint a new step compile key (``DC.new_keys() == 0`` less the
+  eager slices' ``aoi.tri_slice`` keys, one per new count bucket: the
+  pow2 ladder keeps the step key set O(ladder));
 * a forced ``aoi.cohort`` fault demotes the whole cohort to per-space
   solo buckets same-tick -- counted, bit-exact -- and the operator
   re-arm (``recohort()``) stacks every space back onto one bucket.
@@ -63,7 +65,7 @@ def _drive(engines, handles, ticks=TICKS, seed=11):
     events and the measured-window dispatch/new-key counts."""
     rng, scenes = _scenes(seed)
     events = {k: [] for k in engines}
-    meters = {}
+    meters = {"slices": 0}
     for t in range(ticks):
         if t == WARMUP:
             DC.reset()
@@ -81,12 +83,19 @@ def _drive(engines, handles, ticks=TICKS, seed=11):
                 e.submit(h, _pad(x, cap), _pad(z, cap), _pad(r, cap),
                          _pad(act, cap).astype(bool))
             e.flush()
+            busy = set()
             for h in handles[k]:
                 ev = e.take_events(h)
                 tick_evs.append(tuple(np.array(p, copy=True) for p in ev))
+                if k != "cpu" and sum(len(p) for p in ev):
+                    busy.add(id(h.bucket))
             events[k].append(tick_evs)
+            if t >= WARMUP:
+                # a bucket whose tick has events slices its triples
+                meters["slices"] += len(busy)
     meters["dispatches"] = DC.read()
-    meters["new_keys"] = DC.new_keys()
+    meters["new_keys"] = DC.new_keys() - DC.new_keys("aoi.tri_slice")
+    meters["slice_keys"] = DC.new_keys("aoi.tri_slice")
     return events, meters
 
 
@@ -124,12 +133,15 @@ def run_stacked():
     _assert_parity(ev_c, label="stacked")
     _assert_parity(ev_s, label="stacked")
     meas = TICKS - WARMUP
-    disp_c = m_c["dispatches"] / meas
-    disp_s = m_s["dispatches"] / meas
+    # the step launches, without the harvests' eager triples slices
+    disp_c = (m_c["dispatches"] - m_c["slices"]) / meas
+    disp_s = (m_s["dispatches"] - m_s["slices"]) / meas
     print(f"  stacked     parity OK | dispatches/tick: "
           f"cohort={disp_c:g} solo={disp_s:g} "
-          f"(ratio {disp_c / disp_s:.4f}) | new jit keys after warmup: "
-          f"cohort={m_c['new_keys']} solo={m_s['new_keys']}")
+          f"(ratio {disp_c / disp_s:.4f}) | new step keys after warmup: "
+          f"cohort={m_c['new_keys']} solo={m_s['new_keys']}, new triples "
+          f"slice keys: cohort={m_c['slice_keys']} "
+          f"solo={m_s['slice_keys']}")
     assert disp_c == 1, f"cohort steady tick took {disp_c} dispatches"
     assert disp_s == N_SPACES, \
         f"solo baseline took {disp_s}, want {N_SPACES}"

@@ -63,11 +63,12 @@ def main():
         rt.tick()
 
     # 1. the engine phase spans bench.py turns into phase_ms are recorded
-    # (the default triples emit path laps aoi.decode; the classic word-stream
-    # path laps aoi.diff instead -- docs/observability.md)
+    # (the default triples emit path records aoi.decode; the classic
+    # word-stream path records aoi.diff instead -- docs/observability.md)
     names = {nm for nm, _tid, _t0, _t1 in trace.spans()}
-    for want in ("tick", "tick.aoi", "aoi.flush", "aoi.stage", "aoi.kernel",
-                 "aoi.fetch", "aoi.emit"):
+    for want in ("tick", "tick.aoi", "tick.sync", "sync.collect",
+                 "aoi.flush", "aoi.stage", "aoi.kernel", "aoi.fetch",
+                 "aoi.fanout", "aoi.emit"):
         assert want in names, f"span {want!r} missing from {sorted(names)}"
     assert "aoi.decode" in names or "aoi.diff" in names, \
         f"neither decode span present in {sorted(names)}"

@@ -48,8 +48,9 @@ def test_config_matrix_well_formed():
 
 def test_bench_engine_records_span_phase_breakdown():
     """Engine records carry the telemetry-sourced per-phase breakdown
-    (the acceptance contract: {stage, kernel, diff, fetch, decode, emit}
-    plus the split-phase scheduler's {dispatch, harvest} pair and the
+    (the acceptance contract: {stage, kernel, diff, fetch, decode,
+    fanout, emit} plus the split-phase scheduler's {dispatch, harvest}
+    pair and the
     whole-tick span) even on the native-calculator path, where the
     scheduler phases are zero (CPU buckets dispatch-and-complete inline)."""
     bench = _load_bench()
@@ -57,7 +58,8 @@ def test_bench_engine_records_span_phase_breakdown():
                        ticks=3, reps=1)
     rec = bench.bench_engine(cfg, "cpp")
     assert set(rec["phase_ms"]) == {"stage", "kernel", "diff", "fetch",
-                                    "decode", "emit", "dispatch", "harvest"}
+                                    "decode", "fanout", "emit", "dispatch",
+                                    "harvest"}
     assert all(v >= 0.0 for v in rec["phase_ms"].values())
     assert rec["span_tick_ms"] >= 0.0
 

@@ -162,18 +162,25 @@ def test_cohort_one_dispatch_per_tick_vs_solo():
     engines, handles = _engines(fused=True)
     del engines["cpu"], handles["cpu"]
     _drive(engines, handles, 3)  # warmup: full upload + first deltas
-    counts = {}
+    counts, slices = {}, {}
     for k, e in engines.items():
         DC.reset()
         DC.reset_keys()
-        _drive({k: e}, {k: handles[k]}, 4)
+        out = _drive({k: e}, {k: handles[k]}, 4)
         counts[k] = DC.read()
         assert DC.new_keys() == 0, \
             f"{k}: steady-state recompiles must be 0"
+        # a bucket whose tick has events slices its triples: one launch
+        slices[k] = sum(
+            len({id(h.bucket) for h, (en, lv) in zip(handles[k], tick)
+                 if len(en) or len(lv)})
+            for tick in out[k])
     # cohort: one fused launch per bucket (2 buckets: rungs 256 + 1024);
     # solo: one per space (4) -- the dispatch ratio the bench pins
-    assert counts["cohort"] == 2 * 4, counts
-    assert counts["solo"] == len(CAPS) * 4, counts
+    assert slices["cohort"] > 0, slices
+    assert counts["cohort"] == 2 * 4 + slices["cohort"], (counts, slices)
+    assert counts["solo"] == len(CAPS) * 4 + slices["solo"], \
+        (counts, slices)
     coh = engines["cohort"]._buckets[("tpu-cohort", 256)]
     assert coh.stats["cohort_dispatches"] >= 7
     assert coh.stats["cohort_demotions"] == 0

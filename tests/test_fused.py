@@ -16,7 +16,7 @@ The contract under test (docs/perf.md "Fused dispatch"):
 * any ``aoi.*`` seam firing inside the fused attempt demotes that one
   tick to the unfused path -- counted in ``aoi.fused_demotions``,
   republished same-tick, bit-exact;
-* telemetry: the "aoi.fused" span brackets the fused enqueue and the
+* telemetry: the "aoi.kernel" span brackets the fused enqueue and the
   ``aoi.fused_dispatches`` / ``aoi.fused_demotions`` counters surface
   through the engine stats (docs/observability.md).
 """
@@ -161,11 +161,11 @@ def test_fused_interest_parity():
 
 def test_fused_one_dispatch_per_steady_tick():
     """THE point of the PR: once warm, a fused single-chip bucket ticks
-    in exactly one device program launch (unfused: two -- scatter +
-    step).  Counted at the launch sites via ops.dispatch_count; D2H
-    fetches and async prefetch slices are not launches and don't count.
-    Non-deferred mode: the deferral (pipeline/cross_tick) adds prefetch
-    slicing that is correctness-neutral but not a program launch either.
+    in exactly one device step launch (unfused: two -- scatter + step),
+    plus the harvest's eager triples slice on a tick with events (one
+    more launch, both paths).  Counted at the launch sites via
+    ops.dispatch_count; D2H fetches are not launches.  Non-deferred
+    mode: the deferral (pipeline/cross_tick) slices at dispatch instead.
     Per-chip counts for mesh/rowshard (also 1 fused / 2 unfused, the
     single launch fanning out under shard_map) are asserted by
     scripts/fused_smoke.py against 8 virtual devices."""
@@ -177,6 +177,7 @@ def test_fused_one_dispatch_per_steady_tick():
     handles = {k: e.create_space(cap) for k, e in engines.items()}
     rng, xs, zs, rr, act = _scene(3, cap, 180)
     steady = {k: [] for k in engines}
+    sliced = {k: [] for k in engines}
     for t in range(6):
         _sparse_step(rng, xs, zs)
         for k, e in engines.items():
@@ -184,13 +185,16 @@ def test_fused_one_dispatch_per_steady_tick():
                      _pad(rr, cap), act.copy())
             DC.reset()
             e.flush()
+            en, lv = e.take_events(handles[k])
             if t >= 2:  # warm: past first-tick full restage + compiles
                 steady[k].append(DC.read())
-            e.take_events(handles[k])
-    assert steady["fused"] == [1] * 4, \
-        f"fused steady ticks took {steady['fused']} dispatches, want 1"
-    assert all(c == 2 for c in steady["plain"]), \
-        f"unfused baseline moved: {steady['plain']} (expected 2)"
+                sliced[k].append(int(len(en) + len(lv) > 0))
+    assert sliced["fused"] == sliced["plain"] and any(sliced["fused"])
+    assert steady["fused"] == [1 + s for s in sliced["fused"]], \
+        f"fused steady ticks took {steady['fused']} dispatches, want 1 " \
+        f"+ the triples slice"
+    assert steady["plain"] == [2 + s for s in sliced["plain"]], \
+        f"unfused baseline moved: {steady['plain']} (expected 2 + slice)"
     assert handles["fused"].bucket.stats["fused_dispatches"] >= 4
 
 
@@ -221,12 +225,12 @@ def test_fused_demotion_paged_under_fault_plan():
     assert demos >= 1
 
 
-# -- telemetry: the aoi.fused span + counters --------------------------------
+# -- telemetry: the aoi.kernel span + counters -------------------------------
 
 def test_fused_span_and_counters():
-    """The fused enqueue emits the "aoi.fused" span (alongside
-    "aoi.kernel", which keeps the bench phase attribution) and the
-    fused_dispatches counter lands in the engine stats."""
+    """The fused enqueue is the tick's "aoi.kernel" span (the removed
+    "aoi.fused" lap covered the same extent) and the fused_dispatches /
+    fused_demotions counters land in the engine stats."""
     engines, handles = _engines({"fused": {"fused": True}})
     telemetry.enable()
     trace.reset()
@@ -235,8 +239,8 @@ def test_fused_span_and_counters():
         names = {nm for nm, *_ in trace.spans()}
     finally:
         telemetry.disable()
-    assert "aoi.fused" in names
+    assert "aoi.fused" not in names
     assert "aoi.kernel" in names
     st = handles["fused"][0].bucket.stats
     assert st["fused_dispatches"] > 0
-    assert "fused_demotions" in st
+    assert st["fused_demotions"] == 0

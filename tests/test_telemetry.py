@@ -51,10 +51,13 @@ CANONICAL_NAMES = (
     # runtime tick phases + the whole-tick histogram
     "tick", "tick.seconds", "tick.timers", "tick.aoi", "tick.sync",
     "tick.post",
+    # the sync phase's spans and record counter, the served game's packing
+    "sync.columns", "sync.collect", "sync.records", "game.sync_pack",
     # AOI engine phase spans + engine gauges
     "aoi.flush", "aoi.emit", "aoi.h2d", "aoi.stage", "aoi.kernel",
-    "aoi.fetch", "aoi.diff", "aoi.decode", "aoi.host_tick", "aoi.buckets",
-    "aoi.calc_level", "aoi.emit_path",
+    "aoi.fetch", "aoi.wait", "aoi.diff", "aoi.decode", "aoi.fanout",
+    "aoi.host_tick", "aoi.buckets", "aoi.calc_level", "aoi.emit_path",
+    "aoi.launches",
     # paged ragged neighbor/event storage (ops/aoi_pages.py absorbers)
     "aoi.pages", "aoi.page_occupancy", "aoi.page_spills",
     # live migration / chip-loss failover (engine/placement.py): start
@@ -79,9 +82,20 @@ CANONICAL_NAMES = (
 
 @pytest.fixture(autouse=True)
 def _telemetry_off_after():
-    """Telemetry state is process-global; never leak it across tests."""
+    """Telemetry state is process-global; never leak it across tests --
+    neither the switch nor what the registry's counters and gauges
+    counted while a test had it on (other files read their absolute
+    values)."""
+    from goworld_tpu.telemetry.metrics import Counter, Gauge
+
+    reg = telemetry.registry()
+    saved = {nm: m.value for nm, m in list(reg._metrics.items())
+             if isinstance(m, (Counter, Gauge))}
     yield
     telemetry.disable()
+    for nm, m in list(reg._metrics.items()):
+        if isinstance(m, (Counter, Gauge)):
+            m.value = saved.get(nm, 0.0)
 
 
 # -- bit-exact parity: telemetry on vs off, per device tier ------------------
@@ -123,10 +137,12 @@ def test_single_chip_parity_on_vs_off():
     off = _walk()
     on, names = _traced_walk()
     _assert_on_off_identical(off, on)
-    # the single-chip default is the triples path: its harvest laps are
-    # aoi.decode (mirror upkeep) + aoi.emit (fan-out), not aoi.diff
-    assert {"aoi.stage", "aoi.kernel", "aoi.fetch", "aoi.decode",
-            "aoi.emit"} <= names, names
+    # the single-chip default is the triples path: its harvest segments
+    # are aoi.fetch (with the aoi.wait inside), aoi.decode (mirror upkeep)
+    # and aoi.fanout, not aoi.diff; no bucket-level aoi.emit is left
+    assert {"aoi.stage", "aoi.kernel", "aoi.fetch", "aoi.wait",
+            "aoi.decode", "aoi.fanout"} <= names, names
+    assert "aoi.emit" not in names and "aoi.fused" not in names, names
 
 
 def _mesh_devices():
@@ -145,8 +161,8 @@ def test_mesh_parity_on_vs_off():
     off = _walk(mesh=SpaceMesh(devs))
     on, names = _traced_walk(mesh=SpaceMesh(devs))
     _assert_on_off_identical(off, on)
-    assert {"aoi.stage", "aoi.kernel", "aoi.fetch", "aoi.diff"} <= names, \
-        names
+    assert {"aoi.stage", "aoi.kernel", "aoi.fetch", "aoi.diff",
+            "aoi.fanout"} <= names, names
 
 
 def test_rowshard_parity_on_vs_off():
@@ -157,8 +173,8 @@ def test_rowshard_parity_on_vs_off():
     off = _walk(mesh=SpaceMesh(devs), **kw)
     on, names = _traced_walk(mesh=SpaceMesh(devs), **kw)
     _assert_on_off_identical(off, on)
-    assert {"aoi.stage", "aoi.kernel", "aoi.fetch", "aoi.diff"} <= names, \
-        names
+    assert {"aoi.stage", "aoi.kernel", "aoi.fetch", "aoi.diff",
+            "aoi.fanout"} <= names, names
 
 
 # -- disabled path -----------------------------------------------------------
@@ -290,6 +306,249 @@ def test_runtime_tick_records_spans_on_injected_clock():
     t0, t1 = spans["tick"]
     assert (t0, t1) == (clk.t, clk.t)  # fake clock never advanced
     assert hist.count == count0 + 1
+
+
+# -- segments: one clock read per boundary ----------------------------------
+
+
+class _CountingClock(_Clock):
+    """A fake clock that counts its reads and steps 1 ms per read."""
+
+    def __init__(self):
+        super().__init__()
+        self.reads = 0
+
+    def __call__(self):
+        self.reads += 1
+        self.t += 0.001
+        return self.t
+
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enter/exit."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+        return False
+
+
+def test_segments_share_one_stamp_per_boundary():
+    """Each boundary reads the clock once; that stamp ends one segment's
+    sum and span and starts the next; ``None`` sums without a span."""
+    clk = _CountingClock()
+    telemetry.enable(clock=clk)
+    trace.reset()
+    sink = {"a_s": 0.0, "b_s": 0.0}
+    with trace.Segments(sink) as seg:
+        seg.begin("aoi.stage", "a_s")
+        seg.begin("aoi.kernel", "b_s")
+        seg.begin(None, "b_s")
+    assert clk.reads == 4
+    spans = [(nm, t0, t1) for nm, _tid, t0, t1 in trace.spans()]
+    assert [nm for nm, *_ in spans] == ["aoi.stage", "aoi.kernel"]
+    assert spans[0][2] == spans[1][1]  # one stamp at the boundary
+    assert sink["a_s"] == pytest.approx(0.001)
+    assert sink["b_s"] == pytest.approx(0.002)
+    assert trace.current_span() is None
+
+
+def test_segments_feed_their_sums_while_tracing_is_off():
+    telemetry.disable()
+    sink = {"fetch_s": 0.0}
+    with trace.Segments(sink) as seg:
+        seg.begin("aoi.fetch", "fetch_s")
+    assert sink["fetch_s"] > 0.0
+    assert trace.spans() == []
+
+
+def _annotations_balanced(names):
+    """Every converted span opened and closed one annotation each, in
+    nesting order, and the ring holds one span per annotation."""
+    log = _FakeAnnotation.log
+    depth = []
+    for kind, nm in log:
+        if kind == "enter":
+            depth.append(nm)
+        else:
+            assert depth and depth[-1] == nm, log
+            depth.pop()
+    assert depth == [], depth
+    ring = [nm for nm, *_ in trace.spans()]
+    for nm in names:
+        opened = sum(1 for k, n in log if k == "enter" and n == nm)
+        assert opened == ring.count(nm) > 0, (nm, opened, ring.count(nm))
+
+
+def test_converted_spans_open_one_annotation_each_even_on_faults():
+    """With a fake annotation factory, the single-chip bucket's segments
+    (aoi.stage/kernel/fetch/decode/fanout) and aoi.wait each open and
+    close exactly one annotation per span, also on the tick whose
+    ``aoi.fetch`` faults inside the fetch segment (recovered bit-exactly
+    from the host copies), and nothing is left open."""
+    from goworld_tpu import faults
+
+    converted = ("aoi.stage", "aoi.kernel", "aoi.fetch", "aoi.wait",
+                 "aoi.decode", "aoi.fanout")
+    off = _walk(ticks=6)
+    tr = trace.enable()
+    telemetry.enable()
+    tr.annot_factory = _FakeAnnotation
+    _FakeAnnotation.log = []
+    trace.reset()
+    faults.install("aoi.fetch:fail@4")
+    try:
+        on = _walk(ticks=6)
+        fired = [f["seam"] for f in faults.plan().snapshot()["fired"]]
+    finally:
+        faults.clear()
+    _assert_on_off_identical(off, on)
+    assert fired == ["aoi.fetch"], fired
+    _annotations_balanced(converted)
+    assert trace.current_span() is None
+
+
+def _small_shard(seed):
+    """The benchmark cell's path at a CPU size: players through gate
+    batches and MovementIngest, NPCs through Space.move_entities."""
+    from benchmark.harness import Shard
+
+    cfg = {"spaces": 2, "entities_per_space": 240, "capacity": 256,
+           "world": 500.0, "radius": 100.0, "spread": {"kind": "uniform"},
+           "engine": {"aoi_backend": "tpu"}}
+    mix = {"player_share": 1 / 3, "gates": 4, "move_share": 1.0,
+           "step": 5.0, "watchers_per_space": 0}
+    shard = Shard(cfg, mix, seed)
+    shard.tick(moving=False)
+    shard.tick()
+    return shard
+
+
+def _inside(spans, inner, outer):
+    """Every ``inner`` span lies within some ``outer`` span."""
+    outs = [(a, b) for nm, a, b in spans if nm == outer]
+    ins = [(a, b) for nm, a, b in spans if nm == inner]
+    assert ins, inner
+    return all(any(oa <= a and b <= ob for oa, ob in outs) for a, b in ins)
+
+
+def test_sync_and_harvest_spans_nest_and_count():
+    """On the benchmark cell's path: sync.columns and sync.collect nest in
+    tick.sync, aoi.wait in aoi.fetch in aoi.harvest, the inner fan-out is
+    aoi.fanout (every aoi.emit is the runtime's, outside aoi.flush),
+    ``sync.records`` counts exactly the records drained, and
+    ``aoi.launches`` moves with ``ops.dispatch_count``."""
+    import time
+
+    from goworld_tpu.ops import dispatch_count as DC
+
+    shard = _small_shard(2**31 + 11)
+    reg = telemetry.registry()
+    records, launches = reg.counter("sync.records"), \
+        reg.counter("aoi.launches")
+    telemetry.enable(clock=time.perf_counter)
+    trace.reset()
+    r0, l0, d0 = records.value, launches.value, DC.read()
+    drained = sum(shard.tick().records for _ in range(3))
+    assert records.value - r0 == drained > 0
+    assert launches.value - l0 == DC.read() - d0 > 0
+    spans = [(nm, a, b) for nm, _tid, a, b in trace.spans()]
+    assert _inside(spans, "sync.columns", "tick.sync")
+    assert _inside(spans, "sync.collect", "tick.sync")
+    assert _inside(spans, "aoi.wait", "aoi.fetch")
+    assert _inside(spans, "aoi.fetch", "aoi.harvest")
+    assert _inside(spans, "aoi.fanout", "aoi.harvest")
+    flushes = [(a, b) for nm, a, b in spans if nm == "aoi.flush"]
+    assert not any(fa <= a and b <= fb for nm, a, b in spans
+                   if nm == "aoi.emit" for fa, fb in flushes)
+
+
+def test_streams_and_sync_records_identical_traced_and_untraced():
+    """Two shards on one seed, one traced (spans, counters and profiler
+    annotations through a fake factory), one not: the same enter/leave
+    pairs and the same sync records, tick by tick (records compared as a
+    multiset: the dirty set's walk order follows object identity)."""
+    seed = 2**31 + 29
+    runs = []
+    for traced in (False, True):
+        shard = _small_shard(seed)
+        if traced:
+            tr = trace.enable()
+            telemetry.enable()
+            tr.annot_factory = _FakeAnnotation
+        else:
+            telemetry.disable()
+        runs.append([shard.tick(keep=True) for _ in range(3)])
+        telemetry.disable()
+    for off, on in zip(*runs):
+        assert sorted(off.sync) == sorted(on.sync) and len(off.sync) > 0
+        for (oe, ol), (ne, nl) in zip(off.events, on.events):
+            np.testing.assert_array_equal(oe, ne)
+            np.testing.assert_array_equal(ol, nl)
+
+
+def test_game_sync_pack_span():
+    """GameService._send_position_syncs packs per gate inside the
+    game.sync_pack span."""
+    import types
+
+    from goworld_tpu.components.game.service import GameService
+
+    recs = [("c%015d" % i, i % 2, "e%015d" % i, 1.0, 0.0, 2.0, 0.5)
+            for i in range(4)]
+    stub = types.SimpleNamespace(
+        rt=types.SimpleNamespace(drain_sync=lambda: recs),
+        cluster=types.SimpleNamespace(by_gate=lambda gate_id: None))
+    telemetry.enable()
+    trace.reset()
+    GameService._send_position_syncs(stub)
+    assert [nm for nm, *_ in trace.spans()] == ["game.sync_pack"]
+
+
+def test_benchmark_readers_of_the_new_metrics():
+    """The per-layer readers of benchmark/metrics, from a run.Context with
+    synthetic spans and a primed registry: the span entries give ms per
+    tick, the counter readers divide the window's counts."""
+    from benchmark import run
+
+    mdir = str(REPO / "benchmark" / "metrics")
+    spans = [("tick.sync", 0.0, 1.0), ("sync.columns", 0.0, 0.05),
+             ("sync.collect", 0.05, 0.8), ("aoi.wait", 2.0, 2.6),
+             ("sync.collect", 3.0, 3.7)]
+    ctx = run.Context(spans, 2, None, 0)
+
+    def read(name):
+        return run.per_layer_reader(mdir, name)(ctx)
+
+    assert read("sync_columns_ms") == pytest.approx(25.0)
+    assert read("sync_collect_ms") == pytest.approx(725.0)
+    assert read("aoi_wait_ms") == pytest.approx(300.0)
+    reg = telemetry.registry()
+    counters = {nm: reg.counter(nm) for nm in ("sync.records",
+                                                "aoi.launches")}
+    saved = {nm: c.value for nm, c in counters.items()}
+    try:
+        counters["sync.records"].value = 1_450_000.0
+        counters["aoi.launches"].value = 6.0
+        assert read("sync_us_per_record") == pytest.approx(1.0)
+        assert read("aoi_launches_per_tick") == pytest.approx(3.0)
+        # a program without the counters (or a window that counted
+        # nothing) reads as no metric, never as a fault
+        counters["sync.records"].value = 0.0
+        counters["aoi.launches"].value = 0.0
+        assert read("sync_us_per_record") is None
+        assert read("aoi_launches_per_tick") is None
+    finally:
+        for nm, c in counters.items():
+            c.value = saved[nm]
 
 
 # -- metrics registry --------------------------------------------------------
