@@ -90,11 +90,15 @@ def _batched_clear(prev_all, row_slots, row_ents, col_slots, col_words,
 _LANES = 128
 _MAX_GAPS = 2048    # escaped chunk-index deltas per flush
 _MAX_EXC = 32768    # exception triples (tail + multi-bit words) per flush
-# triples-path extraction cap ceiling: the [max_triples, 32] bit matrix
-# inside extract_triples is the shape driver (~32 MB of int32 at 2^18), so
+# triples-path extraction cap ceiling: the [max_triples, 128] chunk rows
+# inside extract_triples (one gathered chunk and its int32 running
+# popcount per rank, ~128 MB each at 2^18) are the shape driver, so
 # growth stops here and larger ticks permanently take the counted
 # full-grid fallback (decode_overflow)
 _TRI_MAX = 1 << 18
+_TRI_CHUNKS = telemetry.counter(
+    "aoi.tri_chunks", "dirty 128-word chunks the triples extraction "
+    "compacted, summed over tri-mode ticks")
 
 
 def _device_fault(e: BaseException) -> bool:
@@ -329,8 +333,9 @@ def _fused_bucket_step_tri(prev_all, *args):
     fused kernel / scatter prologue as :func:`_fused_bucket_step`, but the
     diff compacts straight into fixed-capacity (observer, observed, kind)
     triples ON DEVICE (ops/events.py extract_triples) -- harvest then
-    fetches the compact triple buffer plus ONE count scalar, and the host
-    never unpacks a word again on the steady path.  The raw ``new``/``chg``
+    fetches the compact triple buffer plus ONE [count, dirty chunks]
+    scalar vector, and the host never unpacks a word again on the steady
+    path.  The raw ``new``/``chg``
     grids still ride donated scratch for the counted-overflow and
     poisoned-scalar recoveries (prev_all is donated, so the diff would
     otherwise be unrecoverable).
@@ -364,13 +369,13 @@ def _fused_bucket_step_tri(prev_all, *args):
                                     platform=platform)
             prev_all = prev_all.at[slot_idx].set(new)
             chg = jnp.where(sub[:, None, None], chg, jnp.uint32(0))
-            tri, count = EV.extract_triples(chg, new, chg.shape[1],
-                                            max_triples)
+            tri, count, chunks = EV.extract_triples(chg, new, chg.shape[1],
+                                                    max_triples)
             new_buf = new_buf.at[:].set(new)
             chg_buf = chg_buf.at[:].set(chg)
             tri_buf = tri_buf.at[:].set(tri)
             return (prev_all, new_buf, chg_buf, tri_buf,
-                    count.reshape(1))
+                    jnp.stack([count, chunks]))
 
         _fused_tri_impl = aoi_step_tri
     return _fused_tri_impl(prev_all, *args)
@@ -3178,8 +3183,9 @@ class _TPUBucket(_Bucket):
 
     def _harvest_tri(self, rec, seg) -> None:  # gwlint: allow[host-sync] -- triples-path drain point: fetches the compact triple buffer once per flush
         """Harvest one tri-mode tick: fetch the compact (observer, observed,
-        kind) triples + count scalar, XOR the mirror, and fan the pairs out
-        through the native/vector emit layer (docs/perf.md emit paths).
+        kind) triples + [count, chunks] scalars, XOR the mirror, and fan the
+        pairs out through the native/vector emit layer (docs/perf.md emit
+        paths).
         The ``aoi.fetch`` segment of ``seg`` is open on entry."""
         slots, s_n, mt = rec["slots"], rec["s_n"], rec["mt"]
         c = self.capacity
@@ -3193,6 +3199,8 @@ class _TPUBucket(_Bucket):
                 scalars = np.asarray(rec["scalars"])
             raw = faults.filter("aoi.scalars", scalars)
             count = int(raw[0])
+            if 0 <= raw[1] <= s_n * c * c // EV.CHUNK_WORDS:
+                _TRI_CHUNKS.inc(int(raw[1]))
             if not 0 <= count <= s_n * c * c:
                 from ..utils import gwlog
 

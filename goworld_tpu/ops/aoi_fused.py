@@ -64,9 +64,9 @@ def fused_tri_step(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
     """Fused triples-mode tick: scatter + kernel + diff + triple
     extraction in one program.
 
-    Returns ``(prev_all, new_buf, chg_buf, tri_buf, count[1], x_all,
-    z_all)`` -- the same scratch/rec shape as the unfused tri step plus
-    the rebound device x/z, so the existing tri harvest decodes the
+    Returns ``(prev_all, new_buf, chg_buf, tri_buf, [count, chunks],
+    x_all, z_all)`` -- the same scratch/rec shape as the unfused tri step
+    plus the rebound device x/z, so the existing tri harvest decodes the
     result unchanged."""
     global _tri_impl
     if _tri_impl is None:
@@ -96,13 +96,13 @@ def fused_tri_step(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
                                     platform=platform)
             prev_all = prev_all.at[slot_idx].set(new)
             chg = jnp.where(sub[:, None, None], chg, jnp.uint32(0))
-            tri, count = EV.extract_triples(chg, new, chg.shape[1],
-                                            max_triples)
+            tri, count, chunks = EV.extract_triples(chg, new, chg.shape[1],
+                                                    max_triples)
             new_buf = new_buf.at[:].set(new)
             chg_buf = chg_buf.at[:].set(chg)
             tri_buf = tri_buf.at[:].set(tri)
             return (prev_all, new_buf, chg_buf, tri_buf,
-                    count.reshape(1), x_all, z_all)
+                    jnp.stack([count, chunks]), x_all, z_all)
 
         _tri_impl = aoi_fused_tri
     # compile-key meter (steady-state recompiles = 0 pins): the static

@@ -19,8 +19,9 @@ compacted on device into fixed-capacity index lists (static shapes under jit).
 ``extract_triples(chg, new, capacity, max_triples)`` is the device-resident
 decode the production buckets run (docs/perf.md emit paths): it compacts a
 classified diff into fixed-capacity (observer, observed, kind) int32
-triples ON DEVICE, so harvest fetches the compact triple buffer plus one
-count scalar instead of word grids that still need host bit expansion.
+triples ON DEVICE, so harvest fetches the compact triple buffer plus two
+scalars (the bit count and the dirty-chunk count) instead of word grids
+that still need host bit expansion.
 
 The paged layout (:mod:`goworld_tpu.ops.aoi_pages`, docs/perf.md paged
 storage) carries the same ``(gidx, chg_word, new_word)`` entries this
@@ -382,70 +383,97 @@ def expand_classified_host(chg_vals, ent_vals, flat_idx, capacity: int,  # gwlin
             _sorted_pairs(s[~is_ent], i[~is_ent], j[~is_ent], capacity))
 
 
+CHUNK_WORDS = 128  # words per chunk of extract_triples' compaction
+
+
 def extract_triples(chg, new, capacity: int, max_triples: int):
     """Classified diff words -> compact (observer, observed, kind) triples,
     entirely on device (docs/perf.md emit paths).
 
-    Two-pass compaction sized by an exact popcount (NOT a silent cap):
-    pass 1 compacts the nonzero WORDS of the flat change grid (there are at
-    most ``count`` of them, so the same ``max_triples`` budget covers both
-    passes on every non-overflow tick); pass 2 finds, for each output row,
-    the surviving word and bit holding the set BIT of that rank.  When
-    ``count > max_triples`` the triple buffer is incomplete and the caller
-    must fall back (a counted, per-tick event -- bucket ``decode_overflow``
-    stat), which is why the dropped pass-1 words never matter.
+    Chunk-first compaction sized by an exact popcount (NOT a silent cap).
+    The whole grid is read once, by a streaming reduction: the set bits of
+    each ``CHUNK_WORDS``-word chunk.  The dirty chunks are then compacted
+    in ascending order (each holds at least one set bit, so at most
+    ``count`` of them: ``min(chunks, max_triples)`` slots cover every
+    non-overflow tick), and each output row r finds the chunk, the word
+    and the bit holding the set bit of rank r.  Scatters and gathers run
+    over chunks and ranks only, never over the words of the whole grid.
+    When ``count > max_triples`` the triple buffer holds the first
+    ``max_triples`` set bits and the caller must fall back (a counted,
+    per-tick event -- bucket ``decode_overflow`` stat).
 
     ``chg``/``new`` are uint32 planar words of any leading shape whose flat
     word order defines the observer index: ``obs = flat_word // W`` (for
     the bucket grids [s_n, C, W] that is the global observer row
-    ``s * C + i``).  ``kind`` is 1 for enter (the bit's NEW interest state),
-    0 for leave.
+    ``s * C + i``); their size is a multiple of ``CHUNK_WORDS`` (bucket
+    capacities are multiples of 128).  ``kind`` is 1 for enter (the bit's
+    NEW interest state), 0 for leave.
 
-    Returns ``(tri [max_triples, 3] int32, count i32)``.  ``tri`` rows are
-    (-1, -1, -1)-filled past the real triples and UNSORTED (pass order is
-    (word, bit), not (observer, observed)); the emit layer
+    Returns ``(tri [max_triples, 3] int32, count i32, chunks i32)``, where
+    ``chunks`` counts the dirty chunks (the work the compaction sized).
+    ``tri`` rows are (-1, -1, -1)-filled past the real triples and UNSORTED
+    (pass order is (word, bit), not (observer, observed)); the emit layer
     (:mod:`goworld_tpu.ops.aoi_emit`) owns the deterministic callback-order
     sort.
     """
     w = words_per_row(capacity)
-    flat_c = chg.reshape(-1)
+    flat_c = chg.reshape(-1, CHUNK_WORDS)
     flat_n = new.reshape(-1)
-    count = popcount_total(chg)
-    (widx,) = jnp.nonzero(flat_c != jnp.uint32(0), size=max_triples,
-                          fill_value=-1)
-    wsel = jnp.maximum(widx, 0)
-    wvals = jnp.where(widx >= 0, flat_c[wsel], jnp.uint32(0))
-    nvals = jnp.where(widx >= 0, flat_n[wsel], jnp.uint32(0))
-    # pass 2: row r of the output is the bit whose rank among all set bits
-    # in (word, bit) order is r: its word is found by a binary search of
-    # the words' running popcount, its bit as the one with rank
-    # r - (bits in earlier words) inside that word.  Gathers over
-    # [max_triples] and [max_triples, 32] only.  (A nonzero over the
+    nc = flat_c.shape[0]
+    cbits = jnp.sum(jax.lax.population_count(flat_c), axis=1,
+                    dtype=jnp.int32)
+    dirty = cbits > 0
+    count = jnp.sum(cbits)
+    chunks = jnp.sum(dirty.astype(jnp.int32))
+    mc = min(nc, max_triples)
+    (cidx,) = jnp.nonzero(dirty, size=mc, fill_value=-1)
+    csel = jnp.maximum(cidx, 0)
+    cb = jnp.where(cidx >= 0, cbits[csel], 0)
+    cend = jnp.cumsum(cb)
+    # row r of the output is the bit whose rank among all set bits in
+    # (word, bit) order is r.  Its chunk slot is the number of chunk ends
+    # <= r: a prefix sum of the ends' histogram (the same scatter-and-scan
+    # nonzero uses, here over [max_triples] ranks and not a search loop).
+    r = jnp.arange(max_triples, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.cumsum(jnp.bincount(cend, length=max_triples)),
+                       mc - 1)
+    # one gather of (bits before the chunk, chunk index) per rank
+    cstart, crow = jnp.stack([cend - cb, csel], axis=1)[slot].T
+    nth = r - cstart
+    # its word: the lanes of its chunk whose running popcount is <= nth
+    # precede it; the largest of those sums counts the bits before it.
+    # The running popcount is a matmul with a triangle of ones (exact:
+    # int8 counts <= 32, int32 sums <= 4096), so the MXU does the scan.
+    rows = flat_c[crow]
+    pre = jnp.dot(jax.lax.population_count(rows).astype(jnp.int8),
+                  jnp.triu(jnp.ones((CHUNK_WORDS,) * 2, jnp.int8)),
+                  preferred_element_type=jnp.int32)
+    le = pre <= nth[:, None]
+    lane = jnp.minimum(jnp.sum(le.astype(jnp.int32), axis=1),
+                       CHUNK_WORDS - 1)
+    nth = nth - jnp.max(jnp.where(le, pre, 0), axis=1)
+    at_lane = jnp.arange(CHUNK_WORDS, dtype=jnp.int32)[None, :] \
+        == lane[:, None]
+    wv = jnp.sum(jnp.where(at_lane, rows, jnp.uint32(0)), axis=1)
+    g = crow * CHUNK_WORDS + lane
+    # its bit: the one with rank nth inside the word.  (A nonzero over the
     # [max_triples, 32] bit matrix gives the same rows, but its prefix sum
     # over 32x the elements cost the TPU compiler ~20 s per cap size,
     # stalling a served game's tick loop.)
     shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)[None, :]
-    pc = jax.lax.population_count(wvals).astype(jnp.int32)
-    end = jnp.cumsum(pc)
-    r = jnp.arange(max_triples, dtype=jnp.int32)
-    slot = jnp.minimum(jnp.searchsorted(end, r, side="right"),
-                       max_triples - 1)
-    wv = wvals[slot]
-    nth = r - (end[slot] - pc[slot])
     below = jax.lax.population_count(
         wv[:, None] & ((jnp.uint32(1) << shifts) - jnp.uint32(1)))
     hit = (((wv[:, None] >> shifts) & jnp.uint32(1)) != 0) \
         & (below.astype(jnp.int32) == nth[:, None])
     k = jnp.argmax(hit, axis=1).astype(jnp.uint32)
-    g = widx[slot]
     obs = g // w
     j = k.astype(jnp.int32) * w + g % w
-    kind = ((nvals[slot] >> k) & jnp.uint32(1)).astype(jnp.int32)
-    valid = r < end[-1]
+    kind = ((flat_n[g] >> k) & jnp.uint32(1)).astype(jnp.int32)
+    valid = r < cend[-1]
     tri = jnp.stack([jnp.where(valid, obs, -1),
                      jnp.where(valid, j, -1),
                      jnp.where(valid, kind, -1)], axis=1).astype(jnp.int32)
-    return tri, count
+    return tri, count, chunks
 
 
 def triples_to_words(tri, capacity: int):  # gwlint: allow[host-sync] -- pure numpy on already-fetched triples

@@ -336,33 +336,84 @@ def test_fanout_triples_vector_matches_host_expansion():
         np.testing.assert_array_equal(xl, wl)
 
 
-@pytest.mark.parametrize("max_triples", [4096, 256], ids=["fits", "capped"])
-def test_extract_triples_matches_loop_reference(max_triples):
+def _tri_grid(case):
+    """(chg, new, capacity, max_triples) of one extraction case."""
+    rng = np.random.default_rng(5)
+    s, c, dens, mt = {
+        "fits": (2, 256, 0.05, 4096),
+        "capped": (2, 256, 0.05, 256),
+        "empty": (2, 256, 0.0, 256),
+        # every word set: more dirty chunks (128) than triple slots
+        "all_dirty_capped": (2, 512, 1.0, 100),
+        "last_word": (2, 256, 0.0, 256),
+        # W = 12: 128-word chunks straddle rows and spaces
+        "3x384": (3, 384, 0.05, 4096),
+        "dense_overflow": (1, 1024, 0.9, 4096),
+    }[case]
+    w = c // 32
+    chg = np.where(rng.random((s, c, w)) < dens,
+                   rng.integers(1, 2**32, (s, c, w), dtype=np.uint64),
+                   0).astype(np.uint32)
+    if case == "last_word":
+        chg[-1, -1, -1] = 1 << 31
+    new = rng.integers(0, 2**32, (s, c, w), dtype=np.uint64).astype(np.uint32)
+    return chg, new, c, mt
+
+
+@pytest.mark.parametrize("case", ["fits", "capped", "empty",
+                                  "all_dirty_capped", "last_word", "3x384",
+                                  "dense_overflow"])
+def test_extract_triples_matches_loop_reference(case):
     """Device triple compaction vs a plain loop: the rows are every set
     bit of the change grid in (word, bit) order, (observer, observed,
     kind), -1-filled -- and the first ``max_triples`` of them when the
-    tick overflows (the caller then falls back on ``count``)."""
+    tick overflows (the caller then falls back on ``count``).  ``chunks``
+    is the number of nonzero 128-word chunks."""
     import jax.numpy as jnp
 
     from goworld_tpu.ops.aoi_predicate import words_per_row
 
-    rng = np.random.default_rng(5)
-    s, c = 2, 256
+    chg, new, c, max_triples = _tri_grid(case)
     w = words_per_row(c)
-    chg = np.where(rng.random((s, c, w)) < 0.05,
-                   rng.integers(1, 2**32, (s, c, w), dtype=np.uint64),
-                   0).astype(np.uint32)
-    new = rng.integers(0, 2**32, (s, c, w), dtype=np.uint64).astype(np.uint32)
     ref = []
     flat_c, flat_n = chg.reshape(-1), new.reshape(-1)
     for g in np.nonzero(flat_c)[0]:
         for k in range(32):
             if (int(flat_c[g]) >> k) & 1:
                 ref.append((g // w, k * w + g % w, (int(flat_n[g]) >> k) & 1))
-    tri, count = EV.extract_triples(jnp.asarray(chg), jnp.asarray(new), c,
-                                    max_triples)
+    if case == "all_dirty_capped":  # the cap falls inside a word
+        ends = np.cumsum([bin(int(v)).count("1") for v in flat_c])
+        assert max_triples not in ends and len(ref) > max_triples
+    tri, count, chunks = EV.extract_triples(jnp.asarray(chg),
+                                            jnp.asarray(new), c, max_triples)
     tri = np.asarray(tri)
     assert int(count) == len(ref)
+    assert int(chunks) == np.count_nonzero(
+        chg.reshape(-1, EV.CHUNK_WORDS).any(axis=1))
     n = min(len(ref), max_triples)
     assert tri[:n].tolist() == [list(r) for r in ref[:n]]
     assert (tri[n:] == -1).all()
+
+
+def test_extract_triples_scatters_chunks_not_words():
+    """HLO guard at the single-chip cell's shape (8 x 16384, 2^18 slots),
+    lowered only: no scatter has more updates than the grid has 128-word
+    chunks, and no search loop (``while``) is left -- the word-level
+    ``nonzero`` scattered all 67M words of the grid."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    s, c, mt = 8, 16384, 1 << 18
+    grid = jax.ShapeDtypeStruct((s, c, c // 32), jnp.uint32)
+    text = jax.jit(EV.extract_triples, static_argnums=(2, 3)).lower(
+        grid, grid, c, mt).as_text()
+    assert "stablehlo.while" not in text
+    sigs = re.findall(r'"stablehlo\.scatter".*?\}\) : \(([^)]*)\)', text,
+                      re.S)
+    assert sigs, "no scatter found: the guard reads the wrong text"
+    for sig in sigs:
+        updates = re.findall(r"tensor<([0-9x]+)x[a-z]", sig)[-1]
+        n = int(np.prod([int(d) for d in updates.split("x")]))
+        assert n <= s * c * (c // 32) // EV.CHUNK_WORDS, sig

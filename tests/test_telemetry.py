@@ -57,7 +57,7 @@ CANONICAL_NAMES = (
     "aoi.flush", "aoi.emit", "aoi.h2d", "aoi.stage", "aoi.kernel",
     "aoi.fetch", "aoi.wait", "aoi.diff", "aoi.decode", "aoi.fanout",
     "aoi.host_tick", "aoi.buckets", "aoi.calc_level", "aoi.emit_path",
-    "aoi.launches",
+    "aoi.launches", "aoi.tri_chunks",
     # paged ragged neighbor/event storage (ops/aoi_pages.py absorbers)
     "aoi.pages", "aoi.page_occupancy", "aoi.page_spills",
     # live migration / chip-loss failover (engine/placement.py): start
@@ -469,6 +469,40 @@ def test_sync_and_harvest_spans_nest_and_count():
     flushes = [(a, b) for nm, a, b in spans if nm == "aoi.flush"]
     assert not any(fa <= a and b <= fb for nm, a, b in spans
                    if nm == "aoi.emit" for fa, fb in flushes)
+
+
+def test_tri_chunks_counts_dirty_chunks_of_the_tick():
+    """``aoi.tri_chunks`` moves, over one tri-mode tick of known moves, by
+    the nonzero 128-word chunks of that tick's change grid (the XOR of
+    the bucket's interest words before and after it)."""
+    from goworld_tpu.ops import events as EV
+
+    cap, n = 256, 180
+    rng = np.random.default_rng(3)
+    eng = AOIEngine(default_backend="tpu", emit="vector")
+    hs = [eng.create_space(cap) for _ in range(2)]
+    act = np.zeros(cap, bool)
+    act[:n] = True
+    r = np.full(cap, 80.0, np.float32)
+    pos = [rng.uniform(0, 600, (2, cap)).astype(np.float32) for _ in hs]
+    for h, (x, z) in zip(hs, pos):
+        eng.submit(h, x, z, r, act)
+    eng.flush()
+    b = hs[0].bucket
+    assert b._emit == "vector" and not b.paged
+    before = np.array(b.prev, copy=True)
+    chunks = telemetry.registry().counter("aoi.tri_chunks")
+    telemetry.enable()
+    c0 = chunks.value
+    for h, (x, z) in zip(hs, pos):
+        eng.submit(h, x + rng.uniform(-15, 15, cap).astype(np.float32),
+                   z + rng.uniform(-15, 15, cap).astype(np.float32), r, act)
+    eng.flush()
+    slots = [h.slot for h in hs]
+    chg = (np.asarray(b.prev) ^ before)[slots]
+    want = np.count_nonzero(chg.reshape(-1, EV.CHUNK_WORDS).any(axis=1))
+    assert 0 < want < chg.size // EV.CHUNK_WORDS
+    assert chunks.value - c0 == want
 
 
 def test_streams_and_sync_records_identical_traced_and_untraced():
