@@ -90,11 +90,11 @@ def _batched_clear(prev_all, row_slots, row_ents, col_slots, col_words,
 _LANES = 128
 _MAX_GAPS = 2048    # escaped chunk-index deltas per flush
 _MAX_EXC = 32768    # exception triples (tail + multi-bit words) per flush
-# triples-path extraction cap ceiling: the [max_triples, 128] chunk rows
-# inside extract_triples (one gathered chunk and its int32 running
-# popcount per rank, ~128 MB each at 2^18) are the shape driver, so
-# growth stops here and larger ticks permanently take the counted
-# full-grid fallback (decode_overflow)
+# triples-path extraction cap ceiling up to a grid of 2^26 words: the
+# [max_triples, 128] chunk rows inside extract_triples (one gathered chunk
+# and its int32 running popcount per rank, ~128 MB each at 2^18) set the
+# step's memory, so growth stops at _tri_ceiling and larger ticks take
+# the counted full-grid fallback (decode_overflow)
 _TRI_MAX = 1 << 18
 _TRI_CHUNKS = telemetry.counter(
     "aoi.tri_chunks", "dirty 128-word chunks the triples extraction "
@@ -440,6 +440,34 @@ def _fused_bucket_step_paged(prev_all, *args):
 
         _fused_paged_impl = aoi_step_paged
     return _fused_paged_impl(prev_all, *args)
+
+
+_TRIPLES = telemetry.counter(
+    "aoi.triples", "triples the device extraction delivered, summed over "
+    "the tri-mode ticks that fit the cap")
+
+
+def _tri_ceiling(grid_words: int) -> int:
+    """Largest triples cap of a bucket whose grid holds ``grid_words``
+    words: _TRI_MAX up to 2^26 words, then the same share of the grid
+    (one triple slot per 256 words), so the extraction's [cap, 128]
+    intermediates stay about half a grid.  At 1 x 131072 (2^29 words)
+    that is 2^21, which holds a dense crowd's ~0.6M events a tick with
+    the decay's 1.5x margin."""
+    return max(_TRI_MAX, grid_words >> 8)
+
+
+def _full_diff(seg, chg, new):  # gwlint: allow[host-sync] -- the counted full-grid recovery: fetches a tick's raw grids once
+    """The full-grid host recovery of one tick (a cap overflow, a spill or
+    corrupt control scalars): fetch the raw change and NEW grids and list
+    the changed words, as the ``aoi.overflow`` segment of ``seg``.
+    Returns ``(gidx, chg_vals, ent_vals)`` in ascending word order."""
+    seg.begin("aoi.overflow", "fetch_s")
+    chg_h = np.asarray(chg).reshape(-1)
+    new_h = np.asarray(new).reshape(-1)
+    gidx = np.nonzero(chg_h)[0]
+    chg_vals = chg_h[gidx]
+    return gidx, chg_vals, chg_vals & new_h[gidx]
 
 
 class _CapDecay:
@@ -1396,7 +1424,12 @@ class AOIEngine:
                       "(0=native 1=vector 2=host decode)"),
                Sample("aoi.page_occupancy", "gauge", page_occ, lbl,
                       "fullest page pool at last harvest "
-                      "(used/total pages; paged buckets only)")]
+                      "(used/total pages; paged buckets only)"),
+               Sample("aoi.scratch_bytes", "gauge",
+                      sum(b.scratch_bytes() for b in self._buckets.values()
+                          if isinstance(b, _TPUBucket)), lbl,
+                      "device bytes held by the single-chip buckets' "
+                      "scratch pools")]
         for k in sorted(stats):
             out.append(Sample("aoi." + k, "counter", stats[k], lbl,
                               "summed per-bucket AOI stat"))
@@ -1828,13 +1861,15 @@ class _TPUBucket(_Bucket):
         self._kcap = 8
         self._caps = _CapDecay(nd_floor=4096)
         # triples-path extraction cap (native/vector emit): grows on a
-        # counted overflow up to _TRI_MAX, decays back via _tri
+        # counted overflow up to _tri_ceiling, decays back via _tri
         self._max_triples = 16384
         self._tri = _TriCapDecay(floor=16384)
         # optimistic triple-buffer prefetch rows for the pipelined path
         self._pred_tri = 2048
-        # donated scratch buffers, keyed (s_n, mc, kcap); replaced by each
-        # flush's returns (same device memory, in-place)
+        # donated scratch buffers, keyed by _scratch_key; replaced by each
+        # flush's returns (same device memory, in-place).  Only keys of
+        # the current caps stay: a set sized for a cap the bucket left is
+        # dropped (4 GiB a set at 1 x 131072)
         self._scratch: dict[tuple, tuple] = {}
         # encode-side caps (instance attrs so overflow tests can shrink them)
         self._max_gaps = _MAX_GAPS
@@ -1943,6 +1978,35 @@ class _TPUBucket(_Bucket):
         if self._emit != "host":
             return self._tri.steady
         return self._caps.steady
+
+    def _scratch_key(self, s_n: int) -> tuple:
+        """The scratch-pool key of a dispatch of ``s_n`` staged slots
+        under the current mode and caps: mc = -2 names the paged sets,
+        -1 the triples sets, and a chunk cap (>= 512) the classic ones."""
+        if self.paged:
+            return (s_n, -2, self._n_pages)
+        if self._emit != "host":
+            return (s_n, -1, self._max_triples)
+        n_chunks_total = s_n * self.capacity * self.W // _LANES
+        return (s_n, min(self._max_chunks, max(n_chunks_total, 512)),
+                self._kcap)
+
+    def _scratch_prune(self) -> None:
+        """Drop the pooled sets whose key the mode or caps have left: they
+        can never be reused, and would pin their grids on the device."""
+        for k in [k for k in self._scratch if k != self._scratch_key(k[0])]:
+            del self._scratch[k]
+
+    def _scratch_put(self, rec) -> None:
+        """Return a harvested record's scratch set to the pool, unless the
+        caps have left its key (then it goes with the other stale sets)."""
+        self._scratch.setdefault(rec["key"], rec["scratch"])
+        self._scratch_prune()
+
+    def scratch_bytes(self) -> int:
+        """Device bytes held by the scratch pool (the ``aoi.scratch_bytes``
+        gauge); a set out with an unharvested tick is not counted."""
+        return sum(a.nbytes for s in self._scratch.values() for a in s)
 
     def _grow_to(self, n_slots: int) -> None:
         jnp = self._jnp
@@ -2240,23 +2304,15 @@ class _TPUBucket(_Bucket):
                     or self._page_free.shape[0] != want:
                 self._n_pages = want
                 self._page_free = jnp.arange(want, dtype=jnp.int32)
-            key = (s_n, -2, self._n_pages)
-        elif tri_mode:
-            # triples path (docs/perf.md emit paths): the decode happens ON
-            # DEVICE; harvest fetches [count, 3] triples + one scalar.  The
-            # scratch key uses mc=-1 as the tri namespace (classic mc >= 512)
-            mt = self._max_triples
-            key = (s_n, -1, mt)
-        else:
-            n_chunks_total = s_n * c * self.W // _LANES
-            mc = min(self._max_chunks, max(n_chunks_total, 512))
-            key = (s_n, mc, self._kcap)
+        key = self._scratch_key(s_n)
+        mc, mt = key[1], self._max_triples
         scratch = self._scratch.pop(key, None)
         if scratch is None:
             # keep a few shape variants so alternating staged-slot counts
             # still reuse donated memory; evict oldest beyond that.  The
             # pipeline holds one extra set in flight, so the pool plus the
             # inflight record double-buffer naturally.
+            self._scratch_prune()
             while len(self._scratch) >= 4:
                 self._scratch.pop(next(iter(self._scratch)))
             if self.paged:
@@ -2882,11 +2938,7 @@ class _TPUBucket(_Bucket):
         if poisoned:
             # full-diff recovery (same shape as the cap-overflow branch,
             # without growing the caps off corrupted values)
-            chg_h = np.asarray(chg).reshape(-1)
-            new_h = np.asarray(new).reshape(-1)
-            gidx = np.nonzero(chg_h)[0]
-            chg_vals = chg_h[gidx]
-            ent_vals = chg_vals & new_h[gidx]
+            gidx, chg_vals, ent_vals = _full_diff(seg, chg, new)
         elif nd == 0 and exc_n == 0:
             # quiet tick (or every staged slot unsubscribed): the stream is
             # empty by construction -- the scalars above are the ONLY fetch
@@ -2901,11 +2953,7 @@ class _TPUBucket(_Bucket):
             # a chunk holds at most _LANES nonzero words
             self._kcap = min(max(self._kcap, 2 * mcc), _LANES)
             self._caps.reset_after_growth()
-            chg_h = np.asarray(chg).reshape(-1)
-            new_h = np.asarray(new).reshape(-1)
-            gidx = np.nonzero(chg_h)[0]
-            chg_vals = chg_h[gidx]
-            ent_vals = chg_vals & new_h[gidx]
+            gidx, chg_vals, ent_vals = _full_diff(seg, chg, new)
         elif n_esc > self._max_gaps or exc_n > self._max_exc:
             # encode overflow (pathological churn): rebuild from the raw
             # grids kept on device
@@ -2949,7 +2997,7 @@ class _TPUBucket(_Bucket):
         )
         self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
         # the harvested scratch set returns to the pool for reuse
-        self._scratch.setdefault(rec["key"], rec["scratch"])
+        self._scratch_put(rec)
         self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx, s_n)
 
     def _mirror_xor_stream(self, slots, epochs, gidx, chg_vals) -> None:  # gwlint: allow[host-sync] -- harvest-phase mirror upkeep on already-fetched host arrays
@@ -3111,14 +3159,10 @@ class _TPUBucket(_Bucket):
                 # the ceiling.  A fault-caused spill says nothing about
                 # size, so it only doubles.
                 self._grow_pool(nw, bw, full=page_fault is None)
-            chg_h = np.asarray(chg).reshape(-1)
-            new_h = np.asarray(new).reshape(-1)
-            gidx = np.nonzero(chg_h)[0]
-            chg_vals = chg_h[gidx]
-            ent_vals = chg_vals & new_h[gidx]
+            gidx, chg_vals, ent_vals = _full_diff(seg, chg, new)
             seg.begin("aoi.diff", "decode_s")
             self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
-            self._scratch.setdefault(rec["key"], rec["scratch"])
+            self._scratch_put(rec)
             self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx,
                           s_n)
             return
@@ -3178,7 +3222,7 @@ class _TPUBucket(_Bucket):
         self.stats["page_occupancy"] = (n_used / n_pages) if n_pages else 0.0
         seg.begin("aoi.diff", "decode_s")
         self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
-        self._scratch.setdefault(rec["key"], rec["scratch"])
+        self._scratch_put(rec)
         self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx, s_n)
 
     def _harvest_tri(self, rec, seg) -> None:  # gwlint: allow[host-sync] -- triples-path drain point: fetches the compact triple buffer once per flush
@@ -3221,21 +3265,19 @@ class _TPUBucket(_Bucket):
             # docs/robustness.md)
             if not poisoned:
                 self.stats["decode_overflow"] += 1
-                if self._max_triples < _TRI_MAX:
+                ceiling = _tri_ceiling(self.s_max * c * self.W)
+                if self._max_triples < ceiling:
                     self._max_triples = min(
-                        _TRI_MAX, 1 << (2 * count - 1).bit_length())
+                        ceiling, 1 << (2 * count - 1).bit_length())
                 self._tri.reset_after_growth()
-            chg_h = np.asarray(chg).reshape(-1)
-            new_h = np.asarray(new).reshape(-1)
-            gidx = np.nonzero(chg_h)[0]
-            chg_vals = chg_h[gidx]
-            ent_vals = chg_vals & new_h[gidx]
+            gidx, chg_vals, ent_vals = _full_diff(seg, chg, new)
             seg.begin("aoi.diff", "decode_s")
             self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
-            self._scratch.setdefault(rec["key"], rec["scratch"])
+            self._scratch_put(rec)
             self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx,
                           s_n)
             return
+        _TRIPLES.inc(count)
         if count == 0:
             tri_h = np.empty((0, 3), np.int32)
         else:
@@ -3243,12 +3285,16 @@ class _TPUBucket(_Bucket):
             if pf is not None and pf[0] >= count:
                 tri_h = np.asarray(pf[1])[:count]
             else:
-                ndp = min(mt, -(-count // 256) * 256)
-                # an eager slice: one launch, and one compile per new
-                # count bucket
-                DC.record()
-                DC.record_key("aoi.tri_slice", (tri.shape, ndp))
-                sl_tri = tri[:ndp]
+                # the count rounded up to a power of two: a few slice
+                # programs per cap, all met while the count settles
+                ndp = min(mt, max(256, 1 << (count - 1).bit_length()))
+                sl_tri = tri
+                if ndp < mt:
+                    # an eager slice: one launch, and one compile per
+                    # new size
+                    DC.record()
+                    DC.record_key("aoi.tri_slice", (tri.shape, ndp))
+                    sl_tri = tri[:ndp]
                 sl_tri.copy_to_host_async()
                 tri_h = np.asarray(sl_tri)[:count]
         seg.begin("aoi.decode", "decode_s")
@@ -3259,7 +3305,7 @@ class _TPUBucket(_Bucket):
             if len(tri_h):
                 self._mirror_xor_triples(slots, rec["epochs"], tri_h)
             self._apply_deferred_mirror_ops()
-        self._scratch.setdefault(rec["key"], rec["scratch"])
+        self._scratch_put(rec)
         seg.begin("aoi.fanout", "emit_s")
         try:
             faults.check("aoi.emit")

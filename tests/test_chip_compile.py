@@ -96,6 +96,41 @@ def test_fused_tri_step_compiles_the_kernel(one_chip):
     _assert_kernel(lowered.compile())
 
 
+def test_single_chip_tri_step_fits_the_crowd_space(one_chip):
+    """The single-chip bucket's triples step at one 131072-entity space
+    (BASELINE's 100k Zipf crowd), at the ceiling its grid allows: the
+    kernel reaches the TPU compiler and the program's device bytes fit a
+    v5e's 16 GiB with room for the host's own buffers.  Donated grids
+    come back in place, so the outputs that alias an argument are counted
+    once."""
+    from goworld_tpu.engine import aoi as A
+
+    s, c = 1, 131072
+    w = words_per_row(c)
+    mt = A._tri_ceiling(s * c * w)
+    assert mt >= 1 << 20
+    if A._fused_tri_impl is None:
+        # the step program is built on its first call: a tiny one here
+        tiny = jnp.zeros((1, 128, words_per_row(128)), jnp.uint32)
+        f0 = jnp.zeros((1, 128), jnp.float32)
+        A._fused_bucket_step_tri(
+            tiny, tiny + 0, tiny + 0, jnp.full((16, 3), -1, jnp.int32),
+            jnp.zeros(1, jnp.int32), f0, f0, f0, jnp.zeros((1, 128), bool),
+            jnp.ones(1, bool), 16, None)
+    grid = _spec((s, c, w), jnp.uint32, one_chip)
+    f32 = _spec((s, c), jnp.float32, one_chip)
+    compiled = A._fused_tri_impl.lower(
+        grid, grid, grid, _spec((mt, 3), jnp.int32, one_chip),
+        _spec((s,), jnp.int32, one_chip), f32, f32, f32,
+        _spec((s, c), jnp.bool_, one_chip), _spec((s,), jnp.bool_, one_chip),
+        mt, "tpu").compile()
+    _assert_kernel(compiled)
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert held < 14 * 2**30, m
+
+
 def test_sharded_step_compiles_over_four_chips(topo):
     """The space-sharded tick with chip-local chunk extraction over the
     four described chips: the kernel on every chip, one psum."""
