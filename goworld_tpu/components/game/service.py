@@ -768,18 +768,17 @@ class GameService:
             )
 
     def _send_position_syncs(self):
-        records = self.rt.drain_sync()
-        if not records:
+        batch = self.rt.drain_sync()
+        if not batch:
             return
         per_gate: dict[int, Packet] = {}
         with _trace.span("game.sync_pack"):
-            for client_id, gate_id, eid, x, y, z, yaw in records:
-                p = per_gate.get(gate_id)
-                if p is None:
-                    p = GWConnection.make_sync_on_clients_packet(gate_id)
-                    per_gate[gate_id] = p
-                GWConnection.append_sync_record(p, client_id, eid, x, y, z,
-                                                yaw)
+            # the batch's columns are the 48-byte wire records already
+            # (engine/sync.py): one bytes copy per gate
+            for gate_id, raw in sorted(batch.pack_by_gate().items()):
+                p = GWConnection.make_sync_on_clients_packet(gate_id)
+                p.append_bytes(raw)
+                per_gate[gate_id] = p
         traced = telemetry.enabled()
         for gate_id, p in per_gate.items():
             conn = self.cluster.by_gate(gate_id)
@@ -1031,7 +1030,10 @@ class GameService:
                     other.interested_by.add(e)
                     if e.client is not None:
                         other._watcher_clients += 1
-                        other._touch_watched()
+                # the restored pairs of a watching client enter its space's
+                # watch index (engine/space.py), as a bound client's do
+                if e.client is not None:
+                    e._touch_client()
             from ...ops import aoi_predicate as AP
             import numpy as np
 

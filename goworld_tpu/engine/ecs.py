@@ -28,13 +28,15 @@ slot its position/yaw reads are therefore f32-quantized; the f64
 ``Vector3`` snapshot is re-materialized from the columns when the
 entity leaves its slot.
 
-The companion columns (y/yaw/sync/watched) are host-only: they exist so
-the ingest and batched-move paths can update height/yaw and flag
-position sync fully vectorized.  ``sync`` holds pending SYNC_* flags
-per slot (drained by ``Space.drain_column_sync`` into the runtime's
-dirty-entity machinery); ``watched`` mirrors "some client can see this
-entity" (``_watcher_clients > 0 or client is not None``) so the drain
-touches only entities whose movement anyone observes.
+The companion columns (y/yaw/sync/client/cid/gate/eid) are host-only:
+they exist so the ingest and batched-move paths can update height/yaw
+and flag position sync fully vectorized, and so the sync phase builds
+its records as arrays (``Space.collect_sync``).  ``sync`` holds pending
+SYNC_* flags per slot; ``client`` says the slot's entity has a client,
+whose id bytes and gate ``cid``/``gate`` hold (kept by the client-binding
+sites, ``Entity._touch_client``); ``eid`` holds the entity id bytes,
+written when the slot is assigned.  Ids are fixed-width bytes, widened
+if an id longer than 16 bytes is ever written (the wire's ids are 16).
 """
 
 from __future__ import annotations
@@ -49,7 +51,9 @@ from .vector import Vector3
 # with a team_mask policy: observer A sees B iff vis[A] & team[B] != 0
 HOT_DEVICE_COLUMNS = ("x", "z", "r", "act", "nonplain", "team", "vis")
 # host-only companions enabling fully vectorized ingest + sync flagging
-HOST_COLUMNS = ("y", "yaw", "sync", "watched")
+HOST_COLUMNS = ("y", "yaw", "sync", "client", "cid", "gate", "eid")
+# ids as the wire carries them: 16 ascii bytes (netutil/packet.py)
+ID_BYTES = 16
 
 
 class ColumnStore:
@@ -57,7 +61,7 @@ class ColumnStore:
     indices are stable for the space's lifetime)."""
 
     __slots__ = ("cap", "x", "z", "r", "act", "nonplain", "team", "vis",
-                 "y", "yaw", "sync", "watched")
+                 "y", "yaw", "sync", "client", "cid", "gate", "eid")
 
     def __init__(self):
         self.cap = 0
@@ -71,7 +75,10 @@ class ColumnStore:
         self.y = np.empty(0, np.float32)
         self.yaw = np.empty(0, np.float32)
         self.sync = np.zeros(0, np.uint8)
-        self.watched = np.zeros(0, bool)
+        self.client = np.zeros(0, bool)
+        self.cid = np.zeros(0, "S%d" % ID_BYTES)
+        self.gate = np.zeros(0, np.int64)
+        self.eid = np.zeros(0, "S%d" % ID_BYTES)
 
     def ensure_capacity(self, new_cap: int):
         if new_cap <= self.cap:
@@ -83,9 +90,10 @@ class ColumnStore:
             setattr(self, name, grown)
         for name, dt in (("act", bool), ("nonplain", bool),
                          ("team", np.uint32), ("vis", np.uint32),
-                         ("sync", np.uint8), ("watched", bool)):
+                         ("sync", np.uint8), ("client", bool),
+                         ("cid", None), ("gate", np.int64), ("eid", None)):
             arr = getattr(self, name)
-            grown = np.zeros(new_cap, dt)
+            grown = np.zeros(new_cap, dt or arr.dtype)
             grown[: len(arr)] = arr
             setattr(self, name, grown)
         self.cap = new_cap
@@ -98,7 +106,20 @@ class ColumnStore:
         self.team[slot] = 0
         self.vis[slot] = 0
         self.sync[slot] = 0
-        self.watched[slot] = False
+        self.client[slot] = False
+        self.cid[slot] = b""
+        self.gate[slot] = 0
+        self.eid[slot] = b""
+
+    def put_id(self, name: str, slot: int, ident: str):
+        """Write an id's bytes into the ``cid`` or ``eid`` column, widening
+        the column first if the id does not fit."""
+        raw = ident.encode()
+        arr = getattr(self, name)
+        if len(raw) > arr.itemsize:
+            arr = arr.astype("S%d" % len(raw))
+            setattr(self, name, arr)
+        arr[slot] = raw
 
 
 class PositionView(Vector3):

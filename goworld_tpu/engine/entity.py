@@ -155,15 +155,15 @@ class Entity:
         if self.aoi_slot >= 0 and self.space is not None:
             self.space._nonplain[self.aoi_slot] = not self._plain_aoi
 
-    def _touch_watched(self):
-        """Mirror "some client can see this entity" into the space's
-        ``watched`` column (engine/ecs.py) -- the vectorized ingest path's
-        sync drain filters flagged movers by it, so it must track every
-        _watcher_clients / client transition while slotted."""
-        slot = self.aoi_slot
-        if slot >= 0 and self.space is not None:
-            self.space._cols.watched[slot] = (
-                self._watcher_clients > 0 or self.client is not None)
+    def _touch_client(self):
+        """Mirror the client binding into the space's client columns
+        (engine/ecs.py) and recompute this entity's pairs in the space's
+        watch index (engine/space.py) -- the columnar sync collect reads
+        both, so every client transition while slotted comes here."""
+        sp = self.space
+        if self.aoi_slot >= 0 and sp is not None:
+            sp._set_client_cols(self.aoi_slot, self.client)
+            sp._rewatch(self)
 
     @property
     def is_space(self) -> bool:
@@ -354,7 +354,6 @@ class Entity:
             other._flush_attr_deltas()
         if other not in self.interested_in and self.client is not None:
             other._watcher_clients += 1
-            other._touch_watched()
         self.interested_in.add(other)
         other.interested_by.add(self)
         if self.client is not None:
@@ -364,7 +363,6 @@ class Entity:
     def _uninterest(self, other: "Entity"):
         if other in self.interested_in and self.client is not None:
             other._watcher_clients -= 1
-            other._touch_watched()
         self.interested_in.discard(other)
         other.interested_by.discard(self)
         if self.client is not None:
@@ -420,9 +418,8 @@ class Entity:
             return
         for other in self.interested_in:
             other._watcher_clients -= 1
-            other._touch_watched()
         self.client = None
-        self._touch_watched()
+        self._touch_client()
         self._recompute_plain()
         if self._plain_aoi:
             self._dematerialize_interests()
@@ -435,9 +432,8 @@ class Entity:
             for other in self.interested_in:
                 old.destroy_entity(other)
                 other._watcher_clients -= 1
-                other._touch_watched()
             self.client = None
-            self._touch_watched()
+            self._touch_client()
             self.on_client_disconnected()
         if client is not None:
             if was_plain:
@@ -446,14 +442,13 @@ class Entity:
                 self._materialize_interests()
             for other in self.interested_in:
                 other._watcher_clients += 1
-                other._touch_watched()
             # flush pending deltas to the old audiences first -- the
             # snapshots below already contain them (see _interest)
             self._flush_attr_deltas()
             for other in self.interested_in:
                 other._flush_attr_deltas()
             self.client = client
-            self._touch_watched()
+            self._touch_client()
             client.create_entity(self, is_player=True)
             for other in self.interested_in:
                 client.create_entity(other, is_player=False)

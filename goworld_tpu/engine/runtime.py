@@ -13,7 +13,9 @@ Tick phases (order matters and is part of the engine contract):
                       replay enter/leave events through entity hooks;
   3. sync          -- collect position/yaw records for every entity flagged
                       dirty (reference: CollectEntitySyncInfos,
-                      Entity.go:1221-1267) and flush attr deltas;
+                      Entity.go:1221-1267), as columns per space from its
+                      watch index (engine/space.py, engine/sync.py), and
+                      flush attr deltas;
   4. post          -- callbacks queued by workers/IO during the tick.
 """
 
@@ -23,6 +25,8 @@ import os
 import time
 from typing import Callable
 
+import numpy as np
+
 from .. import faults, telemetry
 from ..telemetry import trace as _trace
 from ..utils.crontab import Crontab
@@ -31,6 +35,7 @@ from .entity import SYNC_NEIGHBORS, SYNC_OWN, Entity
 from .manager import EntityManager
 from .placement import PlacementController
 from .post import PostQueue
+from .sync import SyncBatch
 from .timers import TimerQueue
 
 # whole-tick latency histogram (pow2 buckets -> p50/p99 at /debug/metrics);
@@ -41,6 +46,10 @@ _TICK_SECONDS = telemetry.histogram(
 # watching client), counted once per tick
 _SYNC_RECORDS = telemetry.counter(
     "sync.records", "position-sync records collected by the sync phase")
+# the records of them built one at a time: slotless entities (no AOI slot,
+# so no columns); 0 where every mover holds a slot
+_SYNC_WALKED = telemetry.counter(
+    "sync.walked", "position-sync records built by the per-entity walk")
 
 # SLO gate: a tick over this budget trips the flight recorder (0 = off).
 # Env-configured -- the budget is an ops knob, not an engine parameter.
@@ -157,13 +166,12 @@ class Runtime:
         # tick -- Entity.go:1221-1267 -- which compiled Go affords)
         self._dirty_entities: set[Entity] = set()
         # spaces whose sync COLUMN holds pending flags (vectorized ingest
-        # writes -- engine/ecs.py): drained at the head of the sync phase
-        # into the per-entity dirty machinery.  A dict used as an ordered
-        # set: drain order stays insertion order (deterministic)
+        # writes -- engine/ecs.py), read at the head of the sync phase.  A
+        # dict used as an ordered set
         self._col_sync_spaces: dict = {}
-        # position sync records collected this tick:
-        # (client_id, gate_id, entity_id, x, y, z, yaw)
-        self.sync_out: list[tuple] = []
+        # position sync records collected since the last drain_sync, as
+        # columns; iterates as (client_id, gate_id, entity_id, x, y, z, yaw)
+        self.sync_out = SyncBatch()
         # optional hooks set by the hosting component (GameService): called
         # when entities register/unregister so the dispatcher directory stays
         # current (reference: MT_NOTIFY_CREATE_ENTITY/DESTROY)
@@ -261,51 +269,78 @@ class Runtime:
         (entities self-register via Entity._mark_dirty; idle entities cost
         nothing per tick).  The dirty set object is STABLE -- entities cache
         a reference to it (Entity._dirty_set) -- so it is drained in place,
-        never swapped.  The common steady-state case (no client, nobody's
-        client watching) exits after two integer tests."""
-        # fold pending sync-column flags (batched ingest) into the dirty
-        # machinery first, so batched and per-entity movement emit through
-        # one path -- exactly-once per entity per tick
+        never swapped.
+
+        Records are built per space as columns (Space.collect_sync): each
+        slotted mover's flags -- from the space's sync column (batched
+        ingest) and from the entity's ``_sync_flags`` (per-entity moves)
+        -- land in one per-slot vector, and the space's watch index names
+        the watching clients.  Only slotless entities, which have no
+        columns, still build their records one at a time."""
         css = self._col_sync_spaces
+        ds = self._dirty_entities
+        if not css and not ds:
+            return
+        flags: dict = {}  # space -> pending flags per slot
         if css:
             with _trace.span("sync.columns"):
                 for sp in css:
-                    sp.drain_column_sync()
+                    flags[sp] = sp.take_column_sync()
                 css.clear()
-        ds = self._dirty_entities
-        if not ds:
-            return
-        n0 = len(self.sync_out)
         with _trace.span("sync.collect"):
+            folds: dict = {}  # space -> ([slots], [flags]) of dirty movers
+            walked: list[tuple] = []
             dirty = list(ds)
             ds.clear()
             for e in dirty:
                 if e.destroyed:
                     continue
-                flags = e._sync_flags
-                if flags:
+                f = e._sync_flags
+                if f:
                     e._sync_flags = 0
-                    if (e.client is not None or
-                            (flags & SYNC_NEIGHBORS
-                             and e._watcher_clients > 0)):
-                        self._collect_sync(e, flags)
+                    slot = e.aoi_slot
+                    if slot >= 0:
+                        fold = folds.get(e.space)
+                        if fold is None:
+                            fold = folds[e.space] = ([], [])
+                        fold[0].append(slot)
+                        fold[1].append(f)
+                    elif (e.client is not None or
+                            (f & SYNC_NEIGHBORS and e._watcher_clients > 0)):
+                        self._walk_sync(e, f, walked)
                 if e._attr_deltas:
                     e._flush_attr_deltas()
-        # once per tick, never per record: the count the span divides
-        _SYNC_RECORDS.inc(len(self.sync_out) - n0)
+            out = self.sync_out
+            n0 = len(out)
+            for sp in sorted(flags.keys() | folds.keys(),
+                             key=lambda sp: sp.id):
+                f = flags.get(sp)
+                if f is None:
+                    f = np.zeros(sp._slot_watermark, np.uint8)
+                fold = folds.get(sp)
+                if fold is not None:
+                    f[fold[0]] |= np.array(fold[1], np.uint8)
+                part = sp.collect_sync(f)
+                if part is not None:
+                    out.add(part)
+            out.add_walked(walked)
+        # once per tick, never per record: the counts the span divides
+        _SYNC_RECORDS.inc(len(out) - n0)
+        _SYNC_WALKED.inc(len(walked))
 
-    def _collect_sync(self, e: Entity, flags: int):
-        """One 16-byte-payload record per flagged entity per tick
-        (reference record layout: proto.go:135-139)."""
+    @staticmethod
+    def _walk_sync(e: Entity, flags: int, out: list):
+        """The records of one slotless entity (reference record layout:
+        proto.go:135-139)."""
         x, y, z = e.position.to_tuple()
         if flags & SYNC_OWN and e.client is not None:
-            self.sync_out.append(
+            out.append(
                 (e.client.client_id, e.client.gate_id, e.id, x, y, z, e.yaw)
             )
         if flags & SYNC_NEIGHBORS and e._watcher_clients > 0:
             for other in e.interested_by:
                 if other.client is not None:
-                    self.sync_out.append(
+                    out.append(
                         (
                             other.client.client_id,
                             other.client.gate_id,
@@ -317,7 +352,7 @@ class Runtime:
                         )
                     )
 
-    def drain_sync(self) -> list[tuple]:
+    def drain_sync(self) -> SyncBatch:
         out = self.sync_out
-        self.sync_out = []
+        self.sync_out = SyncBatch()
         return out

@@ -20,14 +20,33 @@ disabled where entities live when not in a real space.
 
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 
 from .ecs import ColumnStore
-from .entity import Entity
+from .entity import SYNC_NEIGHBORS, SYNC_OWN, Entity
 from .vector import Vector3
 
 SPACE_TYPE_NAME = "__space__"
 _MIN_CAPACITY = 128
+# watch-index codes: target slot << _SHIFT | watching observer slot
+_SHIFT = 32
+_LOW = (1 << _SHIFT) - 1
+
+
+class _Replay:
+    """One event replay in progress (``Space._replay``): its pairs, their
+    entities, and how many were replayed / folded into the watch index."""
+
+    __slots__ = ("pairs", "obs", "tgt", "enter", "done", "applied", "moved")
+
+    def __init__(self, pairs, obs, tgt, enter):
+        self.pairs, self.obs, self.tgt, self.enter = pairs, obs, tgt, enter
+        self.done = self.applied = 0
+        # an entity left this space during the replay, so a pair's
+        # entities may no longer sit at the pair's slots
+        self.moved = False
 
 
 class Space(Entity):
@@ -41,7 +60,7 @@ class Space(Entity):
         self._aoi_handle = None
         self._aoi_default_dist = 0.0
         # columnar ECS store (engine/ecs.py): the hot per-slot attributes
-        # (x/z/r/act/nonplain + the y/yaw/sync/watched host companions)
+        # (x/z/r/act/nonplain + the y/yaw/sync/client/id host companions)
         # as capacity-sized arrays grown by doubling.  Entities hold VIEWS
         # into these columns while slotted (Entity.position); submit_aoi
         # hands the calculator the columns themselves, so the flush()
@@ -68,6 +87,14 @@ class Space(Entity):
         # with no nonplain entity opts out (set_subscribed) so device
         # backends skip its extraction/fetch/decode entirely
         self._aoi_subscribed = True
+        # the watch index: every (watching observer, target) slot pair with
+        # the observer in target.interested_by and observer.client set, as
+        # sorted int64 codes target << 32 | observer.  Event replay keeps
+        # it with whole-array operations (_replay); the sites that bind a
+        # client or free a slot keep it directly (_rewatch, leave_entity).
+        # collect_sync reads it: no per-record walk builds sync records
+        self._watch = np.empty(0, np.int64)
+        self._rp: _Replay | None = None
 
     @property
     def is_space(self) -> bool:
@@ -226,8 +253,8 @@ class Space(Entity):
             cols.team[slot] = np.uint32(1)
             cols.vis[slot] = np.uint32(0xFFFFFFFF)
             cols.sync[slot] = 0
-            cols.watched[slot] = (e._watcher_clients > 0
-                                  or e.client is not None)
+            cols.put_id("eid", slot, e.id)
+            self._set_client_cols(slot, e.client)
             self._aoi_dirty = True
         if not is_restore:
             self.on_entity_enter_space(e)
@@ -256,6 +283,16 @@ class Space(Entity):
             p.y = float(cols.y[slot])
             p.z = float(cols.z[slot])
             e._yaw = float(cols.yaw[slot])
+            # fold what a replay in progress did so far (it places later
+            # pairs anew), then drop every watch pair of the slot: its
+            # interests are severed below
+            self._settle_watch()
+            if self._rp is not None:
+                self._rp.moved = True
+            w = self._watch
+            if len(w):
+                self._watch = w[((w >> _SHIFT) != slot)
+                                & ((w & _LOW) != slot)]
             cols.clear_slot(slot)
             self._slot_entity[slot] = None
             self._slot_np[slot] = None
@@ -376,62 +413,157 @@ class Space(Entity):
         self._aoi_dirty = False
         return True
 
-    def drain_column_sync(self):
-        """Fold pending column sync flags (set vectorized by the batched
-        ingest path, goworld_tpu/ingest/) into the per-entity sync
-        machinery.  One vectorized scan finds flagged slots; only WATCHED
-        movers (some client can see them -- the ``watched`` column) pay a
-        per-entity visit, which routes through ``_sync_flags`` +
-        the dirty set so records emit exactly once per entity per tick
-        even when batched and per-entity writes mix."""
+    def take_column_sync(self) -> np.ndarray:
+        """The pending column sync flags (set vectorized by the batched
+        ingest path, goworld_tpu/ingest/), one per slot up to the
+        watermark, cleared in the column."""
+        sf = self._cols.sync[: self._slot_watermark]
+        f = sf.copy()
+        sf[:] = 0
+        return f
+
+    def collect_sync(self, f: np.ndarray):
+        """This tick's position-sync records of the space as columns --
+        ``(cid, gate, eid, x, y, z, yaw)``, see engine/sync.py -- or None.
+        ``f`` holds each slot's SYNC_* flags: an own-client record for
+        every SYNC_OWN slot whose entity has a client, and one record per
+        watch-index pair whose target is flagged SYNC_NEIGHBORS."""
         cols = self._cols
-        sf = cols.sync[: self._slot_watermark]
-        idx = np.nonzero(sf)[0]
-        if not idx.size:
-            return
-        flags = sf[idx].copy()
-        sf[idx] = 0
-        w = cols.watched[idx]
-        se = self._slot_np
-        for s, f in zip(idx[w].tolist(), flags[w].tolist()):
-            e = se[s]
-            if e is None or e.destroyed:
-                continue
-            e._sync_flags |= f
-            ds = e._dirty_set
-            if ds is not None:
-                ds.add(e)
+        tgt = np.flatnonzero((f & SYNC_OWN).astype(bool)
+                             & cols.client[: len(f)])
+        obs = tgt
+        w = self._watch
+        if len(w):
+            t = w >> _SHIFT
+            sel = (f[t] & SYNC_NEIGHBORS) != 0
+            if not sel.all():
+                t, w = t[sel], w[sel]
+            if len(t):
+                tgt = np.concatenate((tgt, t))
+                obs = np.concatenate((obs, w & _LOW))
+        if not len(tgt):
+            return None
+        return (cols.cid[obs], cols.gate[obs], cols.eid[tgt], cols.x[tgt],
+                cols.y[tgt], cols.z[tgt], cols.yaw[tgt])
 
     def dispatch_aoi_events(self):
         """Replay batched enter/leave pairs through entity interest hooks.
 
         Fast path: a pair whose OBSERVER has no client and default AOI hooks
-        (``_plain_aoi``) is pure interest-set bookkeeping -- two C-level set
-        ops, no method dispatch.  Observers with a client or overridden
-        hooks take the full ``_interest``/``_uninterest`` path (client
-        create/destroy ops, watcher counts, user callbacks).  Slot->entity
-        resolution fancy-indexes the object-array mirror: one C pass per
-        event batch instead of two list lookups per pair."""
+        (``_plain_aoi``) is pure interest-set bookkeeping the calculator's
+        packed words already hold -- dropped with one vectorized mask.
+        Observers with a client or overridden hooks take the full
+        ``_interest``/``_uninterest`` path (client create/destroy ops,
+        watcher counts, user callbacks), and the watch index follows the
+        pairs as whole arrays (``_replay``).  Slot->entity resolution
+        fancy-indexes the object-array mirror: one C pass per event batch
+        instead of two list lookups per pair."""
         if self._aoi_handle is None:
             return
         enter, leave = self._runtime().aoi.take_events(self._aoi_handle)
-        se = self._slot_np
         nonplain = self._nonplain
         # leaves first: a slot reused within one tick (leave+enter) must
-        # destroy before re-creating on clients.  Pairs with a PLAIN
-        # observer are dropped wholesale (one vectorized mask): their
-        # interest state is the calculator's packed words, derived on
-        # demand -- no per-pair host work at all.
+        # destroy before re-creating on clients
         if len(leave):
-            need = leave[nonplain[leave[:, 0]]]
-            for a, b in zip(se[need[:, 0]], se[need[:, 1]]):
-                if a is not None and b is not None:
-                    a._uninterest(b)
+            self._replay(leave[nonplain[leave[:, 0]]], False)
         if len(enter):
-            need = enter[nonplain[enter[:, 0]]]
-            for a, b in zip(se[need[:, 0]], se[need[:, 1]]):
-                if a is not None and b is not None:
+            self._replay(enter[nonplain[enter[:, 0]]], True)
+
+    def _replay(self, pairs: np.ndarray, enter: bool):
+        """Replay (observer, target) slot pairs of live slots through
+        ``_interest`` (``enter``) or ``_uninterest``, then fold them into
+        the watch index as one array operation.  Hooks may change clients
+        and slots mid-replay: a client binding recomputes its observer's
+        pairs from the sets, which already hold the pairs replayed so far,
+        and a slot freed mid-replay first folds them (``_settle_watch``)."""
+        act = self._cols.act
+        pairs = pairs[act[pairs[:, 0]] & act[pairs[:, 1]]]
+        if not len(pairs):
+            return
+        se = self._slot_np
+        obs, tgt = se[pairs[:, 0]], se[pairs[:, 1]]
+        rp = self._rp = _Replay(pairs, obs, tgt, enter)
+        try:
+            for n, a, b in zip(itertools.count(1), obs, tgt):
+                rp.done = n
+                if enter:
                     a._interest(b)
+                else:
+                    a._uninterest(b)
+        finally:
+            self._settle_watch()
+            self._rp = None
+
+    # -- watch index -------------------------------------------------------
+    def _settle_watch(self):
+        """Fold the pairs a replay in progress has replayed since the last
+        fold into the watch index (a no-op outside a replay)."""
+        rp = self._rp
+        if rp is None or rp.done <= rp.applied:
+            return
+        lo, hi = rp.applied, rp.done
+        rp.applied = hi
+        if rp.moved:
+            # place each pair's entities where they are now, if still here
+            o, t = [], []
+            for a, b in zip(rp.obs[lo:hi], rp.tgt[lo:hi]):
+                if (a.space is self and b.space is self
+                        and a.aoi_slot >= 0 and b.aoi_slot >= 0):
+                    o.append(a.aoi_slot)
+                    t.append(b.aoi_slot)
+            o, t = np.array(o, np.int64), np.array(t, np.int64)
+        else:
+            o = rp.pairs[lo:hi, 0].astype(np.int64)
+            t = rp.pairs[lo:hi, 1].astype(np.int64)
+        m = self._cols.client[o]
+        codes = (t[m] << _SHIFT) | o[m]
+        if rp.enter:
+            self._watch_add(codes)
+        else:
+            self._watch_remove(codes)
+
+    def _watch_add(self, codes: np.ndarray):
+        if not len(codes):
+            return
+        codes = np.unique(codes)
+        w = self._watch
+        pos = np.searchsorted(w, codes)
+        if len(w):
+            new = w[np.minimum(pos, len(w) - 1)] != codes
+            codes, pos = codes[new], pos[new]
+        self._watch = np.insert(w, pos, codes)
+
+    def _watch_remove(self, codes: np.ndarray):
+        w = self._watch
+        if not len(codes) or not len(w):
+            return
+        pos = np.searchsorted(w, codes)
+        ok = pos < len(w)
+        pos = pos[ok]
+        pos = pos[w[pos] == codes[ok]]
+        if len(pos):
+            keep = np.ones(len(w), bool)
+            keep[pos] = False
+            self._watch = w[keep]
+
+    def _set_client_cols(self, slot: int, client):
+        cols = self._cols
+        cols.client[slot] = client is not None
+        if client is not None:
+            cols.put_id("cid", slot, client.client_id)
+            cols.gate[slot] = client.gate_id
+
+    def _rewatch(self, e: Entity):
+        """Recompute the watch pairs whose observer is ``e`` (slotted here)
+        from its interest set and client binding."""
+        slot = e.aoi_slot
+        w = self._watch
+        if len(w):
+            self._watch = w[(w & _LOW) != slot]
+        if e.client is not None and e.interested_in:
+            t = [x.aoi_slot for x in e.interested_in
+                 if x.space is self and x.aoi_slot >= 0]
+            self._watch_add((np.array(t, np.int64) << _SHIFT) | slot)
 
     # -- lazy interest derivation ------------------------------------------
     def derive_interests(self, slot: int) -> list[Entity]:

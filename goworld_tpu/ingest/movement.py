@@ -13,10 +13,10 @@ writes a Python attribute per entity:
   delta-staged H2D in ops/aoi_stage's (row, col, x, z) packet layout.
 
 Sync bookkeeping is columnar too: ``cols.sync[slots] |= SYNC_NEIGHBORS``
-plus one runtime registration; the sync phase drains the column into the
-per-entity dirty machinery only for entities some client actually watches
-(Space.drain_column_sync), so batched and per-entity movement emit
-identical records through one path.
+plus one runtime registration; the sync phase reads the column into the
+same per-slot flag vector as per-entity movement's flags
+(Space.take_column_sync / collect_sync), so batched and per-entity
+movement emit identical records through one path.
 
 Entities that cannot take the vectorized land -- unknown, not
 client-syncing, spaceless, or mid-enter (``aoi_slot < 0``) -- fall back to

@@ -268,20 +268,12 @@ class GWConnection:
     def make_sync_on_clients_packet(gate_id: int) -> Packet:
         """Per-gate batch; the dispatcher routes whole packets by this id
         (batching at every hop, reference: GateService.go:400-427 /
-        DispatcherService.go:784-827)."""
+        DispatcherService.go:784-827).  The body is 48-byte records:
+        client id, entity id, x, y, z, yaw as f32 (engine/sync.py
+        SYNC_ON_CLIENT_RECORD)."""
         p = Packet.for_msgtype(MT.MT_SYNC_POSITION_YAW_ON_CLIENTS)
         p.append_u16(gate_id)
         return p
-
-    @staticmethod
-    def append_sync_record(p: Packet, client_id: str, eid: str,
-                           x: float, y: float, z: float, yaw: float):
-        p.append_client_id(client_id)
-        p.append_entity_id(eid)
-        p.append_f32(x)
-        p.append_f32(y)
-        p.append_f32(z)
-        p.append_f32(yaw)
 
     # -- gate band ---------------------------------------------------------
     def send_create_entity_on_client(

@@ -535,9 +535,11 @@ def test_game_sync_pack_span():
     import types
 
     from goworld_tpu.components.game.service import GameService
+    from goworld_tpu.engine.sync import SyncBatch
 
-    recs = [("c%015d" % i, i % 2, "e%015d" % i, 1.0, 0.0, 2.0, 0.5)
-            for i in range(4)]
+    recs = SyncBatch.from_records(
+        [("c%015d" % i, i % 2, "e%015d" % i, 1.0, 0.0, 2.0, 0.5)
+         for i in range(4)])
     stub = types.SimpleNamespace(
         rt=types.SimpleNamespace(drain_sync=lambda: recs),
         cluster=types.SimpleNamespace(by_gate=lambda gate_id: None))
