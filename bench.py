@@ -1668,14 +1668,14 @@ def _clustered_walk(cap, n, ticks, world, seed=23):
     return frames
 
 
-def _clustered_run(frames, cap, n, backend, paged):
+def _clustered_run(frames, cap, n, backend):
     """Drive one clustered-crowd walk through AOIEngine on the given
     tier; crc32-fold the delivered streams (the parity oracle)."""
     from goworld_tpu import faults
     from goworld_tpu.engine.aoi import AOIEngine
 
     faults.clear()
-    eng = AOIEngine(backend, paged=paged)
+    eng = AOIEngine(backend)
     h = eng.create_space(cap)
     r = np.full(n, 100.0, np.float32)
     act = np.ones(n, bool)
@@ -1694,47 +1694,32 @@ def _clustered_run(frames, cap, n, backend, paged):
 
 
 def bench_engine_clustered(cfg, cap=2048, n=1800, ticks=8):
-    """Clustered-crowd skew A/B (ROADMAP #2, docs/perf.md paged storage):
-    the SAME mass-enter storm through the single-chip bucket capped
-    (fixed triples cap -- the storm tick overflows it and is flagged in
-    ``decode_overflow``, the BENCH_r05 failure class) and paged (the
-    on-device page allocator absorbs the skew: ``decode_overflow`` and
-    ``overflow_ticks`` MUST be 0; bins past the warming pool spill to
-    host counted in ``page_spills`` and re-arm it).  Both streams must
-    be crc-identical to each other and to the CPU oracle."""
+    """Clustered-crowd skew (ROADMAP #2): a mass-enter storm through the
+    single-chip bucket.  The storm tick overflows the triples cap, takes
+    the counted full-grid recovery (``decode_overflow``) and grows the
+    cap; the stream must be crc-identical to the CPU oracle."""
     frames = _clustered_walk(cap, n, ticks, cfg.world)
-    cpu_crc, cpu_n, _w, _s = _clustered_run(frames, cap, n, "cpu", False)
-    cap_crc, cap_n, cap_walls, cap_st = _clustered_run(
-        frames, cap, n, "tpu", False)
-    pg_crc, pg_n, pg_walls, pg_st = _clustered_run(
-        frames, cap, n, "tpu", True)
+    cpu_crc, cpu_n, _w, _s = _clustered_run(frames, cap, n, "cpu")
+    cap_crc, cap_n, cap_walls, cap_st = _clustered_run(frames, cap, n, "tpu")
     return {
         "metric": "engine_clustered_crowd",
         "config": "clustered_crowd",
-        "kind": "paged vs capped skew A/B",
-        "value": round(n * len(pg_walls) / sum(pg_walls)),
+        "kind": "capped skew vs CPU oracle",
+        "value": round(n * len(cap_walls) / sum(cap_walls)),
         "unit": "moves/s",
         "rate_kind": "e2e",
         "detail": f"1 space x {n} entities converge into one r=100 "
                   f"cluster at tick 2 of {ticks} and disperse at "
-                  f"{ticks - 1}; same walk capped vs paged vs CPU oracle",
+                  f"{ticks - 1}; same walk capped vs CPU oracle",
         "n_entities": n,
         "ticks": ticks,
-        # the headline robustness claim: the paged layout retires the
-        # overflow class the capped baseline still flags
-        "overflow_ticks": pg_st["decode_overflow"],
-        "decode_overflow": pg_st["decode_overflow"],
+        "overflow_ticks": cap_st["decode_overflow"],
+        "decode_overflow": cap_st["decode_overflow"],
         "events_per_tick_is_lower_bound": False,
-        "page_spills": pg_st["page_spills"],
-        "page_occupancy": round(pg_st["page_occupancy"], 4),
-        "capped_overflow_ticks": cap_st["decode_overflow"],
-        "events_per_tick": round((pg_n / 2) / ticks, 1),
-        "parity_ok": pg_crc == cap_crc == cpu_crc
-        and pg_n == cap_n == cpu_n,
-        "parity_checksum": f"{pg_crc:08x}",
-        "ms_per_tick": round(sum(pg_walls) / len(pg_walls) * 1e3, 2),
-        "capped_ms_per_tick": round(
-            sum(cap_walls) / len(cap_walls) * 1e3, 2),
+        "events_per_tick": round((cap_n / 2) / ticks, 1),
+        "parity_ok": cap_crc == cpu_crc and cap_n == cpu_n,
+        "parity_checksum": f"{cap_crc:08x}",
+        "ms_per_tick": round(sum(cap_walls) / len(cap_walls) * 1e3, 2),
     }
 
 
@@ -2660,9 +2645,9 @@ def main():
                 # (no dropped tick, crc parity, migration_ms)
                 emit(bench_engine_failover(cfg))
                 emit(bench_engine_migrate(cfg))
-                # clustered-crowd skew A/B (docs/perf.md paged storage):
-                # platform-agnostic like the two above -- the paged layout
-                # must retire the overflow class the capped one flags
+                # clustered-crowd skew (counted overflow, cap growth, crc
+                # parity vs the CPU oracle), platform-agnostic like the
+                # two above
                 emit(bench_engine_clustered(cfg))
                 # space-stacked cohort A/B (docs/perf.md "Space-stacked
                 # cohorts"), platform-agnostic like the rows above: the

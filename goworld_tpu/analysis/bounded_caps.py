@@ -3,9 +3,9 @@
 The round-8 robustness work retired a whole failure class: a device
 buffer sized by a cap (``_max_triples``, ``_kcap``, ``mc`` chunk caps)
 silently truncating -- or OOMing the host on growth -- when a skewed
-entity distribution blows past it.  The paged layout absorbs skew, but
-capped buffers legitimately remain (compile-key stability wants static
-shapes).  What must NEVER come back is an *uncounted* cap: a
+entity distribution blows past it.  Capped buffers legitimately remain
+(compile-key stability wants static shapes): a tick past its cap takes
+a counted full-grid recovery and the cap grows.  What must NEVER come back is an *uncounted* cap: a
 ``jnp.zeros``/``jnp.full``/``jnp.empty`` whose shape derives from a
 cap-like name and whose enclosing function has no counted overflow
 fallback (a ``stats[...] += 1`` style counter, or spill/overflow

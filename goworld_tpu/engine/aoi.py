@@ -40,7 +40,6 @@ from .. import faults, telemetry
 from ..ops import aoi_cohort as AC
 from ..ops import aoi_emit as AE
 from ..ops import aoi_fused as AF
-from ..ops import aoi_pages as PG
 from ..ops import aoi_predicate as P
 from ..ops import aoi_stage as AS
 from ..ops import dispatch_count as DC
@@ -54,7 +53,6 @@ from ..ops import events as EV
 
 _fused_impl = None  # built lazily: jax must not load in cpu-only processes
 _fused_tri_impl = None
-_fused_paged_impl = None
 _clear_impl = None
 
 
@@ -381,67 +379,6 @@ def _fused_bucket_step_tri(prev_all, *args):
     return _fused_tri_impl(prev_all, *args)
 
 
-def _fused_bucket_step_paged(prev_all, *args):
-    """Paged-mode bucket flush (docs/perf.md paged storage, ROADMAP #2):
-    same gather / fused kernel / scatter prologue as
-    :func:`_fused_bucket_step`, but the diff compacts into page-granular
-    word entries through the on-device allocator (ops/aoi_pages): each
-    allocation bin's nonzero change words land on pages drawn from the
-    shared free list, so a dense hotspot borrows pages sparse bins never
-    needed and NO global per-tick cap exists -- bins the pool cannot
-    serve are reported in ``spill_bins`` for the counted spill-to-host
-    fallback instead of truncating anything.  Harvest fetches the used
-    page prefix, the page table, and one scalar vector.  The raw
-    ``new``/``chg`` grids still ride donated scratch for the spill and
-    poisoned-scalar recoveries.
-
-    ``args`` = (new_buf, chg_buf, pg_buf, pc_buf, pn_buf, free, slot_idx,
-    x_all, z_all, r_all, act_all, sub_all, page_words, bin_words,
-    max_spill, platform).
-    """
-    global _fused_paged_impl
-    if _fused_paged_impl is None:
-        import functools
-
-        import jax
-        import jax.numpy as jnp
-
-        from ..ops.aoi_dense import aoi_step_chg
-
-        @functools.partial(
-            jax.jit,
-            static_argnames=("page_words", "bin_words", "max_spill",
-                             "platform"),
-            donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-        def aoi_step_paged(prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
-                           free, slot_idx, x_all, z_all, r_all, act_all,
-                           sub_all, page_words, bin_words, max_spill,
-                           platform=None):
-            prev_rows = prev_all[slot_idx]
-            x = x_all[slot_idx]
-            z = z_all[slot_idx]
-            r = r_all[slot_idx]
-            act = act_all[slot_idx]
-            sub = sub_all[slot_idx]
-            new, chg = aoi_step_chg(x, z, r, act, prev_rows,
-                                    platform=platform)
-            prev_all = prev_all.at[slot_idx].set(new)
-            chg = jnp.where(sub[:, None, None], chg, jnp.uint32(0))
-            (pg, pc, pn, page_tab, free_next, spill_bins,
-             scalars) = PG.allocate_pages(chg, new, free, page_words,
-                                          bin_words, max_spill)
-            new_buf = new_buf.at[:].set(new)
-            chg_buf = chg_buf.at[:].set(chg)
-            pg_buf = pg_buf.at[:].set(pg)
-            pc_buf = pc_buf.at[:].set(pc)
-            pn_buf = pn_buf.at[:].set(pn)
-            return (prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
-                    page_tab, free_next, spill_bins, scalars)
-
-        _fused_paged_impl = aoi_step_paged
-    return _fused_paged_impl(prev_all, *args)
-
-
 _TRIPLES = telemetry.counter(
     "aoi.triples", "triples the device extraction delivered, summed over "
     "the tri-mode ticks that fit the cap")
@@ -556,158 +493,6 @@ class _TriCapDecay:
         return None
 
 
-class _PageDecay:
-    """Windowed decay of the paged pool size (the exact _TriCapDecay
-    story for ``n_pages``: growth on spill is the owner's job -- bounded
-    by ops/aoi_pages.pool_ceiling, past which the pool can never spill --
-    and this proposes post-storm shrinks on a doubling window, reporting
-    ``steady`` once the static compile key is final)."""
-
-    def __init__(self, floor: int):
-        self.floor = floor
-        self.peak = 0
-        self.flushes = 0
-        self.refit_at = 8
-        self.steady = False
-
-    def reset_after_growth(self) -> None:
-        self.peak = 0
-        self.flushes = 0
-        self.refit_at = 8
-        self.steady = False
-
-    def observe(self, n_used: int, cur: int) -> int | None:
-        """Track one flush's used-page peak; at the window boundary
-        return the shrunk pool size to adopt, or None."""
-        self.peak = max(self.peak, n_used)
-        self.flushes += 1
-        if self.flushes < self.refit_at:
-            return None
-        fit = max(self.floor,
-                  1 << (max(self.peak * 3 // 2, 1) - 1).bit_length())
-        self.peak = 0
-        self.flushes = 0
-        self.refit_at = min(self.refit_at * 2, 128)
-        if fit < cur:
-            self.steady = False  # one more clean window confirms
-            return fit
-        self.steady = True
-        return None
-
-
-def _paged_absorb_chip(bk, chg_dev, new_dev, W: int):  # gwlint: allow[host-sync] -- counted overflow absorber: fetches used pages + spilled bins instead of a chip's full diff grid
-    """Absorb one chip's decode overflow through the paged pool
-    (docs/perf.md, paged storage): instead of growing the stream caps (a
-    recompile) and fetching the chip's FULL diff grid, compact the kept
-    change/new grids into pages ON DEVICE (ops/aoi_pages) and fetch only
-    the used prefix -- plus any spilled bins host-side, as a counted
-    graceful degradation.  Shares the bucket's persistent free list /
-    pool-decay state (``_page_free``/``_n_pages``/``_pages``) across
-    chips and ticks; the ``aoi.pages`` seam crosses once per absorbed
-    chip (oom/fail/partial = whole-grid spill + pool re-arm; poison =
-    page-table corruption caught by validation -> whole-grid spill +
-    free-list reinit -- the multi-chip pool is transient per-harvest, so
-    reinit IS the rebuild).
-
-    Returns ``(chg_vals, ent_vals, gidx)`` with chip-LOCAL flat word
-    indices (the caller offsets by its chip base), bit-exact with the
-    raw-grid recovery it replaces.
-    """
-    from ..utils import gwlog
-    import jax.numpy as jnp
-
-    nw = int(np.prod(chg_dev.shape))
-    bw = PG.bin_words_for(W)
-    if bk._pages is None:
-        bk._pages = _PageDecay(floor=PG.pool_floor(nw))
-    want = max(bk._n_pages, bk._pages.floor)
-    if bk._page_free is None or int(bk._page_free.shape[0]) != want:
-        bk._n_pages = want
-        bk._page_free = jnp.arange(want, dtype=jnp.int32)
-    n_pages = bk._n_pages
-
-    def _whole_grid():  # gwlint: allow[host-sync] -- counted whole-grid spill drains on purpose
-        # counted spill: the raw-grid fallback the capped path used
-        bk.stats["page_spills"] += 1
-        chg_h = np.asarray(chg_dev).reshape(-1)
-        new_h = np.asarray(new_dev).reshape(-1)
-        gidx = np.nonzero(chg_h)[0]
-        chg_vals = chg_h[gidx]
-        return chg_vals, chg_vals & new_h[gidx], np.asarray(gidx, np.int64)
-
-    try:
-        spec = faults.check("aoi.pages")
-    except Exception as e:  # noqa: BLE001 -- seam-injected device faults
-        if not _device_fault(e):
-            raise
-        gwlog.logger("gw.aoi").warning(
-            "AOI page pool unusable for this chip (%s); spilling its "
-            "whole grid to host and re-arming the pool", e)
-        bk._page_free = None
-        bk._pages.reset_after_growth()
-        return _whole_grid()
-    if spec is not None and spec.kind == "partial":
-        gwlog.logger("gw.aoi").warning(
-            "AOI page allocation reported partial for this chip; "
-            "spilling its whole grid to host and re-arming the pool")
-        bk._page_free = None
-        bk._pages.reset_after_growth()
-        return _whole_grid()
-    _tp = _T.t()
-    (pg, pc, pn, tab, free_next, sb, scal) = PG.paged_extract(
-        chg_dev.reshape(-1), new_dev.reshape(-1), bk._page_free,
-        page_words=PG.PAGE_WORDS, bin_words=bw, max_spill=PG.MAX_SPILL)
-    bk._page_free = free_next
-    scal_h = np.asarray(scal)
-    n_used, n_spill = int(scal_h[0]), int(scal_h[1])
-    n_bins = -(-nw // bw)
-    tab_h = np.asarray(tab)
-    if spec is not None and spec.kind == "poison":
-        # seam-injected allocator corruption: trash the fetched table so
-        # validation must catch it (docs/robustness.md, aoi.pages)
-        tab_h = np.full_like(tab_h, np.iinfo(np.int32).min)
-    bad_scal = not (0 <= n_used <= n_pages and 0 <= n_spill <= n_bins)
-    if bad_scal or not PG.validate_page_table(
-            tab_h, 0 if bad_scal else n_used, n_pages):
-        bk.stats["poisoned"] += 1
-        gwlog.logger("gw.aoi").warning(
-            "AOI page table failed validation during overflow absorb "
-            "(n_used=%d, n_pages=%d); spilling the chip's whole grid and "
-            "reinitialising the free list", n_used, n_pages)
-        bk._page_free = None
-        bk._pages.reset_after_growth()
-        out = _whole_grid()
-        _T.lap("aoi.pages", _tp)
-        return out
-    pg_h = np.asarray(pg[:max(n_used, 1)])[:n_used]
-    pc_h = np.asarray(pc[:max(n_used, 1)])[:n_used]
-    pn_h = np.asarray(pn[:max(n_used, 1)])[:n_used]
-    gidx, chg_vals, new_vals = PG.decode_pages(pg_h, pc_h, pn_h)
-    if n_spill:
-        # hotter than the pool: counted spill for the offending bins +
-        # pool growth so the NEXT storm tick absorbs fully page-side
-        bk.stats["page_spills"] += n_spill
-        sgi, sc, sn = PG.spill_stream(
-            chg_dev.reshape(-1), new_dev.reshape(-1), np.asarray(sb),
-            bw, nw)
-        gidx = np.concatenate([np.asarray(gidx, np.int64), sgi])
-        chg_vals = np.concatenate([chg_vals, sc])
-        new_vals = np.concatenate([new_vals, sn])
-        grown = min(PG.pool_ceiling(nw, bw), max(n_pages * 2, 64))
-        if grown > n_pages:
-            bk._n_pages = grown
-            bk._page_free = None
-        bk._pages.reset_after_growth()
-    else:
-        shrink = bk._pages.observe(n_used, n_pages)
-        if shrink is not None:
-            bk._n_pages = shrink
-            bk._page_free = None
-    bk.stats["page_occupancy"] = n_used / max(n_pages, 1)
-    _T.lap("aoi.pages", _tp)
-    return chg_vals, chg_vals & new_vals, np.asarray(gidx, np.int64)
-
-
 @dataclass(eq=False)  # identity hash: handles live in a WeakSet registry
 class SpaceAOIHandle:
     backend: str        # resolved (cpu | cpp | tpu)
@@ -736,9 +521,8 @@ class AOIEngine:
                  tpu_min_capacity: int = 4096,
                  rowshard_min_capacity: int = 65536,
                  flush_sched: bool = True, emit: str = "auto",
-                 paged: bool = False, cross_tick: bool = False,
-                 interest_mode: str = "device", fused: bool = False,
-                 cohort=False, cohort_ladder=None):
+                 cross_tick: bool = False, interest_mode: str = "device",
+                 fused: bool = False, cohort=False, cohort_ladder=None):
         self.default_backend = default_backend
         # space-stacked cohorts (ROADMAP #2, ops/aoi_cohort, docs/perf.md
         # "Space-stacked cohorts"): "auto"/True stacks small device-eligible
@@ -785,14 +569,6 @@ class AOIEngine:
         # row-sharded tier accepts the flag but stays synchronous (its
         # flush is already a collective barrier -- see aoi_rowshard).
         self.cross_tick = bool(cross_tick)
-        # paged ragged event storage (docs/perf.md paged storage): the
-        # device buckets compact their change stream into fixed-size pages
-        # drawn from a shared on-device free list instead of a global
-        # per-tick cap, retiring the decode_overflow failure class for
-        # skewed (clustered-crowd) distributions.  Off by default while
-        # the capped layouts remain the tuned production path; bench.py's
-        # clustered_crowd config A/Bs the two.
-        self.paged = bool(paged)
         # event emit fan-out path for the device buckets (docs/perf.md):
         # "auto" = fastest available (native when libgwemit builds, else
         # vector), "host" = the original per-word host decode kept as the
@@ -954,8 +730,7 @@ class AOIEngine:
                         capacity, self.mesh, pipeline=self.pipeline,
                         cross_tick=self.cross_tick,
                         delta_staging=self.delta_staging,
-                        emit=self._resolve_emit(), paged=self.paged,
-                        fused=self.fused)
+                        emit=self._resolve_emit(), fused=self.fused)
                     self._rowshard_serial += 1
                     key = (f"tpu-rowshard-{self._rowshard_serial}", capacity)
                 elif self.mesh is not None:
@@ -965,14 +740,12 @@ class AOIEngine:
                         capacity, self.mesh, pipeline=self.pipeline,
                         cross_tick=self.cross_tick,
                         delta_staging=self.delta_staging,
-                        emit=self._resolve_emit(), paged=self.paged,
-                        fused=self.fused)
+                        emit=self._resolve_emit(), fused=self.fused)
                 else:
                     bucket = _TPUBucket(capacity, pipeline=self.pipeline,
                                         cross_tick=self.cross_tick,
                                         delta_staging=self.delta_staging,
                                         emit=self._resolve_emit(),
-                                        paged=self.paged,
                                         fused=self.fused)
             else:
                 raise ValueError(f"unknown AOI backend {backend!r}")
@@ -1004,7 +777,7 @@ class AOIEngine:
                 capacity, self.mesh, pipeline=self.pipeline,
                 cross_tick=self.cross_tick,
                 delta_staging=self.delta_staging, emit=self._resolve_emit(),
-                paged=self.paged, fused=self.fused)
+                fused=self.fused)
             self._rowshard_serial += 1
             self._buckets[(f"tpu-rowshard-{self._rowshard_serial}",
                            capacity)] = bucket
@@ -1020,8 +793,7 @@ class AOIEngine:
                     capacity, self.mesh, pipeline=self.pipeline,
                     cross_tick=self.cross_tick,
                     delta_staging=self.delta_staging,
-                    emit=self._resolve_emit(), paged=self.paged,
-                    fused=self.fused)
+                    emit=self._resolve_emit(), fused=self.fused)
                 self._buckets[key] = bucket
         elif tier == "tpu":
             key = (("tpu-single", capacity) if self.mesh is not None
@@ -1032,7 +804,6 @@ class AOIEngine:
                                     cross_tick=self.cross_tick,
                                     delta_staging=self.delta_staging,
                                     emit=self._resolve_emit(),
-                                    paged=self.paged,
                                     fused=self.fused)
                 self._buckets[key] = bucket
         else:
@@ -1065,7 +836,7 @@ class AOIEngine:
             bucket = _CohortTPUBucket(
                 shape, pipeline=self.pipeline, cross_tick=self.cross_tick,
                 delta_staging=self.delta_staging, emit=self._resolve_emit(),
-                paged=self.paged, fused=self.fused)
+                fused=self.fused)
             self._buckets[key] = bucket
         return bucket
 
@@ -1079,8 +850,7 @@ class AOIEngine:
         bucket = _TPUBucket(capacity, pipeline=self.pipeline,
                             cross_tick=self.cross_tick,
                             delta_staging=self.delta_staging,
-                            emit=self._resolve_emit(), paged=self.paged,
-                            fused=self.fused)
+                            emit=self._resolve_emit(), fused=self.fused)
         bucket.exclusive = True
         bucket.cohort_solo = True
         self._buckets[(f"tpu-solo-{self._cohort_serial}", capacity)] = bucket
@@ -1387,7 +1157,6 @@ class AOIEngine:
         perf: dict[str, float] = {}
         calc_level = 0
         emit_path = 0
-        page_occ = 0.0
         for b in (self._buckets[k] for k in sorted(self._buckets)):
             for k, v in getattr(b, "stats", {}).items():
                 if k == "calc_level":
@@ -1396,11 +1165,6 @@ class AOIEngine:
                     # like calc_level: the WORST bucket -- one demoted emit
                     # path should page even among healthy neighbors
                     emit_path = max(emit_path, v)
-                elif k == "page_occupancy":
-                    # gauge, not a counter: the FULLEST pool -- the bucket
-                    # closest to spilling is the one capacity planning
-                    # must see
-                    page_occ = max(page_occ, v)
                 else:
                     stats[k] = stats.get(k, 0) + v
             for k, v in getattr(b, "perf", {}).items():
@@ -1422,9 +1186,6 @@ class AOIEngine:
                Sample("aoi.emit_path", "gauge", emit_path, lbl,
                       "worst emit-path fallback level "
                       "(0=native 1=vector 2=host decode)"),
-               Sample("aoi.page_occupancy", "gauge", page_occ, lbl,
-                      "fullest page pool at last harvest "
-                      "(used/total pages; paged buckets only)"),
                Sample("aoi.scratch_bytes", "gauge",
                       sum(b.scratch_bytes() for b in self._buckets.values()
                           if isinstance(b, _TPUBucket)), lbl,
@@ -1799,32 +1560,19 @@ class _TPUBucket(_Bucket):
 
     def __init__(self, capacity: int, pipeline: bool = False,
                  delta_staging: bool = True, emit: str = "vector",
-                 paged: bool = False, cross_tick: bool = False,
-                 fused: bool = False):
+                 cross_tick: bool = False, fused: bool = False):
         super().__init__(capacity)
         self.pipeline = pipeline
         self.cross_tick = bool(cross_tick)
         self.delta_staging = delta_staging
         # fused steady tick (docs/perf.md "Fused tick", ROADMAP #3): when
         # eligible, the whole dispatch compiles into ONE program
-        # (ops/aoi_fused: scatter + kernel + diff + extraction/paging),
+        # (ops/aoi_fused: scatter + kernel + diff + extraction),
         # so the steady cost is one enqueue + one D2H fetch.  Unfused is
         # the A/B baseline and the demotion target: an aoi.* seam firing
         # in the fused attempt falls through to the unfused flow in the
         # same call, counted in fused_demotions, bit-exact same-tick.
         self.fused = bool(fused)
-        # paged ragged storage (docs/perf.md paged storage): the change
-        # stream compacts into fixed-size pages from an on-device free
-        # list (ops/aoi_pages) instead of the capped triples/chunk
-        # buffers -- no global per-tick cap, so decode_overflow cannot
-        # fire; bins the pool cannot serve spill to host (counted in
-        # page_spills, republished same-tick bit-exact) and re-arm the
-        # pool through _PageDecay
-        self.paged = bool(paged)
-        self._n_pages = 0           # pool size; sized at first dispatch
-        self._page_free = None      # device free list [n_pages] int32
-        self._pages: _PageDecay | None = None
-        self._pred_pages = 64       # optimistic page prefetch (pipeline)
         # emit fan-out path (docs/perf.md): "native"/"vector" run the
         # device-resident triples decode (_fused_bucket_step_tri) and fan
         # out through ops/aoi_emit; "host" keeps the classic encoded-stream
@@ -1937,10 +1685,6 @@ class _TPUBucket(_Bucket):
         # overflowed its cap and fell back to a counted full recovery;
         # emit_path = the fan-out level actually in use (0=native 1=vector
         # 2=host decode), surfaced like calc_level as a max gauge.
-        # paged-path additions: page_spills = bins (or whole ticks) the
-        # page pool could not serve, re-read from the kept change grid and
-        # republished same-tick (counted, never silent); page_occupancy =
-        # used/total pages at the last harvest (gauge, worst bucket wins)
         # fused-path additions: fused_dispatches = steady ticks that ran
         # as one program, fused_demotions = fused attempts a seam fault
         # demoted to the unfused flow (same call, bit-exact)
@@ -1948,7 +1692,6 @@ class _TPUBucket(_Bucket):
                       "rebuilds": 0, "fallbacks": 0, "host_ticks": 0,
                       "poisoned": 0, "calc_level": 0,
                       "decode_overflow": 0,
-                      "page_spills": 0, "page_occupancy": 0.0,
                       "fused_dispatches": 0, "fused_demotions": 0,
                       "emit_path": AE.EMIT_LEVEL[emit]}
         # phase-attribution counters (seconds, cumulative): stage = host
@@ -1971,20 +1714,16 @@ class _TPUBucket(_Bucket):
 
     @property
     def _steady(self) -> bool:
-        """No cap recompile pending (see _CapDecay/_TriCapDecay/_PageDecay;
+        """No cap recompile pending (see _CapDecay/_TriCapDecay;
         benchmarks read this)."""
-        if self.paged:
-            return self._pages is not None and self._pages.steady
         if self._emit != "host":
             return self._tri.steady
         return self._caps.steady
 
     def _scratch_key(self, s_n: int) -> tuple:
         """The scratch-pool key of a dispatch of ``s_n`` staged slots
-        under the current mode and caps: mc = -2 names the paged sets,
-        -1 the triples sets, and a chunk cap (>= 512) the classic ones."""
-        if self.paged:
-            return (s_n, -2, self._n_pages)
+        under the current mode and caps: mc = -1 names the triples sets,
+        and a chunk cap (>= 512) the classic ones."""
         if self._emit != "host":
             return (s_n, -1, self._max_triples)
         n_chunks_total = s_n * self.capacity * self.W // _LANES
@@ -2266,7 +2005,7 @@ class _TPUBucket(_Bucket):
             seg.begin("aoi.stage", "stage_s")
             self._dispatch_step(seg)
 
-    def _dispatch_step(self, seg) -> None:
+    def _dispatch_step(self, seg) -> None:  # gwlint: allow[bounded-caps] -- scratch sized by the current caps; a tick past them is recovered from the full grid at harvest, counted in decode_overflow, and the caps grow
         """Stage this tick's inputs and enqueue its step program: the rest
         of :meth:`_dispatch_device`, timed by ``seg`` into ``stage_s``
         (the ``aoi.stage`` segment is open on entry)."""
@@ -2285,25 +2024,7 @@ class _TPUBucket(_Bucket):
         self._cur_slots = slots  # recovery needs them once _staged is gone
 
         slot_idx = jnp.asarray(slots, jnp.int32)
-        tri_mode = self._emit != "host" and not self.paged
-        if self.paged:
-            # paged path (docs/perf.md paged storage): the change stream
-            # compacts into pages from the device-resident free list; the
-            # scratch key uses mc=-2 as the paged namespace.  The pool is
-            # (re)sized here: first dispatch seeds the floor, spills grow
-            # it (bounded by pool_ceiling), _PageDecay shrinks it back --
-            # a size change just reinitializes the free list.
-            nw = s_n * c * self.W
-            bw = PG.bin_words_for(self.W)
-            if self._pages is None:
-                self._pages = _PageDecay(floor=PG.pool_floor(nw))
-            # the decay's floor (not a recomputed one) sizes the first
-            # pool, so tests can preset a tiny _PageDecay to force spills
-            want = max(self._n_pages, self._pages.floor)
-            if self._page_free is None or want != self._n_pages \
-                    or self._page_free.shape[0] != want:
-                self._n_pages = want
-                self._page_free = jnp.arange(want, dtype=jnp.int32)
+        tri_mode = self._emit != "host"
         key = self._scratch_key(s_n)
         mc, mt = key[1], self._max_triples
         scratch = self._scratch.pop(key, None)
@@ -2315,16 +2036,7 @@ class _TPUBucket(_Bucket):
             self._scratch_prune()
             while len(self._scratch) >= 4:
                 self._scratch.pop(next(iter(self._scratch)))
-            if self.paged:
-                scratch = (
-                    jnp.zeros((s_n, c, self.W), jnp.uint32),
-                    jnp.zeros((s_n, c, self.W), jnp.uint32),
-                    jnp.full((self._n_pages, PG.PAGE_WORDS), -1,
-                             jnp.int32),
-                    jnp.zeros((self._n_pages, PG.PAGE_WORDS), jnp.uint32),
-                    jnp.zeros((self._n_pages, PG.PAGE_WORDS), jnp.uint32),
-                )
-            elif tri_mode:
+            if tri_mode:
                 scratch = (
                     jnp.zeros((s_n, c, self.W), jnp.uint32),
                     jnp.zeros((s_n, c, self.W), jnp.uint32),
@@ -2342,59 +2054,16 @@ class _TPUBucket(_Bucket):
         sub = self._hsub[sl]
         if self._mirror is not None and not sub.all():
             self._mirror_stale.update(s for s in slots if s in self._unsub)
-        if self.fused and self._dispatch_fused(
+        # the classic host-emit stream has no fused program
+        if self.fused and tri_mode and self._dispatch_fused(
                 slots, sl, slot_idx, key, scratch, sub, old_x, old_z,
-                old_r, old_act, tri_mode, seg):
+                old_r, old_act, seg):
             return
         self._stage_inputs(sl, old_x, old_z, old_r, old_act)
         seg.begin("aoi.kernel", "stage_s")
         self._fault_phase = "kernel"
         faults.check("aoi.kernel")
         all_unsub = not sub.any()
-        if self.paged:
-            DC.record()
-            out = _fused_bucket_step_paged(
-                self.prev, *scratch, self._page_free, slot_idx,
-                self._dev["x"], self._dev["z"], self._dev["r"],
-                self._dev["act"], self._dev["sub"],
-                PG.PAGE_WORDS, bw, PG.MAX_SPILL,
-                "cpu" if self._calc_level >= 1 else None
-            )
-            (self.prev, new, chg, pg, pc, pn, page_tab, self._page_free,
-             spill_bins, scalars) = out
-            seg.begin(None, "stage_s")
-            if not all_unsub:
-                scalars.copy_to_host_async()
-                page_tab.copy_to_host_async()
-                spill_bins.copy_to_host_async()
-            rec = {
-                "mode": "paged",
-                "slots": slots, "s_n": s_n, "key": key,
-                "n_pages": self._n_pages, "bin_words": bw,
-                "epochs": [self._slot_epoch.get(s, 0) for s in slots],
-                "scratch": (new, chg, pg, pc, pn),
-                "page_tab": page_tab,
-                "spill_bins": spill_bins,
-                "scalars": scalars,
-                "all_unsub": all_unsub,
-                "prefetch": None,
-            }
-            if self._defer and not all_unsub:
-                # optimistic page prefetch: the used prefix rides the wire
-                # while the host runs the next tick; harvest refetches on
-                # a misfit
-                ndp = min(self._n_pages, self._pred_pages)
-                sl_pg = (pg[:ndp], pc[:ndp], pn[:ndp])
-                for a in sl_pg:
-                    a.copy_to_host_async()
-                rec["prefetch"] = (ndp, sl_pg)
-            prev_rec, self._inflight = self._inflight, rec
-            if self._defer:
-                if prev_rec is not None:
-                    self._sched = ("rec", prev_rec)
-            else:
-                self._sched = ("inflight",)
-            return
         if tri_mode:
             platform = "cpu" if self._calc_level >= 1 else None
             DC.record()
@@ -2484,10 +2153,9 @@ class _TPUBucket(_Bucket):
             self._sched = ("inflight",)
 
     def _dispatch_fused(self, slots, sl, slot_idx, key, scratch, sub,
-                        old_x, old_z, old_r, old_act, tri_mode,
-                        seg) -> bool:
+                        old_x, old_z, old_r, old_act, seg) -> bool:
         """Attempt the ONE-DISPATCH fused tick (ops/aoi_fused, ROADMAP
-        #3): packet scatter + kernel + diff + extraction/paging as a
+        #3): packet scatter + kernel + diff + extraction as a
         single jitted program, so the steady tick is one enqueue + one
         D2H fetch.  Returns True when the tick was dispatched fused
         (the caller's unfused flow is skipped), False to fall through.
@@ -2495,9 +2163,9 @@ class _TPUBucket(_Bucket):
         Two distinct False paths, by design:
 
         * ineligible -- the tick is not a steady delta tick (stale
-          device roles, r/act changed, diff too large, classic host-emit
-          mode, device down): silent fall-through, the unfused path IS
-          the right program for it;
+          device roles, r/act changed, diff too large, device down):
+          silent fall-through, the unfused path IS the right program
+          for it;
         * demoted -- an ``aoi.delta``/``aoi.kernel`` seam fault fired in
           the fused attempt: counted in ``fused_demotions`` and fall
           through BEFORE any device mutation, so the unfused flow
@@ -2505,8 +2173,6 @@ class _TPUBucket(_Bucket):
           runs clean in the same call -- same-tick, bit-exact.
         """
         s_n = len(slots)
-        if not (tri_mode or self.paged):
-            return False  # classic host-emit stream has no fused program
         if (not self.delta_staging or self._dev_stale
                 or self._calc_level >= 2 or self._need_rebuild):
             return False
@@ -2536,8 +2202,7 @@ class _TPUBucket(_Bucket):
         if n_changed:
             rows, cols = np.nonzero(diff)
             pkt = AS.pad_packet(sl[rows], cols, new_x[rows, cols],
-                                new_z[rows, cols],
-                                page_granular=self.paged)
+                                new_z[rows, cols])
             self.stats["h2d_bytes"] += AS.packet_nbytes(*pkt)
         else:
             zi = np.zeros(0, np.int32)
@@ -2548,63 +2213,30 @@ class _TPUBucket(_Bucket):
         all_unsub = not sub.any()
         platform = "cpu" if self._calc_level >= 1 else None
         DC.record()
-        if self.paged:
-            bw = PG.bin_words_for(self.W)
-            out = AF.fused_paged_step(
-                self.prev, *scratch, self._page_free, self._dev["x"],
-                self._dev["z"], *pkt, slot_idx, self._dev["r"],
-                self._dev["act"], self._dev["sub"], PG.PAGE_WORDS, bw,
-                PG.MAX_SPILL, platform)
-            (self.prev, new, chg, pg, pc, pn, self._page_free, bundle,
-             self._dev["x"], self._dev["z"]) = out
-            seg.begin(None, "stage_s")
-            if not all_unsub:
-                bundle.copy_to_host_async()
-            rec = {
-                "mode": "paged",
-                "slots": slots, "s_n": s_n, "key": key,
-                "n_pages": self._n_pages, "bin_words": bw,
-                "epochs": [self._slot_epoch.get(s, 0) for s in slots],
-                "scratch": (new, chg, pg, pc, pn),
-                # one compact int32 vector replaces the page_tab /
-                # spill_bins / scalars triple-fetch of the unfused
-                # harvest (_harvest_paged slices it back apart)
-                "bundle": bundle,
-                "page_tab": None, "spill_bins": None, "scalars": None,
-                "all_unsub": all_unsub,
-                "prefetch": None,
-            }
-            if self._defer and not all_unsub:
-                ndp = min(self._n_pages, self._pred_pages)
-                sl_pg = (pg[:ndp], pc[:ndp], pn[:ndp])
-                for a in sl_pg:
-                    a.copy_to_host_async()
-                rec["prefetch"] = (ndp, sl_pg)
-        else:
-            mt = self._max_triples
-            out = AF.fused_tri_step(
-                self.prev, *scratch, self._dev["x"], self._dev["z"],
-                *pkt, slot_idx, self._dev["r"], self._dev["act"],
-                self._dev["sub"], mt, platform)
-            (self.prev, new, chg, tri, scalars,
-             self._dev["x"], self._dev["z"]) = out
-            seg.begin(None, "stage_s")
-            if not all_unsub:
-                scalars.copy_to_host_async()
-            rec = {
-                "mode": "tri",
-                "slots": slots, "s_n": s_n, "key": key, "mt": mt,
-                "epochs": [self._slot_epoch.get(s, 0) for s in slots],
-                "scratch": (new, chg, tri),
-                "scalars": scalars,
-                "all_unsub": all_unsub,
-                "prefetch": None,
-            }
-            if self._defer and not all_unsub:
-                ndp = min(mt, self._pred_tri)
-                sl_tri = tri[:ndp]
-                sl_tri.copy_to_host_async()
-                rec["prefetch"] = (ndp, sl_tri)
+        mt = self._max_triples
+        out = AF.fused_tri_step(
+            self.prev, *scratch, self._dev["x"], self._dev["z"],
+            *pkt, slot_idx, self._dev["r"], self._dev["act"],
+            self._dev["sub"], mt, platform)
+        (self.prev, new, chg, tri, scalars,
+         self._dev["x"], self._dev["z"]) = out
+        seg.begin(None, "stage_s")
+        if not all_unsub:
+            scalars.copy_to_host_async()
+        rec = {
+            "mode": "tri",
+            "slots": slots, "s_n": s_n, "key": key, "mt": mt,
+            "epochs": [self._slot_epoch.get(s, 0) for s in slots],
+            "scratch": (new, chg, tri),
+            "scalars": scalars,
+            "all_unsub": all_unsub,
+            "prefetch": None,
+        }
+        if self._defer and not all_unsub:
+            ndp = min(mt, self._pred_tri)
+            sl_tri = tri[:ndp]
+            sl_tri.copy_to_host_async()
+            rec["prefetch"] = (ndp, sl_tri)
         self.stats["fused_dispatches"] += 1
         prev_rec, self._inflight = self._inflight, rec
         if self._defer:
@@ -2821,7 +2453,6 @@ class _TPUBucket(_Bucket):
         self._dev.clear()
         self._dev_stale = {"xz", "ra", "sub"}
         self._scratch.clear()
-        self._page_free = None  # paged free list reinits at next dispatch
         self._need_rebuild = self._calc_level < 2
         if rec_slots:
             self._host_tick(rec_slots, publish_now=True)
@@ -2885,9 +2516,7 @@ class _TPUBucket(_Bucket):
         with _T.Segments(self.perf) as seg:
             seg.begin("aoi.fetch", "fetch_s")
             faults.check("aoi.fetch")  # stallable: a delayed host sync
-            if rec.get("mode") == "paged":
-                self._harvest_paged(rec, seg)
-            elif rec.get("mode") == "tri":
+            if rec.get("mode") == "tri":
                 self._harvest_tri(rec, seg)
             else:
                 self._harvest_words(rec, seg)
@@ -3057,173 +2686,6 @@ class _TPUBucket(_Bucket):
         gw = (srows * c + obs % c) * self.W + j % self.W
         bit = (j // self.W).astype(np.uint32)
         np.bitwise_xor.at(self._mirror.reshape(-1), gw, np.uint32(1) << bit)
-
-    def _grow_pool(self, nw: int, bw: int, full: bool = False) -> None:
-        """Spill re-arm (the growth half of the _PageDecay contract,
-        mirroring the tri/chunk cap growth): double the pool, bounded by
-        pool_ceiling -- a pool at the ceiling can NEVER spill (full word
-        coverage plus per-bin rounding) -- and reinitialize the free list
-        at the next dispatch.  ``full`` jumps straight to the ceiling: a
-        WHOLE-TICK spill (> MAX_SPILL bins) is an unambiguous undersize
-        signal, and doubling through a sustained storm would spill every
-        tick of it; _PageDecay shrinks the pool back afterwards."""
-        ceil_p = PG.pool_ceiling(nw, bw)
-        grown = ceil_p if full else min(ceil_p, max(self._n_pages * 2, 64))
-        if grown > self._n_pages:
-            self._n_pages = grown
-            self._page_free = None
-        if self._pages is not None:
-            self._pages.reset_after_growth()
-
-    def _harvest_paged(self, rec, seg) -> None:  # gwlint: allow[host-sync] -- paged-path drain point: fetches the used page prefix once per flush
-        """Harvest one paged tick: fetch the used page prefix + page table
-        + scalars, validate the allocator's page table, merge any spilled
-        bins' words re-read from the kept change grid, XOR the mirror, and
-        publish (docs/perf.md paged storage; docs/robustness.md spill
-        chain).  Degradation ladder: spilled bins re-read host-side
-        (counted in page_spills, same-tick bit-exact); pool exhaustion
-        injected through the ``aoi.pages`` seam (oom/fail/partial) forces
-        a counted whole-tick spill from the raw grids and re-arms the
-        pool; a corrupt page table (``aoi.pages`` poison, or real
-        allocator rot) re-raises as RESOURCE_EXHAUSTED to ride
-        :meth:`_recover_harvest`'s rebuild-from-host-shadows.  The
-        ``aoi.fetch`` segment of ``seg`` is open on entry."""
-        slots, s_n = rec["slots"], rec["s_n"]
-        n_pages, bw = rec["n_pages"], rec["bin_words"]
-        c = self.capacity
-        (new, chg, pg, pc, pn) = rec["scratch"]
-        nw = s_n * c * self.W
-        poisoned = False
-        n_used = n_spill = 0
-        page_spec = page_fault = None
-        bun_h = None
-        if not rec.get("all_unsub"):
-            if rec.get("bundle") is not None:
-                # fused tick: scalars + page_tab + spill_bins ride ONE
-                # int32 bundle -- a single blocking fetch replaces the
-                # unfused path's three (ops/aoi_fused)
-                with _T.span("aoi.wait"):
-                    bun_h = np.asarray(rec["bundle"])
-                raw = faults.filter("aoi.scalars", bun_h[:4])
-            else:
-                with _T.span("aoi.wait"):
-                    scalars = np.asarray(rec["scalars"])
-                raw = faults.filter("aoi.scalars", scalars)
-            n_used, n_spill, nz_fit, nz_total = (int(v) for v in raw)
-            n_bins = -(-nw // bw)
-            if not (0 <= n_used <= n_pages and 0 <= n_spill <= n_bins
-                    and 0 <= nz_fit <= nw and 0 <= nz_total <= nw):
-                from ..utils import gwlog
-
-                self.stats["poisoned"] += 1
-                gwlog.logger("gw.aoi").warning(
-                    "AOI page scalars failed validation (used=%d spill=%d "
-                    "fit=%d total=%d); recovering the tick from the raw "
-                    "diff grids", n_used, n_spill, nz_fit, nz_total)
-                poisoned = True
-                n_used = n_spill = 0
-            # the aoi.pages seam (docs/robustness.md): oom/fail = pool
-            # exhaustion, partial = untrustworthy allocation -- all three
-            # force the counted whole-tick spill below; poison corrupts
-            # the fetched page table (validated further down)
-            try:
-                page_spec = faults.check("aoi.pages")
-            except Exception as pe:
-                if not _device_fault(pe):
-                    raise
-                page_fault = pe
-            if page_spec is not None and page_spec.kind == "partial":
-                page_fault = page_spec
-        shrink = (None if poisoned or n_spill or page_fault is not None
-                  else self._pages.observe(n_used, n_pages))
-        if shrink is not None and shrink < self._n_pages:
-            self._n_pages = shrink
-            self._page_free = None  # reinit at the shrunk size
-        if poisoned or page_fault is not None or n_spill > PG.MAX_SPILL:
-            # whole-tick spill: the page stream is untrustworthy (poisoned
-            # scalars), the allocator faulted (aoi.pages oom/fail/partial),
-            # or more bins spilled than the reporting vector holds --
-            # recover this tick from the raw diff grids riding the same
-            # record (bit-exact; np.nonzero's ascending flat order matches
-            # the device extraction's), then re-arm the pool
-            if not poisoned:
-                from ..utils import gwlog
-
-                self.stats["page_spills"] += 1
-                gwlog.logger("gw.aoi").warning(
-                    "AOI page pool unusable this tick (%s); spilling the "
-                    "whole tick to host and re-arming the pool",
-                    page_fault if page_fault is not None
-                    else f"{n_spill} bins spilled > {PG.MAX_SPILL}")
-                # organic mass-spill = the pool is way undersized: jump to
-                # the ceiling.  A fault-caused spill says nothing about
-                # size, so it only doubles.
-                self._grow_pool(nw, bw, full=page_fault is None)
-            gidx, chg_vals, ent_vals = _full_diff(seg, chg, new)
-            seg.begin("aoi.diff", "decode_s")
-            self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
-            self._scratch_put(rec)
-            self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx,
-                          s_n)
-            return
-        if n_used == 0:
-            pg_h = np.empty((0, PG.PAGE_WORDS), np.int32)
-            pc_h = pn_h = np.empty((0, PG.PAGE_WORDS), np.uint32)
-        else:
-            pf = rec["prefetch"]
-            if pf is not None and pf[0] >= n_used:
-                pg_h, pc_h, pn_h = (np.asarray(a)[:n_used] for a in pf[1])
-            else:
-                ndp = min(n_pages, -(-max(n_used, 1) // 16) * 16)
-                slices = (pg[:ndp], pc[:ndp], pn[:ndp])
-                for a in slices:
-                    a.copy_to_host_async()
-                pg_h, pc_h, pn_h = (np.asarray(a)[:n_used] for a in slices)
-        seg.begin("aoi.pages", "decode_s")
-        # refit the next dispatch's optimistic page prefetch to this tick
-        self._pred_pages = max(
-            64, min(self._n_pages, -(-n_used * 5 // 4 // 16) * 16))
-        if n_used:
-            # page-table integrity: the table is the allocator's word of
-            # which logical pages back this tick; a duplicate, out-of-range
-            # or truncated id means the free list itself is corrupt -- not
-            # a per-tick cap problem -- so the ONLY safe recovery is the
-            # full device-state rebuild from the host shadows
-            tab_h = (bun_h[4:4 + n_pages] if bun_h is not None
-                     else np.asarray(rec["page_tab"]))
-            if page_spec is not None and page_spec.kind == "poison":
-                tab_h = np.full_like(tab_h, np.iinfo(np.int32).min)
-            if not PG.validate_page_table(tab_h, n_used, n_pages):
-                self.stats["poisoned"] += 1
-                self._page_free = None  # rebuilt (arange) at next dispatch
-                raise RuntimeError(
-                    "RESOURCE_EXHAUSTED: aoi.pages page table failed "
-                    f"validation (n_used={n_used}, n_pages={n_pages}) -- "
-                    "allocator free list corrupt")
-        gidx, chg_vals, new_vals = PG.decode_pages(pg_h, pc_h, pn_h)
-        gidx = gidx.astype(np.int64)
-        if n_spill:
-            # counted graceful degradation: the pool served every bin it
-            # could; the spilled bins' words are re-read from the kept
-            # change grid (small per-bin D2H slices), merged unsorted --
-            # the mirror XOR is order-independent over unique words and
-            # both emit paths sort before expansion -- and the pool grows
-            # for the next tick (decay shrinks it back post-storm)
-            self.stats["page_spills"] += n_spill
-            sb = (bun_h[4 + n_pages:] if bun_h is not None
-                  else np.asarray(rec["spill_bins"]))
-            sg, sc, sn2 = PG.spill_stream(chg.reshape(-1), new.reshape(-1),
-                                          sb, bw, nw)
-            gidx = np.concatenate([gidx, sg])
-            chg_vals = np.concatenate([chg_vals, sc])
-            new_vals = np.concatenate([new_vals, sn2])
-            self._grow_pool(nw, bw)
-        ent_vals = chg_vals & new_vals
-        self.stats["page_occupancy"] = (n_used / n_pages) if n_pages else 0.0
-        seg.begin("aoi.diff", "decode_s")
-        self._mirror_xor_stream(slots, rec["epochs"], gidx, chg_vals)
-        self._scratch_put(rec)
-        self._publish(slots, rec["epochs"], chg_vals, ent_vals, gidx, s_n)
 
     def _harvest_tri(self, rec, seg) -> None:  # gwlint: allow[host-sync] -- triples-path drain point: fetches the compact triple buffer once per flush
         """Harvest one tri-mode tick: fetch the compact (observer, observed,
@@ -3430,8 +2892,7 @@ class _TPUBucket(_Bucket):
                 faults.check("aoi.delta")
                 rows, cols = np.nonzero(diff)
                 pkt = AS.pad_packet(sl[rows], cols, new_x[rows, cols],
-                                    new_z[rows, cols],
-                                    page_granular=self.paged)
+                                    new_z[rows, cols])
                 self._dev["x"], self._dev["z"] = AS.apply_packet(
                     self._dev["x"], self._dev["z"], *pkt)
                 self.stats["h2d_bytes"] += AS.packet_nbytes(*pkt)

@@ -16,8 +16,9 @@ bucket is the membership contract:
   rebuilds every member space onto its own solo bucket the same flush,
   re-staging this tick's inputs so the republish is same-tick and
   bit-exact (``AOIEngine._demote_cohort``);
-* the paged free list (inherited) is bucket-wide, so a quiet member
-  space lends page capacity to a crowded one by construction.
+* the triples extraction cap (inherited) is bucket-wide, so a crowded
+  member space uses the room its quiet neighbours leave; a tick past
+  the cap takes the counted full-grid recovery and grows the cap.
 
 Everything else -- delta staging, fused dispatch, recovery ladder,
 export/import/evacuate -- is inherited unchanged from ``_TPUBucket``;

@@ -68,7 +68,7 @@ from ..ops import dispatch_count as DC
 from ..ops import events as EV
 from .aoi import (_Bucket, _CapDecay, _build_snapshot, _device_fault,
                   _emit_expand, _kernelish_fault, _packed_predicate,
-                  _paged_absorb_chip, _split_rows, _unpack_positions)
+                  _split_rows, _unpack_positions)
 
 _LANES = 128
 
@@ -79,24 +79,13 @@ class _MeshTPUBucket(_Bucket):
 
     def __init__(self, capacity: int, mesh, pipeline: bool = False,
                  delta_staging: bool = True, emit: str = "vector",
-                 paged: bool = False, cross_tick: bool = False,
-                 fused: bool = False):
+                 cross_tick: bool = False, fused: bool = False):
         super().__init__(capacity)
         # fused steady tick (ops/aoi_fused contract, per chip): the
         # packet scatter folds INTO the sharded step, so a steady tick
         # is ONE program launch (vs scatter + step); see _dispatch_fused
         self.fused = bool(fused)
         import jax  # noqa: F401  (fail fast if jax is unavailable)
-
-        # paged overflow absorber (docs/perf.md, paged storage): a chip
-        # whose encoded stream overflows its caps is recovered through
-        # the device-side page allocator (used pages + spilled bins D2H)
-        # instead of growing the caps (a recompile) and fetching its full
-        # diff grid; counted in page_spills, never decode_overflow
-        self.paged = bool(paged)
-        self._n_pages = 0
-        self._page_free = None
-        self._pages = None  # _PageDecay, lazily sized at first absorb
 
         # emit path for the harvested word streams (docs/perf.md emit
         # paths): "native" hands bit expansion + sort to libgwemit; on the
@@ -173,7 +162,6 @@ class _MeshTPUBucket(_Bucket):
         self.stats = {"h2d_bytes": 0, "delta_flushes": 0, "full_flushes": 0,
                       "rebuilds": 0, "fallbacks": 0, "host_ticks": 0,
                       "poisoned": 0, "calc_level": 0, "decode_overflow": 0,
-                      "page_spills": 0, "page_occupancy": 0.0,
                       "fused_dispatches": 0, "fused_demotions": 0,
                       "emit_path": AE.EMIT_LEVEL[emit]}
         # pipelined tick awaiting harvest
@@ -570,8 +558,7 @@ class _MeshTPUBucket(_Bucket):
                 faults.check("aoi.delta")
                 rows, cols = np.nonzero(diff)
                 pkt = AS.pad_packet(sl[rows], cols, new_x[rows, cols],
-                                    new_z[rows, cols],
-                                    page_granular=self.paged)
+                                    new_z[rows, cols])
                 DC.record()
                 self._dx, self._dz = self._delta_fn(len(pkt[0]))(
                     self._dx, self._dz, *pkt)
@@ -947,8 +934,7 @@ class _MeshTPUBucket(_Bucket):
         if n_changed:
             rows, cols = np.nonzero(diff)
             pkt = AS.pad_packet(sl[rows], cols, new_x[rows, cols],
-                                new_z[rows, cols],
-                                page_granular=self.paged)
+                                new_z[rows, cols])
             self.stats["h2d_bytes"] += AS.packet_nbytes(*pkt)
         else:
             zi = np.zeros(0, np.int32)
@@ -1140,7 +1126,6 @@ class _MeshTPUBucket(_Bucket):
         self._xz_stale = True
         self._h2d_cache.clear()
         self._scratch.clear()
-        self._page_free = None  # device-resident free list died with it
         self._need_rebuild = self._calc_level < 2
         # 5. compute the faulted tick on the host (staged slots only:
         # unstaged slots re-step identical inputs -> zero diff by the
@@ -1202,7 +1187,6 @@ class _MeshTPUBucket(_Bucket):
         self._xz_stale = True
         self._h2d_cache.clear()
         self._scratch.clear()
-        self._page_free = None  # device-resident free list died with it
         self._need_rebuild = self._calc_level < 2
         if rec_slots:
             self._host_tick(rec_slots, publish_now=True)
@@ -1360,43 +1344,28 @@ class _MeshTPUBucket(_Bucket):
                 # holds this tick's NEW words -- flush() harvests an
                 # overflowing tick BEFORE the next dispatch donates prev
                 # (see the scalar peek there), so the read is safe.
+                # Recover it from the raw diff grid and grow the caps for
+                # the next flush.
                 lo = d * s_local
-                if self.paged:
-                    # paged absorber: compact the kept grids into pages
-                    # on device and fetch only the used prefix -- no cap
-                    # growth, no recompile, decode_overflow stays 0
-                    chg_vals, ent_vals, gidx = _paged_absorb_chip(
-                        self, chg[lo:lo + s_local],
-                        self.prev[lo:lo + s_local], self.W)
-                    self.perf["fetch_s"] += time.perf_counter() - t0
-                    _T.lap("aoi.fetch", _tf)
-                else:
-                    # capped recovery: fetch the raw diff grid, grow the
-                    # caps for the next flush
-                    self._max_chunks = max(self._max_chunks, 2 * nd)
-                    self._kcap = min(max(self._kcap, 2 * mcc), _LANES)
-                    self.stats["decode_overflow"] += 1
-                    grew = True
-                    chg_h = np.asarray(chg[lo:lo + s_local]).reshape(-1)
-                    new_h = np.asarray(
-                        self.prev[lo:lo + s_local]).reshape(-1)
-                    gidx = np.nonzero(chg_h)[0]
-                    chg_vals = chg_h[gidx]
-                    ent_vals = chg_vals & new_h[gidx]
-                    self.perf["fetch_s"] += time.perf_counter() - t0
-                    _T.lap("aoi.fetch", _tf)
+                self._max_chunks = max(self._max_chunks, 2 * nd)
+                self._kcap = min(max(self._kcap, 2 * mcc), _LANES)
+                self.stats["decode_overflow"] += 1
+                grew = True
+                chg_h = np.asarray(chg[lo:lo + s_local]).reshape(-1)
+                new_h = np.asarray(self.prev[lo:lo + s_local]).reshape(-1)
+                gidx = np.nonzero(chg_h)[0]
+                chg_vals = chg_h[gidx]
+                ent_vals = chg_vals & new_h[gidx]
+                self.perf["fetch_s"] += time.perf_counter() - t0
+                _T.lap("aoi.fetch", _tf)
             elif n_esc > mg or exc_n > mx:
-                # encode overflow: rebuild from the kept chunk grids.
-                # In paged mode this is a counted spill (the chunk grids
-                # ARE the compact recovery source -- bounded by mc rows),
-                # with no cap growth so the compile key never churns.
-                if self.paged:
-                    self.stats["page_spills"] += 1
-                else:
-                    self._max_gaps = max(mg, 2 * n_esc)
-                    self._max_exc = max(mx, 2 * exc_n)
-                    self.stats["decode_overflow"] += 1
-                    grew = True
+                # encode overflow: rebuild from the kept chunk grids (the
+                # compact recovery source, bounded by mc rows) and grow
+                # the encode caps for the next flush
+                self._max_gaps = max(mg, 2 * n_esc)
+                self._max_exc = max(mx, 2 * exc_n)
+                self.stats["decode_overflow"] += 1
+                grew = True
                 lo = d * mc
                 vh = np.asarray(g_vals[lo:lo + mc])
                 nh = np.asarray(g_nv[lo:lo + mc])

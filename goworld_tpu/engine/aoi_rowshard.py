@@ -54,7 +54,7 @@ from ..ops import events as EV
 from ..ops import aoi_emit as AE
 from .aoi import (_Bucket, _CapDecay, _build_snapshot, _device_fault,
                   _emit_expand, _kernelish_fault, _packed_predicate,
-                  _paged_absorb_chip, _unpack_positions)
+                  _unpack_positions)
 
 _LANES = 128
 
@@ -66,8 +66,7 @@ class _RowShardTPUBucket(_Bucket):
 
     def __init__(self, capacity: int, mesh, pipeline: bool = False,
                  delta_staging: bool = True, emit: str = "vector",
-                 paged: bool = False, cross_tick: bool = False,
-                 fused: bool = False):
+                 cross_tick: bool = False, fused: bool = False):
         super().__init__(capacity)
         # fused steady tick (ops/aoi_fused contract, per chip): both
         # packet scatters (sharded block + replicated candidates) fold
@@ -75,16 +74,6 @@ class _RowShardTPUBucket(_Bucket):
         # launch (vs scatter + step); see _dispatch_fused
         self.fused = bool(fused)
         import jax  # noqa: F401  (fail fast if jax is unavailable)
-
-        # paged overflow absorber (docs/perf.md, paged storage): a chip
-        # whose encoded stream overflows its caps is recovered through
-        # the device-side page allocator (used pages + spilled bins D2H)
-        # instead of growing the caps (a recompile) and fetching its full
-        # diff grid; counted in page_spills, never decode_overflow
-        self.paged = bool(paged)
-        self._n_pages = 0
-        self._page_free = None
-        self._pages = None  # _PageDecay, lazily sized at first absorb
 
         # emit path for the harvested word streams (docs/perf.md emit
         # paths; see _MeshTPUBucket -- "vector" and "host" coincide here)
@@ -152,7 +141,6 @@ class _RowShardTPUBucket(_Bucket):
         self.stats = {"h2d_bytes": 0, "delta_flushes": 0, "full_flushes": 0,
                       "rebuilds": 0, "fallbacks": 0, "host_ticks": 0,
                       "poisoned": 0, "calc_level": 0, "decode_overflow": 0,
-                      "page_spills": 0, "page_occupancy": 0.0,
                       "fused_dispatches": 0, "fused_demotions": 0,
                       "emit_path": AE.EMIT_LEVEL[emit]}
         self._pred = (512, 64, 256)
@@ -254,8 +242,7 @@ class _RowShardTPUBucket(_Bucket):
                 faults.check("aoi.delta")
                 cols = np.nonzero(diff)[0]
                 _, cols, xv, zv = AS.pad_packet(cols, cols, self._hx[cols],
-                                                self._hz[cols],
-                                                page_granular=self.paged)
+                                                self._hz[cols])
                 DC.record()
                 self._dxs, self._dzs, self._dxr, self._dzr = \
                     self._delta_fn(len(cols))(
@@ -677,8 +664,7 @@ class _RowShardTPUBucket(_Bucket):
         if n_changed:
             cols = np.nonzero(diff)[0]
             _, cols, xv, zv = AS.pad_packet(cols, cols, self._hx[cols],
-                                            self._hz[cols],
-                                            page_granular=self.paged)
+                                            self._hz[cols])
             self.stats["h2d_bytes"] += cols.nbytes + xv.nbytes + zv.nbytes
         else:
             # zero movers: a shape-(0,) packet keeps the scatter an
@@ -801,39 +787,25 @@ class _RowShardTPUBucket(_Bucket):
             if nd > mc or mcc > kcap:
                 # incomplete stream: recover from this chip's raw diff grid
                 lo = d * cl
-                if self.paged:
-                    # paged absorber: compact the kept grids into pages
-                    # on device and fetch only the used prefix -- no cap
-                    # growth, no recompile, decode_overflow stays 0
-                    chg_vals, ent_vals, gidx = _paged_absorb_chip(
-                        self, chg[lo:lo + cl], self.prev[lo:lo + cl],
-                        self.W)
-                    self.perf["fetch_s"] += time.perf_counter() - t0
-                    _T.lap("aoi.fetch", _tf)
-                else:
-                    self._max_chunks = max(self._max_chunks, 2 * nd)
-                    self._kcap = min(max(self._kcap, 2 * mcc), _LANES)
-                    self.stats["decode_overflow"] += 1
-                    grew = True
-                    chg_h = np.asarray(chg[lo:lo + cl]).reshape(-1)
-                    new_h = np.asarray(self.prev[lo:lo + cl]).reshape(-1)
-                    gidx = np.nonzero(chg_h)[0]
-                    chg_vals = chg_h[gidx]
-                    ent_vals = chg_vals & new_h[gidx]
-                    self.perf["fetch_s"] += time.perf_counter() - t0
-                    _T.lap("aoi.fetch", _tf)
+                self._max_chunks = max(self._max_chunks, 2 * nd)
+                self._kcap = min(max(self._kcap, 2 * mcc), _LANES)
+                self.stats["decode_overflow"] += 1
+                grew = True
+                chg_h = np.asarray(chg[lo:lo + cl]).reshape(-1)
+                new_h = np.asarray(self.prev[lo:lo + cl]).reshape(-1)
+                gidx = np.nonzero(chg_h)[0]
+                chg_vals = chg_h[gidx]
+                ent_vals = chg_vals & new_h[gidx]
+                self.perf["fetch_s"] += time.perf_counter() - t0
+                _T.lap("aoi.fetch", _tf)
             elif n_esc > mg or exc_n > mx:
-                # encode overflow: rebuild from the kept chunk grids.  In
-                # paged mode this is a counted spill (the chunk grids ARE
-                # the compact recovery source, bounded by mc rows), with
-                # no cap growth so the compile key never churns.
-                if self.paged:
-                    self.stats["page_spills"] += 1
-                else:
-                    self._max_gaps = max(mg, 2 * n_esc)
-                    self._max_exc = max(mx, 2 * exc_n)
-                    self.stats["decode_overflow"] += 1
-                    grew = True
+                # encode overflow: rebuild from the kept chunk grids (the
+                # compact recovery source, bounded by mc rows) and grow
+                # the encode caps for the next flush
+                self._max_gaps = max(mg, 2 * n_esc)
+                self._max_exc = max(mx, 2 * exc_n)
+                self.stats["decode_overflow"] += 1
+                grew = True
                 lo = d * mc
                 vh = np.asarray(g_vals[lo:lo + mc])
                 nh = np.asarray(g_nv[lo:lo + mc])
@@ -991,7 +963,6 @@ class _RowShardTPUBucket(_Bucket):
         self._xz_stale = True
         self._h2d_cache.clear()
         self._scratch.clear()
-        self._page_free = None  # device-resident free list died with it
         if staged:
             self._host_tick(old_prev)
         else:

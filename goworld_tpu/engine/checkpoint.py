@@ -75,9 +75,9 @@ from .. import faults, telemetry
 from ..telemetry import trace as _T
 from .aoi import _build_snapshot, _unpack_positions
 
-# rows per dirty page of the packed interest-words matrix.  Matches the
-# paged-storage grain (ops/aoi_pages.PAGE_WORDS): a page is the unit the
-# device path already thinks in, so dirty tracking composes with it.
+# rows per dirty page of the packed interest-words matrix: the grain of
+# the journal's dirty tracking (part of the record format, so restores of
+# existing journals depend on it staying 64).
 PAGE_ROWS = 64
 
 # storage namespace for journal records; eid = "<space>.<epoch:08d>"

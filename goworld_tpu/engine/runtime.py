@@ -72,7 +72,6 @@ class Runtime:
         aoi_rowshard_min_capacity: int = 65536,
         aoi_flush_sched: bool = True,
         aoi_emit: str = "auto",
-        aoi_paged: bool = False,
         aoi_cross_tick: bool = False,
         aoi_fused: bool = False,
         aoi_interest: str = "device",
@@ -115,7 +114,7 @@ class Runtime:
                              tpu_min_capacity=aoi_tpu_min_capacity,
                              rowshard_min_capacity=aoi_rowshard_min_capacity,
                              flush_sched=aoi_flush_sched, emit=aoi_emit,
-                             paged=aoi_paged, cross_tick=aoi_cross_tick,
+                             cross_tick=aoi_cross_tick,
                              fused=aoi_fused,
                              interest_mode=aoi_interest,
                              cohort=aoi_cohort,

@@ -72,17 +72,6 @@ seam                      fires in
                           in ``aoi.cohort_demotions``; the operator
                           re-arm is ``AOIEngine.recohort`` (demoted
                           spaces re-stack through the snapshot seam)
-``aoi.pages``             paged-storage allocator at harvest (paged
-                          buckets, docs/perf.md): ``oom``/``fail``/
-                          ``partial`` = pool exhaustion -- the bucket
-                          spills the whole tick to host from the kept
-                          change grid (counted in ``aoi.page_spills``),
-                          republishes it same-tick bit-exactly and
-                          re-arms the pool; ``poison`` = page-table
-                          corruption -- validation catches it and the
-                          bucket rebuilds from the host shadows
-                          (``_recover_harvest``), reinitializing the
-                          free list
 ``conn.send``             typed packet send (proto/connection.py)
 ``conn.flush``            framed batch write (netutil/conn.py flush)
 ``conn.recv``             blocking packet read (netutil/conn.py recv)
@@ -180,9 +169,6 @@ SEAMS = {
                   "demote the whole cohort to per-space solo buckets, "
                   "counted, same-tick bit-exact republish; "
                   "AOIEngine.recohort re-arms)",
-    "aoi.pages": "paged-storage allocator at harvest (oom/fail/partial = "
-                 "counted whole-tick spill + pool re-arm; poison = page-"
-                 "table corruption caught by validation -> shadow rebuild)",
     "aoi.ingest": "batched wire->column movement decode (any kind demotes "
                   "the batch to the per-entity apply path, bit-identical)",
     "aoi.interest": "interest-policy stack evaluation (any kind = poisoned "

@@ -15,6 +15,4 @@ from .aoi_oracle import CPUAOIOracle  # noqa: F401
 from .aoi_dense import aoi_step_dense, aoi_step_dense_batched  # noqa: F401
 from .aoi_stage import apply_packet, delta_scatter, delta_scatter_1d, \
     pad_packet  # noqa: F401
-from .aoi_pages import allocate_pages_host, decode_pages, paged_extract, \
-    pool_ceiling, pool_floor, spill_stream, validate_page_table  # noqa: F401
 from .events import extract_pairs, popcount_total, unpack_words  # noqa: F401

@@ -18,10 +18,7 @@ enter/leave pair arrays the buckets publish, in one of three modes:
 
 All three orders are identical by construction (one integer sort key,
 unique within a tick); tests/test_aoi_emit.py pins the parity across the
-bucket tiers.  That key is also what lets the paged storage layout
-(:mod:`goworld_tpu.ops.aoi_pages`) feed this module an unsorted merge of
-page-packed and spilled-bin words: the sort here makes arrival order
-irrelevant, so paged and capped harvests publish byte-identical streams.  Everything here is harvest-phase numpy on already-fetched
+bucket tiers.  Everything here is harvest-phase numpy on already-fetched
 arrays -- the gwlint flush-phase rule walks this module's functions and
 rejects any blocking device fetch.
 """

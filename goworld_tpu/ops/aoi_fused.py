@@ -2,35 +2,26 @@
 bottleneck is the host/XLA boundary, not the device).
 
 The unfused steady tick crosses Python -> XLA at least twice per bucket
-(delta scatter, then the bucket step; the paged harvest adds page-table
-fetches on top), and at r04 that host-side overhead is the gap between
-8 ms of device time and 84 ms of wall.  This module compiles the WHOLE
+(delta scatter, then the bucket step), and at r04 that host-side
+overhead is the gap between 8 ms of device time and 84 ms of wall.  This module compiles the WHOLE
 steady-state tick into one jitted, donated, double-buffered program:
 
     delta-scatter of the staged packet
       -> neighbor kernel (aoi_step_chg, pallas/dense per platform)
       -> diff mask by subscription
-      -> triple extraction (tri mode) OR on-device page allocation
-         (paged mode, Ragged Paged Attention discipline: the paged
-         layout lives INSIDE the kernel, not wrapped around it)
+      -> triple extraction
 
-so a steady tick is one enqueue plus one D2H fetch.  The paged variant
-additionally concatenates ``[scalars, page_tab, spill_bins]`` -- all
-int32 -- into a single ``bundle`` vector, folding the page-table
-round-trip of the unfused harvest (the known remaining upside from the
-paged-storage PR) into the same fetch as the count scalars.
+so a steady tick is one enqueue plus one D2H fetch.
 
 Composition, not duplication: the body is assembled from the ops
 layer's jit-free pure functions (``aoi_stage.delta_scatter``,
-``aoi_dense.aoi_step_chg``, ``events.extract_triples``,
-``aoi_pages.allocate_pages``) -- the same inner functions the unfused
-path jits separately -- so fused vs unfused is a program-boundary
+``aoi_dense.aoi_step_chg``, ``events.extract_triples``) -- the same
+inner functions the unfused path jits separately -- so fused vs unfused is a program-boundary
 choice, never a semantics choice, and bit-exactness is by construction.
 
 Donation discipline: the persistent interest state (``prev_all``), the
-scratch output buffers, the page free list, and the device x/z copies
-are all donated and rebound by the caller -- steady state allocates
-nothing.  The staged packet rides the call's implicit H2D.  An empty
+scratch output buffers and the device x/z copies are all donated and
+rebound by the caller -- steady state allocates nothing.  The staged packet rides the call's implicit H2D.  An empty
 packet (zero movers) passes shape-(0,) index arrays: the scatter is a
 no-op and the compile key stays distinct and bounded
 (``aoi_stage.pad_packet`` bounds the non-empty keys).
@@ -49,13 +40,11 @@ Impls are built lazily so importing this module never loads jax
 
 from __future__ import annotations
 
-from . import aoi_pages as PG
 from . import aoi_stage as AS
 from . import dispatch_count as DC
 from . import events as EV
 
 _tri_impl = None
-_paged_impl = None
 
 
 def fused_tri_step(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
@@ -113,68 +102,3 @@ def fused_tri_step(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
     return _tri_impl(prev_all, new_buf, chg_buf, tri_buf, x_all, z_all,
                      rows, cols, xv, zv, slot_idx, r_all, act_all,
                      sub_all, max_triples, platform=platform)
-
-
-def fused_paged_step(prev_all, new_buf, chg_buf, pg_buf, pc_buf,
-                     pn_buf, free, x_all, z_all, rows, cols, xv, zv,
-                     slot_idx, r_all, act_all, sub_all, page_words,
-                     bin_words, max_spill, platform=None):
-    """Fused paged-mode tick: scatter + kernel + diff + on-device page
-    allocation in one program.
-
-    Returns ``(prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
-    free_next, bundle, x_all, z_all)``; ``bundle`` is the single int32
-    D2H vector ``concat([scalars, page_tab, spill_bins])`` the harvest
-    slices back apart -- one blocking fetch where the unfused paged
-    path pays three."""
-    global _paged_impl
-    if _paged_impl is None:
-        import functools
-
-        import jax
-        import jax.numpy as jnp
-
-        from .aoi_dense import aoi_step_chg
-
-        @functools.partial(
-            jax.jit,
-            static_argnames=("page_words", "bin_words", "max_spill",
-                             "platform"),
-            donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8))
-        def aoi_fused_paged(prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
-                            free, x_all, z_all, rows, cols, xv, zv, slot_idx,
-                            r_all, act_all, sub_all, page_words, bin_words,
-                            max_spill, platform=None):
-            x_all, z_all = AS.delta_scatter(x_all, z_all, rows, cols,
-                                            xv, zv)
-            prev_rows = prev_all[slot_idx]
-            x = x_all[slot_idx]
-            z = z_all[slot_idx]
-            r = r_all[slot_idx]
-            act = act_all[slot_idx]
-            sub = sub_all[slot_idx]
-            new, chg = aoi_step_chg(x, z, r, act, prev_rows,
-                                    platform=platform)
-            prev_all = prev_all.at[slot_idx].set(new)
-            chg = jnp.where(sub[:, None, None], chg, jnp.uint32(0))
-            (pg, pc, pn, page_tab, free_next, spill_bins,
-             scalars) = PG.allocate_pages(chg, new, free, page_words,
-                                          bin_words, max_spill)
-            bundle = jnp.concatenate([scalars, page_tab, spill_bins])
-            new_buf = new_buf.at[:].set(new)
-            chg_buf = chg_buf.at[:].set(chg)
-            pg_buf = pg_buf.at[:].set(pg)
-            pc_buf = pc_buf.at[:].set(pc)
-            pn_buf = pn_buf.at[:].set(pn)
-            return (prev_all, new_buf, chg_buf, pg_buf, pc_buf, pn_buf,
-                    free_next, bundle, x_all, z_all)
-
-        _paged_impl = aoi_fused_paged
-    DC.record_key("aoi.fused_paged", (prev_all.shape, new_buf.shape,
-                                      pg_buf.shape, rows.shape,
-                                      page_words, bin_words, max_spill,
-                                      platform))
-    return _paged_impl(prev_all, new_buf, chg_buf, pg_buf, pc_buf,
-                       pn_buf, free, x_all, z_all, rows, cols, xv, zv,
-                       slot_idx, r_all, act_all, sub_all, page_words,
-                       bin_words, max_spill, platform=platform)

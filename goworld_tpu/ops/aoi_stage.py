@@ -11,12 +11,13 @@ Shape discipline: jit compiles per packet LENGTH, so packets are padded to
 a power of two (>= ``_MIN_PACKET``) by repeating their last entry -- the
 scatter is an idempotent set, duplicate (row, col) pairs with identical
 values are harmless -- keeping the compile-key set logarithmic in packet
-size instead of one compile per mover count.  Paged buckets opt into
-``page_granular`` padding (the Ragged Paged Attention discipline carried
-to the H2D wire): mid-size packets round up to a whole number of
-``_PAGE``-entry pages instead of the next power of two, bounding padding
-waste to one page where pow2 wastes up to ~2x, while the key set stays
-small (page multiples up to ``_PAGE_KEYS`` pages, pow2 beyond).
+size instead of one compile per mover count.  The checkpoint journal
+(engine/checkpoint.py) serializes its position packets with
+``page_granular`` padding: mid-size packets round up to a whole number
+of ``_PAGE``-entry pages instead of the next power of two, bounding
+padding waste to one page where pow2 wastes up to ~2x.  That padding is
+part of the journal's record format, so existing journals restore only
+while it stays as it is.
 
 Bit-exactness: the buckets diff the float BIT PATTERNS (``view(uint32)``),
 never float equality -- NaN payloads and -0.0 vs 0.0 would otherwise let
@@ -32,9 +33,9 @@ from . import dispatch_count as DC
 from ..telemetry import trace as _T
 
 _MIN_PACKET = 64
-# page-granular padding (paged buckets): one page of packet entries; the
-# first _PAGE_KEYS page multiples are admissible compile keys, larger
-# packets fall back to pow2 so the key set stays logarithmic
+# page-granular padding (the checkpoint journal's packet format): one page
+# of packet entries; packets up to _PAGE_KEYS pages round to a page
+# multiple, larger ones fall back to pow2
 _PAGE = 64
 _PAGE_KEYS = 8
 
@@ -47,11 +48,11 @@ def pad_packet(rows: np.ndarray, cols: np.ndarray, xv: np.ndarray,
     (>= ``_MIN_PACKET``) by repeating the last entry.  Requires a non-empty
     packet (an empty delta skips the scatter entirely).
 
-    ``page_granular=True`` (paged buckets) rounds mid-size packets up to a
-    whole number of ``_PAGE``-entry pages instead -- at most one page of
-    repeated-entry waste, vs up to ~2x for pow2 -- capped at ``_PAGE_KEYS``
-    pages so the jit compile-key set stays small; bigger packets use the
-    pow2 ladder either way.  Padding never changes what the scatter writes
+    ``page_granular=True`` (the checkpoint journal, engine/checkpoint.py
+    ``_pos_packet``) rounds mid-size packets up to a whole number of
+    ``_PAGE``-entry pages instead -- at most one page of repeated-entry
+    waste, vs up to ~2x for pow2 -- up to ``_PAGE_KEYS`` pages; bigger
+    packets use the pow2 ladder either way.  Padding never changes what the scatter writes
     (idempotent set of the repeated last entry), so both paddings stage
     bit-identical device state."""
     k = len(rows)

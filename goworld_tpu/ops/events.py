@@ -22,13 +22,6 @@ classified diff into fixed-capacity (observer, observed, kind) int32
 triples ON DEVICE, so harvest fetches the compact triple buffer plus two
 scalars (the bit count and the dirty-chunk count) instead of word grids
 that still need host bit expansion.
-
-The paged layout (:mod:`goworld_tpu.ops.aoi_pages`, docs/perf.md paged
-storage) carries the same ``(gidx, chg_word, new_word)`` entries this
-module's word expanders consume, just page-packed: a paged harvest may
-hand the expanders an UNSORTED merge of paged and spilled-bin words --
-legal because every expander here sorts on the unique per-tick key, so
-the published order is identical regardless of arrival order.
 """
 
 from __future__ import annotations

@@ -4,9 +4,10 @@
 Each round derives a fresh :class:`~goworld_tpu.faults.FaultPlan` from
 ``base_seed + round``: every AOI seam gets one spec with a kind drawn
 from its legal menu at an ``@auto`` occurrence (sha256 of (seed, seam) --
-stable across processes), then a paged TPU-path engine walks a seeded
-random world next to an UNINJECTED CPU oracle.  The contract under test
-is the whole self-healing story at once, seams interacting:
+stable across processes), then a TPU-path engine (capped-triples
+extraction) walks a seeded random world next to an UNINJECTED CPU
+oracle.  The contract under test is the whole self-healing story at
+once, seams interacting:
 
 * bit-exact enter/leave parity on every tick, faults and all;
 * zero stuck buckets -- after the plan exhausts, the operator re-arm
@@ -52,7 +53,6 @@ AOI_SEAM_KINDS = {
     "aoi.scalars": ["poison", "stall"],
     "aoi.fetch": ["oom", "fail", "stall"],
     "aoi.emit": ["oom", "fail"],
-    "aoi.pages": ["oom", "fail", "partial", "poison"],
     "aoi.device": ["oom", "fail"],
 }
 
@@ -62,7 +62,7 @@ AOI_SEAM_KINDS = {
 INGEST_KINDS = ["oom", "fail", "stall", "poison"]
 
 # under the one-tick deferral (cross_tick) only dispatch-side faults keep
-# per-tick delivery timing; harvest-side recovery (fetch/scalars/pages
+# per-tick delivery timing; harvest-side recovery (fetch/scalars
 # regeneration, emit demotion mid-publish) CONVERGES instead of staying
 # tick-exact -- tests/test_cross_tick.py pins convergence for those, so
 # the cross-tick walks soak the timing-preserving menu and leave the
@@ -90,8 +90,8 @@ def build_plan(seed: int, menu=None) -> faults.FaultPlan:
 
 def soak_aoi(seed: int, cap=256, n=200, ticks=10, cross_tick=False) -> dict:
     """One engine walk under a full seam plan.  ``cross_tick=True`` runs
-    the paged bucket with the one-tick deferral on (the aoi_paged x
-    aoi_cross_tick combo): the oracle comparison shifts by one tick and a
+    the triples-path bucket with the one-tick deferral on (the
+    aoi_cross_tick path): the oracle comparison shifts by one tick and a
     trailing drain flush collects the last parked delivery."""
     rng = np.random.default_rng(seed)
     x = rng.uniform(0, 600, cap).astype(np.float32)
@@ -106,8 +106,7 @@ def soak_aoi(seed: int, cap=256, n=200, ticks=10, cross_tick=False) -> dict:
                       menu=CROSS_TICK_SEAM_KINDS if cross_tick else None)
     faults.install(plan)
     try:
-        eng = AOIEngine(default_backend="tpu", paged=True,
-                        cross_tick=cross_tick)
+        eng = AOIEngine(default_backend="tpu", cross_tick=cross_tick)
         h = eng.create_space(cap)
         ev, oev = [], []
         # ticks under fire, then the operator re-arm (demotion is sticky
@@ -151,7 +150,7 @@ def soak_aoi(seed: int, cap=256, n=200, ticks=10, cross_tick=False) -> dict:
 
 
 def soak_fused(seed: int, cap=256, n=200, ticks=10) -> dict:
-    """The ``aoi.fused`` round: a fused paged engine
+    """The ``aoi.fused`` round: a fused triples-path engine
     (``Runtime(aoi_fused=True)`` routing, docs/perf.md "Fused dispatch")
     walks next to the uninjected CPU oracle under seam specs PINNED at
     mid-walk occurrences (the soak_ingest idiom: provably fired every
@@ -178,7 +177,7 @@ def soak_fused(seed: int, cap=256, n=200, ticks=10) -> dict:
                  at=int(rng.integers(3, ticks)))
     faults.install(plan)
     try:
-        eng = AOIEngine(default_backend="tpu", paged=True, fused=True)
+        eng = AOIEngine(default_backend="tpu", fused=True)
         h = eng.create_space(cap)
         for t in range(ticks + 2):
             if t == ticks:  # plan exhausted or not: operator re-arm
@@ -290,7 +289,7 @@ def soak_cohort(seed: int, ticks=10) -> dict:
 
 
 def soak_ingest(seed: int, n=48, ticks=8) -> dict:
-    """Runtime-level ingest soak on a paged cross-tick engine: the
+    """Runtime-level ingest soak on a cross-tick triples-path engine: the
     batched wire->column decode walks under the timing-preserving
     engine-seam plan PLUS an ``aoi.ingest`` spec pinned inside the walk
     (so the batch demotion provably fires).  The drained sync stream
@@ -315,8 +314,8 @@ def soak_ingest(seed: int, n=48, ticks=8) -> dict:
         if plan is not None:
             faults.install(plan)
         try:
-            rt = Runtime(aoi_backend="tpu", aoi_paged=True,
-                         aoi_cross_tick=True, aoi_tpu_min_capacity=16)
+            rt = Runtime(aoi_backend="tpu", aoi_cross_tick=True,
+                         aoi_tpu_min_capacity=16)
             rt.entities.register(SoakScene)
             rt.entities.register(SoakWalker)
             sc = rt.entities.create_space("SoakScene", kind=1)
@@ -671,7 +670,7 @@ def main(argv):
     for i in range(rounds):
         seed = base_seed + i
         # alternate the engine walk's cadence so every soak covers both
-        # the sequential bucket and the aoi_paged x aoi_cross_tick combo
+        # the sequential bucket and the aoi_cross_tick path
         xt = bool(i % 2)
         a = soak_aoi(seed, cross_tick=xt)
         f = soak_fused(seed)
@@ -685,7 +684,7 @@ def main(argv):
               f"{' xtick' if xt else ''}: "
               f"aoi fired={a['fired']} rebuilds={a['stats']['rebuilds']} "
               f"host_ticks={a['stats']['host_ticks']} "
-              f"page_spills={a['stats']['page_spills']} | "
+              f"decode_overflow={a['stats']['decode_overflow']} | "
               f"fused n={f['fused']} demoted={f['demoted']} | "
               f"cohort {co['kind']}@{co['at']} demoted={co['demoted']} "
               f"restacked={co['redispatched']} | "

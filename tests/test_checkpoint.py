@@ -6,7 +6,8 @@ The contract under test (docs/robustness.md "Durability & crash-restart"):
 * a space restored from its journal produces the IDENTICAL enter/leave
   event stream as the uncrashed oracle for >= 20 post-restore ticks,
   across the device bucket tiers (``tpu``/``mesh``/``rowshard``) and with
-  the paged event store and the cross-tick scheduler on or off;
+  the classic encoded stream (``emit="host"``) and the cross-tick
+  scheduler on or off;
 * the manifest is monotonic in ``(space, epoch, tick)`` and every entry's
   CRC matches its journal record;
 * a real ``kill -9`` mid-run loses nothing: restore + replay merged with
@@ -76,13 +77,14 @@ def _tick(eng, handles, frame, r, act):
             for h in handles]
 
 
-def _run_restore_parity(tmp_path, tier, paged, cross_tick, cap=CAP):
+def _run_restore_parity(tmp_path, tier, host_emit, cross_tick, cap=CAP):
     """Checkpoint a space for PRE ticks, restore it into a SECOND handle
     on the same engine (same already-jitted bucket kernels), then drive
     oracle and restored space through POST identical frames and compare
     the concatenated delivered streams bit-exactly."""
     mesh = 2 if tier in ("mesh", "rowshard") else None
-    eng = AOIEngine("cpu", mesh=mesh, paged=paged, cross_tick=cross_tick)
+    eng = AOIEngine("cpu", mesh=mesh, cross_tick=cross_tick,
+                    emit="host" if host_emit else "auto")
     ctl, store, kv = _mk(tmp_path, eng)
     h = eng._create_handle(cap, tier)
     ctl.track("s", h)
@@ -121,7 +123,8 @@ def _run_restore_parity(tmp_path, tier, paged, cross_tick, cap=CAP):
         a = np.concatenate([np.asarray(v).ravel() for v in oracle[side]])
         b = np.concatenate([np.asarray(v).ravel() for v in restored[side]])
         assert np.array_equal(a, b), \
-            f"{tier} paged={paged} xtick={cross_tick}: stream diverged"
+            f"{tier} host_emit={host_emit} xtick={cross_tick}: " \
+            "stream diverged"
     assert sum(len(v) for v in oracle[0]) > 0, "degenerate walk: no events"
     ctl.close()
     rest.close()
@@ -129,7 +132,7 @@ def _run_restore_parity(tmp_path, tier, paged, cross_tick, cap=CAP):
     kv.close()
 
 
-# tier-1 covers every tier and every +/-paged +/-cross_tick axis; the
+# tier-1 covers every tier and every +/-host-emit +/-cross_tick axis; the
 # remaining mesh/rowshard single-flag combos ride the @slow sweep (each
 # fresh mesh/rowshard engine re-jits its kernels on the CPU backend)
 TIER1_COMBOS = [
@@ -140,9 +143,8 @@ TIER1_COMBOS = [
     ("mesh", True, True),
     ("rowshard", True, True),
 ]
-# The plain multi-chip combos cost ~45 s of wall on the virtual CPU mesh
-# (no paged absorber to shrink the chunk streams); the full tier x flag
-# matrix stays pinned under -m slow.
+# The other multi-chip combos cost ~45 s of wall on the virtual CPU mesh;
+# the full tier x flag matrix stays pinned under -m slow.
 SLOW_COMBOS = [
     ("mesh", False, False),
     ("mesh", True, False),
@@ -154,20 +156,20 @@ SLOW_COMBOS = [
 
 
 @pytest.mark.parametrize(
-    "tier,paged,cross_tick", TIER1_COMBOS,
-    ids=[f"{t}{'+paged' if p else ''}{'+xtick' if c else ''}"
+    "tier,host_emit,cross_tick", TIER1_COMBOS,
+    ids=[f"{t}{'+host' if p else ''}{'+xtick' if c else ''}"
          for t, p, c in TIER1_COMBOS])
-def test_restore_parity(tmp_path, tier, paged, cross_tick):
-    _run_restore_parity(tmp_path, tier, paged, cross_tick)
+def test_restore_parity(tmp_path, tier, host_emit, cross_tick):
+    _run_restore_parity(tmp_path, tier, host_emit, cross_tick)
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize(
-    "tier,paged,cross_tick", SLOW_COMBOS,
-    ids=[f"{t}{'+paged' if p else ''}{'+xtick' if c else ''}"
+    "tier,host_emit,cross_tick", SLOW_COMBOS,
+    ids=[f"{t}{'+host' if p else ''}{'+xtick' if c else ''}"
          for t, p, c in SLOW_COMBOS])
-def test_restore_parity_slow(tmp_path, tier, paged, cross_tick):
-    _run_restore_parity(tmp_path, tier, paged, cross_tick)
+def test_restore_parity_slow(tmp_path, tier, host_emit, cross_tick):
+    _run_restore_parity(tmp_path, tier, host_emit, cross_tick)
 
 
 # -- the journal itself ------------------------------------------------------
@@ -230,6 +232,50 @@ def test_incremental_records_are_deltas(tmp_path):
     delta = store.read(RECORD_TYPE, "s.00000001")
     assert len(delta["blob"]) < len(base["blob"])
     ctl.close()
+
+
+def test_pad_packet_page_granular():
+    from goworld_tpu.ops import aoi_stage as AS
+
+    def mk(k):
+        i = np.arange(k, dtype=np.int32)
+        return i, i, i.astype(np.float32), i.astype(np.float32)
+
+    # mid-size packets round to whole pages (<= one page of waste)...
+    assert len(AS.pad_packet(*mk(130), page_granular=True)[0]) == 192
+    assert len(AS.pad_packet(*mk(130))[0]) == 256  # pow2 default
+    # ...tiny and huge packets take the pow2 ladder either way
+    assert len(AS.pad_packet(*mk(30), page_granular=True)[0]) == 64
+    assert len(AS.pad_packet(*mk(513), page_granular=True)[0]) == 1024
+    # padding repeats the last entry (idempotent under the set scatter)
+    rows, cols, xv, zv = AS.pad_packet(*mk(130), page_granular=True)
+    assert (rows[130:] == 129).all() and (xv[130:] == 129.0).all()
+
+
+def test_pos_packet_journal_bytes_pinned():
+    """A journal's position packets keep their bytes: the page-granular
+    padding (packet sizes and the repeated tail) is part of the record
+    format, so journals already written stay restorable."""
+    import hashlib
+
+    from goworld_tpu.engine.checkpoint import _pos_packet
+
+    digest = hashlib.sha256()
+    sizes = []
+    for k in (1, 30, 64, 65, 130, 512, 513):
+        cols = np.arange(k, dtype=np.int64) * 3
+        x = np.linspace(-1.0, 1.0, k).astype(np.float32)
+        z = np.linspace(5.0, -5.0, k).astype(np.float32)
+        pkt = _pos_packet(cols, x, z)
+        sizes.append(pkt["n"])
+        for key in ("rows", "cols", "xv", "zv"):
+            digest.update(pkt[key])
+    assert sizes == [64, 64, 64, 128, 192, 512, 1024]
+    assert digest.hexdigest() == ("ae950d436a7cd02d2ac77ca520c5f4fc"
+                                  "613cfdffb23983612115dff4566316fa")
+    empty = np.zeros(0, np.int64)
+    assert _pos_packet(empty, empty.astype(np.float32),
+                       empty.astype(np.float32)) is None
 
 
 def test_full_every_bounds_the_chain(tmp_path):

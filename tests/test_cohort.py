@@ -147,8 +147,10 @@ def test_cohort_parity(fused):
     _assert_same(out)
 
 
-def test_cohort_parity_paged():
-    engines, handles = _engines(paged=True)
+def test_cohort_parity_host_emit():
+    """The same parity on the classic encoded stream (``emit="host"``):
+    the stacked bucket's chunk caps are shared by every member space."""
+    engines, handles = _engines(emit="host")
     out = _drive(engines, handles, 6)
     _assert_same(out)
 

@@ -9,8 +9,9 @@ words -- BIT-exactly, across:
 * ``pad_snapshot`` growth between ladder rungs (pow2 planar repack and
   the dense fallback both);
 * slot release + reuse inside a live cohort bucket;
-* cross-cohort page lending (paged cohort bucket: one crowded member
-  borrows pool pages a quiet member never uses, events stay bit-exact).
+* one crowded member past the cohort bucket's shared triples cap beside
+  quiet ones (counted full-grid recovery, the cap grows, events stay
+  bit-exact).
 
 Positions are compared by BIT PATTERN (``view(uint32)``), never float
 equality -- the delta-staging discipline (NaN payloads, -0.0 vs 0.0).
@@ -165,23 +166,26 @@ def test_round_trip_survives_grow():
     assert not m1[256:].any() and not m1[:, 256:].any()
 
 
-def test_cross_cohort_page_lending():
-    """Paged cohort bucket: the page pool is bucket-wide, so a crowded
-    space draws pages a quiet space never claims -- and both spaces'
-    event streams stay bit-exact vs the oracle and the solo baseline."""
+def test_cohort_crowded_member_past_shared_triples_cap():
+    """The triples cap is bucket-wide: one crowded space beside quiet
+    ones drives the shared cohort bucket past a tiny cap.  The tick takes
+    the counted full-grid recovery, the cap grows, and every member's
+    stream stays bit-exact vs the oracle and the solo baseline."""
     from test_aoi_delta import _pad, _scene, _sparse_step
 
     engines = {
         "cpu": AOIEngine(default_backend="cpu"),
-        "cohort": AOIEngine(default_backend="tpu", cohort="auto",
-                            paged=True),
-        "solo": AOIEngine(default_backend="tpu", cohort="solo",
-                          paged=True),
+        "cohort": AOIEngine(default_backend="tpu", cohort="auto"),
+        "solo": AOIEngine(default_backend="tpu", cohort="solo"),
     }
-    # one crowded space (dense interest) + one nearly-empty one
-    loads = [(256, 220), (256, 4)]
+    # one crowded space (dense interest) + two nearly-empty ones
+    loads = [(256, 220), (256, 4), (200, 6)]
     handles = {k: [e.create_space(c) for c, _n in loads]
                for k, e in engines.items()}
+    bucket = handles["cohort"][0].bucket
+    assert all(h.bucket is bucket for h in handles["cohort"]), \
+        "one shared bucket"
+    bucket._max_triples = 64  # the crowded member alone overflows it
     scenes = [list(_scene(21 + i, cap, n))
               for i, (cap, n) in enumerate(loads)]
     out = {k: [] for k in engines}
@@ -199,9 +203,10 @@ def test_cross_cohort_page_lending():
         for t in range(6):
             for si in range(len(loads)):
                 re_, rl = out["cpu"][t][si]
-                pe, pl = out[k][t + 0][si]
+                pe, pl = out[k][t][si]
                 np.testing.assert_array_equal(re_, pe)
                 np.testing.assert_array_equal(rl, pl)
-    bucket = handles["cohort"][0].bucket
-    assert bucket is handles["cohort"][1].bucket, "one shared pool"
-    assert bucket.stats.get("page_occupancy", 0) > 0
+    assert bucket.stats["decode_overflow"] >= 1
+    assert bucket._max_triples > 64, "the shared cap never grew"
+    assert all(h.bucket.stats["decode_overflow"] == 0
+               for h in handles["solo"][1:]), "quiet solo spaces overflowed"

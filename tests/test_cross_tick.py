@@ -8,8 +8,9 @@ The contract under test (docs/perf.md cross-tick section):
 * it composes IDEMPOTENTLY with ``pipeline``: either flag, or both,
   produce the same single-shift stream (``_defer = pipeline or
   cross_tick``);
-* the parity holds with the split-phase scheduler on or off and with
-  paged storage on or off;
+* the parity holds with the split-phase scheduler on or off and on both
+  extraction formats (capped triples, and the classic encoded stream of
+  ``emit="host"``);
 * the row-sharded tier stays synchronous (cross_tick accepted, ignored)
   -- a single giant space keeps zero added latency;
 * a fault during tick T's harvest while T+1 is already dispatched must
@@ -46,13 +47,14 @@ def _engines(variants: dict, **common):
 
 
 @pytest.mark.parametrize("flush_sched", [False, True])
-@pytest.mark.parametrize("paged", [False, True])
-def test_cross_tick_shifted_parity(flush_sched, paged):
+@pytest.mark.parametrize("host_emit", [False, True])
+def test_cross_tick_shifted_parity(flush_sched, host_emit):
     """cross_tick == sequential shifted exactly one tick, with the
-    split-phase scheduler and paged storage toggled both ways."""
+    split-phase scheduler toggled both ways, on the triples path and on
+    the classic encoded stream (``emit="host"``)."""
     engines, handles = _engines(
         {"xt": {"cross_tick": True}, "seq": {}},
-        flush_sched=flush_sched, paged=paged)
+        flush_sched=flush_sched, emit="host" if host_emit else "auto")
     out = _drive_multi(engines, handles, 8)
     assert all(len(e) == 0 and len(l) == 0 for e, l in out["xt"][0]), \
         "cross-tick tick 0 delivers nothing"

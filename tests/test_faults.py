@@ -54,7 +54,7 @@ def test_seam_catalog_stable():
     """The catalog is API: docs, gwlint and env strings name these."""
     assert set(faults.SEAMS) == {
         "aoi.grow", "aoi.h2d", "aoi.delta", "aoi.kernel", "aoi.scalars",
-        "aoi.fetch", "aoi.emit", "aoi.device", "aoi.pages", "aoi.ingest",
+        "aoi.fetch", "aoi.emit", "aoi.device", "aoi.ingest",
         "aoi.interest", "aoi.cohort", "conn.send", "conn.flush", "conn.recv",
         "disp.connect", "bench.config", "store.write", "store.read",
         "store.manifest", "clu.lease", "clu.kill", "clu.zombie",

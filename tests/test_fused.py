@@ -5,9 +5,10 @@ The contract under test (docs/perf.md "Fused dispatch"):
 
 * a fused steady-state tick compiles the whole per-bucket pipeline --
   delta scatter -> neighbor kernel -> diff/classify -> triple extraction
-  or on-device page allocation -- into ONE jitted, donated program, and
-  its event stream is bit-exact vs the unfused path and the CPU oracle
-  across tiers +/- paged +/- cross_tick +/- interest stacks;
+  -- into ONE jitted, donated program, and its event stream is bit-exact
+  vs the unfused path and the CPU oracle across tiers +/- cross_tick
+  +/- interest stacks; the classic encoded stream (``emit="host"``) has
+  no fused program and falls through to the unfused path, bit-exact;
 * device dispatches per steady-state tick == 1 for the fused
   single-chip bucket (counted through ``ops.dispatch_count``; unfused
   pays 2: scatter + step).  The mesh and row-sharded tiers launch one
@@ -53,29 +54,38 @@ def _engines(variants: dict, **common):
 
 # -- parity: fused vs unfused vs oracle --------------------------------------
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_fused_parity_single_chip(paged):
-    """Fused single-chip is bit-exact vs unfused and the oracle, triples
-    and paged modes both, and the fused path actually runs."""
+@pytest.mark.parametrize("host_emit", [False, True])
+def test_fused_parity_single_chip(host_emit):
+    """Fused single-chip is bit-exact vs unfused and the oracle.  On the
+    triples path the fused program actually runs; the classic encoded
+    stream (``emit="host"``) has none, so every tick falls through to
+    the unfused flow -- silently, not as a demotion."""
     engines, handles = _engines(
-        {"fused": {"fused": True}, "plain": {}}, paged=paged)
+        {"fused": {"fused": True}, "plain": {}},
+        emit="host" if host_emit else "auto")
     out = _drive_multi(engines, handles, 8)
     _assert_multi_same(out)
     st = handles["fused"][0].bucket.stats
-    assert st["fused_dispatches"] > 0, "fused path never taken"
+    if host_emit:
+        assert st["fused_dispatches"] == 0, "host emit has no fused program"
+    else:
+        assert st["fused_dispatches"] > 0, "fused path never taken"
     assert st["fused_demotions"] == 0
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_fused_cross_tick_parity(paged):
+@pytest.mark.parametrize("host_emit", [False, True])
+def test_fused_cross_tick_parity(host_emit):
     """fused composes with the one-tick deferral: fused+cross_tick is
-    the oracle shifted exactly one tick, like unfused cross_tick."""
+    the oracle shifted exactly one tick, like unfused cross_tick, on
+    both extraction formats."""
     engines, handles = _engines(
-        {"fxt": {"fused": True, "cross_tick": True}}, paged=paged)
+        {"fxt": {"fused": True, "cross_tick": True}},
+        emit="host" if host_emit else "auto")
     out = _drive_multi(engines, handles, 8)
     _drain_trailing(engines, handles, out, ("fxt",))
     _assert_multi_same(out, shift=1, keys=("fxt",))
-    assert handles["fxt"][0].bucket.stats["fused_dispatches"] > 0
+    n_fused = handles["fxt"][0].bucket.stats["fused_dispatches"]
+    assert (n_fused == 0) if host_emit else (n_fused > 0)
 
 
 def test_fused_mesh_parity():
@@ -213,11 +223,11 @@ def test_fused_demotion_republishes_same_tick(seam):
     assert demos >= 1, f"forced {seam} fault did not demote"
 
 
-def test_fused_demotion_paged_under_fault_plan():
-    """Same contract, paged storage + a multi-seam plan (the soak's
-    shape): parity holds and every fired seam either demoted the fused
-    attempt or hit the shared recovery path."""
-    engines, handles = _engines({"fused": {"fused": True}}, paged=True)
+def test_fused_demotion_under_fault_plan():
+    """Same contract on the triples path under a multi-seam plan (the
+    soak's shape): parity holds and every fired seam either demoted the
+    fused attempt or hit the shared recovery path."""
+    engines, handles = _engines({"fused": {"fused": True}})
     faults.install("seed=3;aoi.kernel:fail@3;aoi.delta:oom@5")
     out = _drive_multi(engines, handles, 8)
     _assert_multi_same(out)

@@ -6,8 +6,9 @@ rates"):
 * the fused device step -- radius AND team mask AND tier cadence AND
   line of sight -- is BIT-EXACT against the composed CPU oracle
   (interest/oracle.py) for every policy combination, standalone and
-  behind the engine seam across the bucket tiers with the paged event
-  store and the cross-tick scheduler on or off;
+  behind the engine seam across the bucket tiers with the classic
+  encoded stream (``emit="host"``) and the cross-tick scheduler on or
+  off;
 * stacks with different tier periods agree bit-exactly on coinciding
   full-cadence boundary ticks, with strictly fewer line-of-sight
   samples for the larger period (``interest.los_pair_evals`` -- the
@@ -134,7 +135,7 @@ def test_stack_device_host_parity(combo):
 # discarding the bucket diff in favor of the stack's, the base bucket
 # still carrying radius state underneath.  Fresh mesh/rowshard engines
 # re-JIT (~12s each on the CPU backend), so tier-1 keeps one row per
-# tier and spreads the +/-paged +/-cross_tick axes across them; the
+# tier and spreads the +/-host-emit +/-cross_tick axes across them; the
 # full cross-product is tier-2 (@slow).
 
 TIER1_ENGINE = [
@@ -154,9 +155,10 @@ SLOW_ENGINE = [
 ]
 
 
-def _engine_parity(tier, paged, cross_tick, cap=ENGINE_CAP):
+def _engine_parity(tier, host_emit, cross_tick, cap=ENGINE_CAP):
     mesh = 2 if tier in ("mesh", "rowshard") else None
-    eng = AOIEngine("cpu", mesh=mesh, paged=paged, cross_tick=cross_tick)
+    eng = AOIEngine("cpu", mesh=mesh, cross_tick=cross_tick,
+                    emit="host" if host_emit else "auto")
     h = eng._create_handle(cap, tier)
     stack = eng.attach_interest(h, _policies("team+tier+los"))
     assert AOIEngine.interest_stack(h) is stack
@@ -180,27 +182,28 @@ def _engine_parity(tier, paged, cross_tick, cap=ENGINE_CAP):
         a = np.concatenate(got[side])
         b = np.concatenate(want[side])
         assert np.array_equal(a, b), \
-            f"{tier} paged={paged} xtick={cross_tick}: {name} diverged"
+            f"{tier} host_emit={host_emit} xtick={cross_tick}: " \
+            f"{name} diverged"
     assert np.array_equal(stack.words, ref.words)
     assert sum(len(v) for v in want[0]) > 0, "degenerate walk: no events"
     assert stack.stats["demotions"] == 0
 
 
 @pytest.mark.parametrize(
-    "tier,paged,cross_tick", TIER1_ENGINE,
-    ids=[f"{t}{'+paged' if p else ''}{'+xtick' if c else ''}"
+    "tier,host_emit,cross_tick", TIER1_ENGINE,
+    ids=[f"{t}{'+host' if p else ''}{'+xtick' if c else ''}"
          for t, p, c in TIER1_ENGINE])
-def test_engine_stack_parity(tier, paged, cross_tick):
-    _engine_parity(tier, paged, cross_tick)
+def test_engine_stack_parity(tier, host_emit, cross_tick):
+    _engine_parity(tier, host_emit, cross_tick)
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize(
-    "tier,paged,cross_tick", SLOW_ENGINE,
-    ids=[f"{t}{'+paged' if p else ''}{'+xtick' if c else ''}"
+    "tier,host_emit,cross_tick", SLOW_ENGINE,
+    ids=[f"{t}{'+host' if p else ''}{'+xtick' if c else ''}"
          for t, p, c in SLOW_ENGINE])
-def test_engine_stack_parity_sweep(tier, paged, cross_tick):
-    _engine_parity(tier, paged, cross_tick)
+def test_engine_stack_parity_sweep(tier, host_emit, cross_tick):
+    _engine_parity(tier, host_emit, cross_tick)
 
 
 # -- tiered rates: bit-exact on boundary ticks, cheaper in between -----------

@@ -58,8 +58,6 @@ CANONICAL_NAMES = (
     "aoi.fetch", "aoi.wait", "aoi.diff", "aoi.decode", "aoi.fanout",
     "aoi.host_tick", "aoi.buckets", "aoi.calc_level", "aoi.emit_path",
     "aoi.launches", "aoi.tri_chunks",
-    # paged ragged neighbor/event storage (ops/aoi_pages.py absorbers)
-    "aoi.pages", "aoi.page_occupancy", "aoi.page_spills",
     # live migration / chip-loss failover (engine/placement.py): start
     # spans, per-flush cover/swap + evacuation spans, totals
     "aoi.migrate", "aoi.migrate.snapshot", "aoi.migrate.replay",
@@ -489,7 +487,7 @@ def test_tri_chunks_counts_dirty_chunks_of_the_tick():
         eng.submit(h, x, z, r, act)
     eng.flush()
     b = hs[0].bucket
-    assert b._emit == "vector" and not b.paged
+    assert b._emit == "vector" and b._scratch_key(2)[1] == -1  # triples
     before = np.array(b.prev, copy=True)
     chunks = telemetry.registry().counter("aoi.tri_chunks")
     telemetry.enable()
