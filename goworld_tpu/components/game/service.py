@@ -1019,21 +1019,20 @@ class GameService:
             for e, ids in pending_interests:
                 # PLAIN entities stay lazy -- their interests live only in
                 # the seeded packed words below; eager sets are rebuilt just
-                # for entities with clients/hooks
+                # for entities with hooks
                 if e._plain_aoi:
                     continue
-                for oid in ids:
-                    other = self.rt.entities.get(oid)
-                    if other is None:
-                        continue
-                    e.interested_in.add(other)
-                    other.interested_by.add(e)
-                    if e.client is not None:
-                        other._watcher_clients += 1
+                others = [o for o in map(self.rt.entities.get, ids)
+                          if o is not None]
+                if not e._default_hooks:
+                    for other in others:
+                        e._ints_in.add(other)
+                        other._ints_by.add(e)
                 # the restored pairs of a watching client enter its space's
-                # watch index (engine/space.py), as a bound client's do
+                # watch index (engine/space.py), as a bound client's do:
+                # with default hooks the index is their only record
                 if e.client is not None:
-                    e._touch_client()
+                    e._touch_client(others)
             from ...ops import aoi_predicate as AP
             import numpy as np
 

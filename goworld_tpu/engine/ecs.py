@@ -37,6 +37,15 @@ whose id bytes and gate ``cid``/``gate`` hold (kept by the client-binding
 sites, ``Entity._touch_client``); ``eid`` holds the entity id bytes,
 written when the slot is assigned.  Ids are fixed-width bytes, widened
 if an id longer than 16 bytes is ever written (the wire's ids are 16).
+
+The replay columns serve event replay as whole arrays
+(``Space._replay_columns``): ``hooked`` says the slot's type overrides
+``on_enter_aoi``/``on_leave_aoi``; ``watchers`` counts the watch-index
+pairs whose target is the slot (the clients watching it); ``deltas``
+says the slot's entity holds attr deltas not yet routed; ``shown`` says
+its type has ``all_client_attrs`` (a neighbour's create op carries
+attrs); ``tname``, ``ident`` and ``gclient`` hold the entity's type
+name, id and ``GameClient`` (or None) as objects.
 """
 
 from __future__ import annotations
@@ -61,7 +70,9 @@ class ColumnStore:
     indices are stable for the space's lifetime)."""
 
     __slots__ = ("cap", "x", "z", "r", "act", "nonplain", "team", "vis",
-                 "y", "yaw", "sync", "client", "cid", "gate", "eid")
+                 "y", "yaw", "sync", "client", "cid", "gate", "eid",
+                 "hooked", "watchers", "deltas", "shown", "tname", "ident",
+                 "gclient")
 
     def __init__(self):
         self.cap = 0
@@ -79,6 +90,13 @@ class ColumnStore:
         self.cid = np.zeros(0, "S%d" % ID_BYTES)
         self.gate = np.zeros(0, np.int64)
         self.eid = np.zeros(0, "S%d" % ID_BYTES)
+        self.hooked = np.zeros(0, bool)
+        self.watchers = np.zeros(0, np.int32)
+        self.deltas = np.zeros(0, bool)
+        self.shown = np.zeros(0, bool)
+        self.tname = np.empty(0, object)
+        self.ident = np.empty(0, object)
+        self.gclient = np.empty(0, object)
 
     def ensure_capacity(self, new_cap: int):
         if new_cap <= self.cap:
@@ -91,9 +109,16 @@ class ColumnStore:
         for name, dt in (("act", bool), ("nonplain", bool),
                          ("team", np.uint32), ("vis", np.uint32),
                          ("sync", np.uint8), ("client", bool),
-                         ("cid", None), ("gate", np.int64), ("eid", None)):
+                         ("cid", None), ("gate", np.int64), ("eid", None),
+                         ("hooked", bool), ("watchers", np.int32),
+                         ("deltas", bool), ("shown", bool)):
             arr = getattr(self, name)
             grown = np.zeros(new_cap, dt or arr.dtype)
+            grown[: len(arr)] = arr
+            setattr(self, name, grown)
+        for name in ("tname", "ident", "gclient"):
+            arr = getattr(self, name)
+            grown = np.empty(new_cap, object)  # None-filled
             grown[: len(arr)] = arr
             setattr(self, name, grown)
         self.cap = new_cap
@@ -110,6 +135,13 @@ class ColumnStore:
         self.cid[slot] = b""
         self.gate[slot] = 0
         self.eid[slot] = b""
+        self.hooked[slot] = False
+        self.watchers[slot] = 0
+        self.deltas[slot] = False
+        self.shown[slot] = False
+        self.tname[slot] = None
+        self.ident[slot] = None
+        self.gclient[slot] = None
 
     def put_id(self, name: str, slot: int, ident: str):
         """Write an id's bytes into the ``cid`` or ``eid`` column, widening

@@ -17,6 +17,7 @@ from the reference are deliberate and TPU/batch-first:
 
 from __future__ import annotations
 
+from operator import attrgetter
 from typing import TYPE_CHECKING, Any, Iterable
 
 from .attrs import MapAttr
@@ -31,6 +32,8 @@ if TYPE_CHECKING:
 # Entity.go:1199-1204)
 SYNC_OWN = 1
 SYNC_NEIGHBORS = 2
+
+_SLOT = attrgetter("aoi_slot")  # sort key: interest records go by slot
 
 
 class GameClient:
@@ -113,25 +116,30 @@ class Entity:
         self._yaw: float = 0.0
         self.space: "Space | None" = None
         self.aoi_slot: int = -1  # slot in the space's arrays while in a space
-        self.interested_in: set[Entity] = set()
-        self.interested_by: set[Entity] = set()
-        # how many of interested_by have a client -- maintained by
-        # _interest/_uninterest/set_client so the sync phase can skip the
-        # neighbor fanout for entities nobody's client is watching (the
-        # common case: server-side mobs far from any player)
-        self._watcher_clients = 0
+        # the eager interest record, kept only for types that override
+        # on_enter_aoi/on_leave_aoi (their hooks run per pair): what this
+        # entity is interested in, and which such observers are interested
+        # in it.  A client observer with default hooks is recorded in its
+        # space's watch index alone (engine/space.py), and a plain entity
+        # in the calculator's packed words: interested_in, interested_by,
+        # _watcher_clients and neighbors() derive from all three
+        self._ints_in: set[Entity] = set()
+        self._ints_by: set[Entity] = set()
+        cls = type(self)
+        self._default_hooks = (cls.on_enter_aoi is Entity.on_enter_aoi
+                               and cls.on_leave_aoi is Entity.on_leave_aoi)
         self.client: GameClient | None = None
         self.client_syncing = False  # accept client-originated position sync
         self._timer_ids: dict[int, tuple] = {}  # tid -> (method, interval, repeat, args)
         self._sync_flags = 0
         self._attr_deltas: list[tuple] = []  # (path, op, value) this tick
         self.destroyed = False
-        # hot-path caches, set by EntityManager.create: the runtime's stable
-        # dirty-set object, and whether AOI event replay for this entity is
-        # pure set bookkeeping (no client, default hooks -- the batched fast
-        # path in Space.dispatch_aoi_events)
+        # hot-path caches: the runtime's stable dirty-set object (set by
+        # EntityManager.create), and whether AOI event replay for this
+        # entity is pure set bookkeeping (no client, default hooks -- the
+        # batched fast path in Space.dispatch_aoi_events)
         self._dirty_set: set | None = None
-        self._plain_aoi = True
+        self._plain_aoi = self._default_hooks
 
     # ------------------------------------------------------------------ api
     def _mark_dirty(self):
@@ -144,26 +152,20 @@ class Entity:
             s.add(self)
 
     def _recompute_plain(self):
-        if self.desc is not None:
-            self._plain_aoi = self.client is None and self.desc.plain_aoi_hooks
-        else:
-            cls = type(self)
-            self._plain_aoi = self.client is None and (
-                cls.on_enter_aoi is Entity.on_enter_aoi
-                and cls.on_leave_aoi is Entity.on_leave_aoi
-            )
+        self._plain_aoi = self.client is None and self._default_hooks
         if self.aoi_slot >= 0 and self.space is not None:
             self.space._nonplain[self.aoi_slot] = not self._plain_aoi
 
-    def _touch_client(self):
+    def _touch_client(self, targets: Iterable["Entity"] = ()):
         """Mirror the client binding into the space's client columns
-        (engine/ecs.py) and recompute this entity's pairs in the space's
-        watch index (engine/space.py) -- the columnar sync collect reads
-        both, so every client transition while slotted comes here."""
+        (engine/ecs.py) and set this entity's pairs in the space's watch
+        index (engine/space.py) to ``targets`` while it has a client --
+        the columnar sync collect and event replay read both, so every
+        client transition while slotted comes here."""
         sp = self.space
         if self.aoi_slot >= 0 and sp is not None:
             sp._set_client_cols(self.aoi_slot, self.client)
-            sp._rewatch(self)
+            sp._rewatch(self, targets)
 
     @property
     def is_space(self) -> bool:
@@ -219,6 +221,8 @@ class Entity:
     def _on_attr_delta(self, path: tuple, op: str, value: Any):
         self._attr_deltas.append((path, op, value))
         self._mark_dirty()
+        if self.aoi_slot >= 0:
+            self.space._cols.deltas[self.aoi_slot] = True
 
     def client_visible_attrs(self, to_owner: bool) -> dict:
         """Snapshot of attrs visible to a client (own client sees ``client``
@@ -251,6 +255,9 @@ class Entity:
             return
         deltas = self._attr_deltas
         self._attr_deltas = []
+        if self.aoi_slot >= 0:
+            self.space._cols.deltas[self.aoi_slot] = False
+        audience = None
         for path, op, value in deltas:
             top = path[0]
             to_owner = top in self.client_attrs or top in self.all_client_attrs
@@ -258,9 +265,10 @@ class Entity:
             if to_owner and self.client is not None:
                 self.client.attr_delta(self.id, path, op, value)
             if to_neighbors:
-                for other in self.interested_by:
-                    if other.client is not None:
-                        other.client.attr_delta(self.id, path, op, value)
+                if audience is None:
+                    audience = self._watching_clients()
+                for c in audience:
+                    c.attr_delta(self.id, path, op, value)
 
     # -- position / AOI ----------------------------------------------------
     @property
@@ -345,29 +353,77 @@ class Entity:
         self._mark_dirty()
 
     # interest bookkeeping -- driven by the space's batched AOI events
-    # (reference: interest/uninterest, Entity.go:236-246)
+    # (reference: interest/uninterest, Entity.go:236-246).  Event replay
+    # calls these per pair only for observers whose type overrides the
+    # AOI hooks; a client observer with default hooks is replayed as whole
+    # arrays (Space._replay_columns) and never comes here
     def _interest(self, other: "Entity"):
         # flush other's pending deltas to its *pre-existing* audience before
         # we join it: the snapshot below already contains them, and a mirror
         # that applied both would double-apply non-idempotent ops (APPEND/POP)
         if self.client is not None:
             other._flush_attr_deltas()
-        if other not in self.interested_in and self.client is not None:
-            other._watcher_clients += 1
-        self.interested_in.add(other)
-        other.interested_by.add(self)
+        self._ints_in.add(other)
+        other._ints_by.add(self)
         if self.client is not None:
             self.client.create_entity(other, is_player=False)
         self.on_enter_aoi(other)
 
     def _uninterest(self, other: "Entity"):
-        if other in self.interested_in and self.client is not None:
-            other._watcher_clients -= 1
-        self.interested_in.discard(other)
-        other.interested_by.discard(self)
+        self._ints_in.discard(other)
+        other._ints_by.discard(self)
         if self.client is not None:
             self.client.destroy_entity(other)
         self.on_leave_aoi(other)
+
+    def _interest_record(self) -> list["Entity"]:
+        """The entities this one's interest record holds, by slot: the
+        eager set for a type with AOI hooks, the space's watch index for a
+        client with default hooks, nothing for a plain entity (its record
+        is the calculator's packed words; see neighbors)."""
+        if not self._default_hooks:
+            return sorted(self._ints_in, key=_SLOT)
+        sp = self.space
+        if self.client is not None and self.aoi_slot >= 0 and sp is not None:
+            return sp._watched_by(self.aoi_slot)
+        return []
+
+    @property
+    def interested_in(self) -> set["Entity"]:
+        """The entities this one is interested in, as its record holds
+        them (a snapshot; empty for a plain entity -- see neighbors)."""
+        return set(self._interest_record())
+
+    @property
+    def interested_by(self) -> set["Entity"]:
+        """The observers interested in this one that keep a record of it:
+        those with AOI hooks (eager) and those with a client (the watch
+        index).  A snapshot; plain observers are left out (observers)."""
+        out = set(self._ints_by)
+        sp = self.space
+        if self.aoi_slot >= 0 and sp is not None:
+            out.update(sp._slot_np[sp._watching(self.aoi_slot)].tolist())
+        return out
+
+    @property
+    def _watcher_clients(self) -> int:
+        """How many clients watch this entity: its slot's ``watchers``
+        column (the watch index's pairs that target it), or the clients of
+        its eager observers while it holds no slot."""
+        sp = self.space
+        if self.aoi_slot >= 0 and sp is not None:
+            sp._settle_watch()
+            return int(sp._cols.watchers[self.aoi_slot])
+        return sum(1 for o in self._ints_by if o.client is not None)
+
+    def _watching_clients(self) -> list[GameClient]:
+        """The clients of the observers that keep a record of this entity
+        (its attr deltas' and client calls' audience)."""
+        out = [o.client for o in self._ints_by if o.client is not None]
+        sp = self.space
+        if self.aoi_slot >= 0 and sp is not None:
+            out += sp._cols.gclient[sp._watching(self.aoi_slot)].tolist()
+        return out
 
     def neighbors(self) -> Iterable["Entity"]:
         """Entities this one is currently interested in (as of the last AOI
@@ -375,7 +431,8 @@ class Entity:
         answer from the calculator's packed interest words on demand; their
         ``interested_in``/``interested_by`` sets are intentionally EMPTY
         (event replay for them is a vectorized no-op).  Entities with a
-        client or overridden hooks keep eagerly maintained sets."""
+        client or overridden hooks answer from their record
+        (``interested_in``)."""
         if self._plain_aoi and self.aoi_slot >= 0 and self.space is not None:
             return self.space.derive_interests(self.aoi_slot)
         return self.interested_in
@@ -387,77 +444,54 @@ class Entity:
             return self.space.derive_observers(self.aoi_slot)
         return self.interested_by
 
-    def _materialize_interests(self):
-        """Promote lazily tracked interests into the eager sets -- called
-        when a plain entity stops being plain (gains a client): the client
-        needs create_entity ops and watcher counts for every current
-        neighbor, so the packed state must surface."""
-        if self.aoi_slot < 0 or self.space is None:
-            return
-        for other in self.space.derive_interests(self.aoi_slot):
-            self.interested_in.add(other)
-            other.interested_by.add(self)
-
-    def _dematerialize_interests(self):
-        """Inverse of _materialize_interests: the entity became plain again
-        (lost its client); its eager sets would go stale because future
-        events take the vectorized fast path, so drop them back into the
-        packed-only representation."""
-        if self.interested_in:
-            for other in self.interested_in:
-                other.interested_by.discard(self)
-            self.interested_in.clear()
-
     # -- client binding ----------------------------------------------------
     def drop_client_ref(self):
         """Detach the client WITHOUT emitting client ops -- the connection is
         already gone (peer disconnect, duplicate-entity teardown).  Keeps the
-        _watcher_clients bookkeeping consistent, which raw ``e.client = None``
-        assignments would silently corrupt."""
+        watch index and its watcher counts consistent, which raw
+        ``e.client = None`` assignments would silently corrupt."""
         if self.client is None:
             return
-        for other in self.interested_in:
-            other._watcher_clients -= 1
         self.client = None
         self._touch_client()
         self._recompute_plain()
-        if self._plain_aoi:
-            self._dematerialize_interests()
 
     def set_client(self, client: GameClient | None):
         was_plain = self._plain_aoi
         old = self.client
+        # a client with default hooks: the watch index is the whole record,
+        # and unbinding drops it -- read it first, for the new client too
+        seen = self._interest_record()
         if old is not None:
             old.destroy_entity(self)
-            for other in self.interested_in:
+            for other in seen:
                 old.destroy_entity(other)
-                other._watcher_clients -= 1
             self.client = None
             self._touch_client()
             self.on_client_disconnected()
         if client is not None:
             if was_plain:
                 # surface the packed interest state: the new client needs a
-                # create op and a watcher count per current neighbor
-                self._materialize_interests()
-            for other in self.interested_in:
-                other._watcher_clients += 1
+                # create op and a watch pair per current neighbor
+                seen = sorted(self.space.derive_interests(self.aoi_slot)
+                              if self.aoi_slot >= 0 and self.space is not None
+                              else (), key=_SLOT)
+            elif not self._default_hooks:
+                seen = self._interest_record()
             # flush pending deltas to the old audiences first -- the
             # snapshots below already contain them (see _interest)
             self._flush_attr_deltas()
-            for other in self.interested_in:
+            for other in seen:
                 other._flush_attr_deltas()
             self.client = client
-            self._touch_client()
+            self._touch_client(seen)
             client.create_entity(self, is_player=True)
-            for other in self.interested_in:
+            for other in seen:
                 client.create_entity(other, is_player=False)
             self._recompute_plain()
             self.on_client_connected()
         else:
             self._recompute_plain()
-            if self._plain_aoi:
-                self._dematerialize_interests()
 
     def give_client_to(self, other: "Entity | str"):
         """Move client ownership to another entity -- local fast path, or
@@ -546,9 +580,8 @@ class Entity:
         """Own client + every interested neighbor's client
         (reference: CallAllClients, Entity.go:743-748)."""
         self.call_client(method, *args)
-        for other in self.interested_by:
-            if other.client is not None:
-                other.client.call_client(self.id, method, args)
+        for c in self._watching_clients():
+            c.call_client(self.id, method, args)
 
     # -- timers ------------------------------------------------------------
     def add_callback(self, delay: float, method: str, *args) -> int:

@@ -31,10 +31,6 @@ class EntityTypeDesc:
     use_aoi: bool
     aoi_distance: float
     rpc_descs: dict[str, RpcDesc]
-    # True when the type keeps the default (no-op) AOI hooks: event replay
-    # for clientless instances is then pure interest-set bookkeeping and
-    # rides the batched fast path (Space.dispatch_aoi_events)
-    plain_aoi_hooks: bool = True
 
 
 class EntityManager:
@@ -65,10 +61,6 @@ class EntityManager:
             use_aoi=bool(cls.use_aoi),
             aoi_distance=float(cls.aoi_distance),
             rpc_descs=collect_rpc_descs(cls),
-            plain_aoi_hooks=(
-                cls.on_enter_aoi is Entity.on_enter_aoi
-                and cls.on_leave_aoi is Entity.on_leave_aoi
-            ),
         )
         self.registry[type_name] = desc
         return desc
@@ -96,7 +88,6 @@ class EntityManager:
         e.manager = self
         e.desc = desc
         e._dirty_set = self.runtime._dirty_entities  # stable set object
-        e._plain_aoi = desc.plain_aoi_hooks
         if attrs:
             e.attrs.assign(attrs)
         e.on_init()

@@ -12,7 +12,9 @@ Space.go:188-261 enter/leave/move -> go-aoi callbacks):
       per-slot arrays (x, z, radius, active) incrementally -- O(1) each;
     * the runtime's tick calls ``submit_aoi`` then ``AOIEngine.flush`` then
       ``dispatch_aoi_events``, which replays enter/leave pairs (sorted,
-      deterministic) through Entity._interest/_uninterest.
+      deterministic): a client observer with default hooks as whole arrays
+      into its client's outbox and the watch index, an observer with AOI
+      hooks through Entity._interest/_uninterest.
 
 The nil space (reference: Space.go:127-140) is a kindless space with AOI
 disabled where entities live when not in a real space.
@@ -21,11 +23,15 @@ disabled where entities live when not in a real space.
 from __future__ import annotations
 
 import itertools
+from collections import deque
+from itertools import compress, repeat, starmap
+from operator import attrgetter, not_
 
 import numpy as np
 
+from .. import telemetry
 from .ecs import ColumnStore
-from .entity import SYNC_NEIGHBORS, SYNC_OWN, Entity
+from .entity import _SLOT, SYNC_NEIGHBORS, SYNC_OWN, Entity
 from .vector import Vector3
 
 SPACE_TYPE_NAME = "__space__"
@@ -33,11 +39,20 @@ _MIN_CAPACITY = 128
 # watch-index codes: target slot << _SHIFT | watching observer slot
 _SHIFT = 32
 _LOW = (1 << _SHIFT) - 1
+_OUTBOX = attrgetter("outbox")
+# the pairs event replay delivered, by path, counted once per batch
+_REPLAY_COLUMNS = telemetry.counter(
+    "aoi.replay_columns", "enter/leave pairs event replay delivered as "
+    "columns (client observers with default AOI hooks)")
+_REPLAY_HOOKED = telemetry.counter(
+    "aoi.replay_hooked", "enter/leave pairs event replay delivered through "
+    "per-pair AOI hooks")
 
 
 class _Replay:
-    """One event replay in progress (``Space._replay``): its pairs, their
-    entities, and how many were replayed / folded into the watch index."""
+    """One per-pair event replay in progress (``Space._replay``): its
+    pairs, their entities, and how many were replayed / folded into the
+    watch index."""
 
     __slots__ = ("pairs", "obs", "tgt", "enter", "done", "applied", "moved")
 
@@ -47,6 +62,15 @@ class _Replay:
         # an entity left this space during the replay, so a pair's
         # entities may no longer sit at the pair's slots
         self.moved = False
+
+
+def _fresh(v):
+    """A copy of a plain attrs snapshot that shares no dict or list."""
+    if isinstance(v, dict):
+        return {k: _fresh(x) for k, x in v.items()}
+    if isinstance(v, list):
+        return [_fresh(x) for x in v]
+    return v
 
 
 class Space(Entity):
@@ -88,11 +112,14 @@ class Space(Entity):
         # backends skip its extraction/fetch/decode entirely
         self._aoi_subscribed = True
         # the watch index: every (watching observer, target) slot pair with
-        # the observer in target.interested_by and observer.client set, as
-        # sorted int64 codes target << 32 | observer.  Event replay keeps
-        # it with whole-array operations (_replay); the sites that bind a
-        # client or free a slot keep it directly (_rewatch, leave_entity).
-        # collect_sync reads it: no per-record walk builds sync records
+        # the observer interested in the target and observer.client set,
+        # as sorted int64 codes target << 32 | observer, with the count of
+        # each target's pairs in the ``watchers`` column.  For an observer
+        # with default AOI hooks it is the only record of the pair.  Event
+        # replay keeps it with whole-array operations (_replay_columns,
+        # _settle_watch); the sites that bind a client or free a slot keep
+        # it directly (_rewatch, leave_entity).  collect_sync reads it: no
+        # per-record walk builds sync records
         self._watch = np.empty(0, np.int64)
         self._rp: _Replay | None = None
 
@@ -254,6 +281,11 @@ class Space(Entity):
             cols.vis[slot] = np.uint32(0xFFFFFFFF)
             cols.sync[slot] = 0
             cols.put_id("eid", slot, e.id)
+            cols.hooked[slot] = not e._default_hooks
+            cols.deltas[slot] = bool(e._attr_deltas)
+            cols.shown[slot] = bool(e.all_client_attrs)
+            cols.tname[slot] = e.type_name
+            cols.ident[slot] = e.id
             self._set_client_cols(slot, e.client)
             self._aoi_dirty = True
         if not is_restore:
@@ -285,14 +317,17 @@ class Space(Entity):
             e._yaw = float(cols.yaw[slot])
             # fold what a replay in progress did so far (it places later
             # pairs anew), then drop every watch pair of the slot: its
-            # interests are severed below
+            # interests are severed below, from what the pairs recorded --
+            # the clients watching it, and what its own client watches
             self._settle_watch()
             if self._rp is not None:
                 self._rp.moved = True
+            watchers = cols.gclient[self._watching(slot)].tolist()
+            seen = e._interest_record()
             w = self._watch
             if len(w):
-                self._watch = w[((w >> _SHIFT) != slot)
-                                & ((w & _LOW) != slot)]
+                self._watch_keep(((w >> _SHIFT) != slot)
+                                 & ((w & _LOW) != slot))
             cols.clear_slot(slot)
             self._slot_entity[slot] = None
             self._slot_np[slot] = None
@@ -305,9 +340,18 @@ class Space(Entity):
             self._runtime().aoi.clear_entity(self._aoi_handle, slot)
             # departure events must fire this tick; sever interests now so
             # callbacks and client destroys are immediate and deterministic
-            for other in list(e.interested_in):
-                e._uninterest(other)
-            for other in list(e.interested_by):
+            # (by slot).  Hooks run per pair; a client with default hooks
+            # gets its destroy ops alone
+            if e._default_hooks:
+                if e.client is not None:
+                    for other in seen:
+                        e.client.destroy_entity(other)
+            else:
+                for other in seen:
+                    e._uninterest(other)
+            for c in watchers:
+                c.destroy_entity(e)
+            for other in sorted(e._ints_by, key=_SLOT):
                 other._uninterest(e)
         self.entities.discard(e)
         e.space = None
@@ -337,20 +381,22 @@ class Space(Entity):
             cols.yaw[slots] = yaws
         self._aoi_dirty = True
         se = self._slot_np
-        # sync bookkeeping (client-driven entities get no owner echo --
-        # same rule as set_position: correcting the owner fights
-        # client-side prediction; server-driven ones do).  Inlined, not a
-        # helper: a per-entity call costs ~5 ms at 64k on the
-        # device-cadence path.
-        for s in slots.tolist():
+        # sync bookkeeping for the slots some client watches or owns
+        # (client-driven entities get no owner echo -- same rule as
+        # set_position: correcting the owner fights client-side
+        # prediction; server-driven ones do).  The columns pick the slots;
+        # the loop is inlined, not a helper: a per-entity call costs ~5 ms
+        # at 64k on the device-cadence path.
+        self._settle_watch()
+        seen = slots[(cols.watchers[slots] > 0) | cols.client[slots]]
+        for s in seen.tolist():
             e = se[s]
             if e is None:
                 continue
-            if e._watcher_clients > 0 or e.client is not None:
-                e._sync_flags |= 2 if e.client_syncing else 3
-                ds = e._dirty_set
-                if ds is not None:
-                    ds.add(e)
+            e._sync_flags |= 2 if e.client_syncing else 3
+            ds = e._dirty_set
+            if ds is not None:
+                ds.add(e)
 
     def sync_entities_from_client(self, slots, xs, ys, zs, yaws):
         """Batched client-driven position/yaw sync: the gate->game sync
@@ -447,39 +493,133 @@ class Space(Entity):
                 cols.y[tgt], cols.z[tgt], cols.yaw[tgt])
 
     def dispatch_aoi_events(self):
-        """Replay batched enter/leave pairs through entity interest hooks.
+        """Replay batched enter/leave pairs to their observers.
 
-        Fast path: a pair whose OBSERVER has no client and default AOI hooks
-        (``_plain_aoi``) is pure interest-set bookkeeping the calculator's
-        packed words already hold -- dropped with one vectorized mask.
-        Observers with a client or overridden hooks take the full
-        ``_interest``/``_uninterest`` path (client create/destroy ops,
-        watcher counts, user callbacks), and the watch index follows the
-        pairs as whole arrays (``_replay``).  Slot->entity resolution
-        fancy-indexes the object-array mirror: one C pass per event batch
-        instead of two list lookups per pair."""
+        A pair whose OBSERVER has no client and default AOI hooks
+        (``_plain_aoi``) is pure interest bookkeeping the calculator's
+        packed words already hold -- dropped with one vectorized mask.  The
+        rest split by observer, on the slot's ``hooked`` column: a client
+        observer with default hooks is replayed as whole arrays
+        (``_replay_columns``: create/destroy ops, the watch index), an
+        observer whose type overrides the hooks through
+        ``_interest``/``_uninterest`` per pair (``_replay``).  Leaves go
+        first -- a slot reused within one tick (leave+enter) must destroy
+        before re-creating on clients -- and within each batch the columns
+        go first: they run no user code."""
         if self._aoi_handle is None:
             return
         enter, leave = self._runtime().aoi.take_events(self._aoi_handle)
-        nonplain = self._nonplain
-        # leaves first: a slot reused within one tick (leave+enter) must
-        # destroy before re-creating on clients
-        if len(leave):
-            self._replay(leave[nonplain[leave[:, 0]]], False)
-        if len(enter):
-            self._replay(enter[nonplain[enter[:, 0]]], True)
+        cols = self._cols
+        nonplain, hooked = cols.nonplain, cols.hooked
+        batches = []
+        for pairs, is_enter in ((leave, False), (enter, True)):
+            if len(pairs):
+                pairs = pairs[nonplain[pairs[:, 0]]]
+                h = hooked[pairs[:, 0]]
+                batches.append((pairs[~h], pairs[h], is_enter))
+        for by_cols, by_hooks, is_enter in batches:
+            if len(by_cols):
+                _REPLAY_COLUMNS.inc(self._replay_columns(by_cols, is_enter))
+            if len(by_hooks):
+                _REPLAY_HOOKED.inc(self._replay(by_hooks, is_enter))
 
-    def _replay(self, pairs: np.ndarray, enter: bool):
+    def _replay_columns(self, pairs: np.ndarray, enter: bool) -> int:
+        """Replay (observer, target) slot pairs of client observers with
+        default hooks as whole arrays; returns how many were live.  Each
+        client's outbox gets the ops the per-pair path would push, in its
+        order: one create (``enter``) or destroy op per pair, built from
+        the target's slot columns, and a target with pending attr deltas
+        flushes them to its audience from before the batch where its first
+        pair stands.  Then the pairs fold into the watch index."""
+        cols = self._cols
+        o = pairs[:, 0].astype(np.int64)
+        t = pairs[:, 1].astype(np.int64)
+        live = cols.act[o] & cols.act[t] & cols.client[o]
+        if not live.all():
+            o, t = o[live], t[live]
+        n = len(o)
+        if not n:
+            return 0
+        # the op tuples come out of zip in C: ("create_entity", type_name,
+        # id, False, attrs, (x, y, z), yaw) with a fresh {} per op, or
+        # ("destroy_entity", type_name, id)
+        tn, ids = cols.tname[t].tolist(), cols.ident[t].tolist()
+        if enter:
+            ops = list(zip(
+                repeat("create_entity", n), tn, ids, repeat(False, n),
+                starmap(dict, repeat((), n)),
+                zip(cols.x[t].tolist(), cols.y[t].tolist(),
+                    cols.z[t].tolist()),
+                cols.yaw[t].tolist()))
+            if cols.shown[t].any():
+                self._attach_attrs(ops, t)
+            cuts = [0]
+            first = np.flatnonzero(cols.deltas[t])
+            if len(first):
+                # each pending target's first pair: its flush stands there
+                first = first[np.unique(t[first], return_index=True)[1]]
+                cuts = sorted({0, *first.tolist()})
+        else:
+            ops = list(zip(repeat("destroy_entity", n), tn, ids))
+            cuts = [0]
+        cuts.append(n)
+        se = self._slot_np
+        for a, b in zip(cuts, cuts[1:]):
+            if enter and cols.deltas[t[a]]:
+                se[t[a]]._flush_attr_deltas()
+            self._hand_out(o[a:b], ops[a:b])
+        codes = (t << _SHIFT) | o
+        if enter:
+            self._watch_add(codes)
+        else:
+            self._watch_remove(codes)
+        return n
+
+    def _attach_attrs(self, ops: list, t: np.ndarray):
+        """Give the create ops of targets whose type shows attrs to
+        neighbours (``shown``) the snapshot the per-pair path would take,
+        each op a copy of its own (no op shares a mutable object)."""
+        se = self._slot_np
+        snaps: dict = {}
+        for i in np.flatnonzero(self._cols.shown[t]).tolist():
+            s = int(t[i])
+            snap = snaps.get(s)
+            if snap is None:
+                snap = snaps[s] = se[s].client_visible_attrs(to_owner=False)
+            op = ops[i]
+            ops[i] = op[:4] + (_fresh(snap),) + op[5:]
+
+    def _hand_out(self, o: np.ndarray, ops: list):
+        """Extend each observer's client outbox with its ops, in order (a
+        stable sort groups them by observer)."""
+        if len(o) > 1 and (o[1:] < o[:-1]).any():
+            order = np.argsort(o, kind="stable")
+            o, ops = o[order], [ops[i] for i in order.tolist()]
+        starts = np.flatnonzero(np.r_[True, o[1:] != o[:-1]])
+        clients = self._cols.gclient[o[starts]].tolist()
+        boxes = list(map(_OUTBOX, clients))
+        # GameClient._push's rule: on_dirty on the first op after a drain
+        # (each observer has a client of its own, so no client repeats)
+        for c in compress(clients, map(not_, boxes)):
+            if c.on_dirty is not None:
+                c.on_dirty(c)
+        ends = starts[1:].tolist() + [len(o)]
+        deque(map(list.extend, boxes,
+                  map(ops.__getitem__, map(slice, starts.tolist(), ends))),
+              maxlen=0)
+
+    def _replay(self, pairs: np.ndarray, enter: bool) -> int:
         """Replay (observer, target) slot pairs of live slots through
         ``_interest`` (``enter``) or ``_uninterest``, then fold them into
-        the watch index as one array operation.  Hooks may change clients
-        and slots mid-replay: a client binding recomputes its observer's
-        pairs from the sets, which already hold the pairs replayed so far,
-        and a slot freed mid-replay first folds them (``_settle_watch``)."""
+        the watch index as one array operation; returns how many were
+        live.  Hooks may change clients and slots mid-replay: a client
+        binding recomputes its observer's pairs from its eager set, which
+        already holds the pairs replayed so far, and a slot freed
+        mid-replay first folds them (``_settle_watch``)."""
         act = self._cols.act
         pairs = pairs[act[pairs[:, 0]] & act[pairs[:, 1]]]
         if not len(pairs):
-            return
+            return 0
         se = self._slot_np
         obs, tgt = se[pairs[:, 0]], se[pairs[:, 1]]
         rp = self._rp = _Replay(pairs, obs, tgt, enter)
@@ -493,11 +633,12 @@ class Space(Entity):
         finally:
             self._settle_watch()
             self._rp = None
+        return len(pairs)
 
     # -- watch index -------------------------------------------------------
     def _settle_watch(self):
-        """Fold the pairs a replay in progress has replayed since the last
-        fold into the watch index (a no-op outside a replay)."""
+        """Fold the pairs a per-pair replay in progress has replayed since
+        the last fold into the watch index (a no-op outside one)."""
         rp = self._rp
         if rp is None or rp.done <= rp.applied:
             return
@@ -522,6 +663,14 @@ class Space(Entity):
         else:
             self._watch_remove(codes)
 
+    def _count_watchers(self, codes: np.ndarray, sign: int):
+        """Add ``sign`` to the ``watchers`` column of each sorted code's
+        target, once per code."""
+        t = codes >> _SHIFT
+        starts = np.flatnonzero(np.r_[True, t[1:] != t[:-1]])
+        self._cols.watchers[t[starts]] += sign * np.diff(
+            np.r_[starts, len(t)]).astype(np.int32)
+
     def _watch_add(self, codes: np.ndarray):
         if not len(codes):
             return
@@ -531,37 +680,61 @@ class Space(Entity):
         if len(w):
             new = w[np.minimum(pos, len(w) - 1)] != codes
             codes, pos = codes[new], pos[new]
-        self._watch = np.insert(w, pos, codes)
+        if len(codes):
+            self._count_watchers(codes, 1)
+            self._watch = np.insert(w, pos, codes)
 
     def _watch_remove(self, codes: np.ndarray):
         w = self._watch
         if not len(codes) or not len(w):
             return
+        codes = np.unique(codes)
         pos = np.searchsorted(w, codes)
         ok = pos < len(w)
-        pos = pos[ok]
-        pos = pos[w[pos] == codes[ok]]
+        pos = pos[ok][w[pos[ok]] == codes[ok]]
         if len(pos):
-            keep = np.ones(len(w), bool)
-            keep[pos] = False
+            self._count_watchers(w[pos], -1)
+            self._watch = np.delete(w, pos)
+
+    def _watch_keep(self, keep: np.ndarray):
+        """Keep the watch pairs under the mask ``keep``, counting the
+        others out of their targets' ``watchers``."""
+        w = self._watch
+        gone = w[~keep]
+        if len(gone):
+            self._count_watchers(gone, -1)
             self._watch = w[keep]
+
+    def _watching(self, slot: int) -> np.ndarray:
+        """Slots of the client observers with default hooks that watch
+        the slot (observers with AOI hooks keep eager records instead)."""
+        w = self._watch
+        lo, hi = np.searchsorted(w, (slot << _SHIFT, (slot + 1) << _SHIFT))
+        o = w[lo:hi] & _LOW
+        return o[~self._cols.hooked[o]]
+
+    def _watched_by(self, slot: int) -> list[Entity]:
+        """The entities the slot's client watches, by slot."""
+        w = self._watch
+        return self._slot_np[w[(w & _LOW) == slot] >> _SHIFT].tolist()
 
     def _set_client_cols(self, slot: int, client):
         cols = self._cols
         cols.client[slot] = client is not None
+        cols.gclient[slot] = client
         if client is not None:
             cols.put_id("cid", slot, client.client_id)
             cols.gate[slot] = client.gate_id
 
-    def _rewatch(self, e: Entity):
-        """Recompute the watch pairs whose observer is ``e`` (slotted here)
-        from its interest set and client binding."""
+    def _rewatch(self, e: Entity, targets=()):
+        """Set the watch pairs whose observer is ``e`` (slotted here) to
+        ``targets`` if it has a client, else to none."""
         slot = e.aoi_slot
         w = self._watch
         if len(w):
-            self._watch = w[(w & _LOW) != slot]
-        if e.client is not None and e.interested_in:
-            t = [x.aoi_slot for x in e.interested_in
+            self._watch_keep((w & _LOW) != slot)
+        if e.client is not None:
+            t = [x.aoi_slot for x in targets
                  if x.space is self and x.aoi_slot >= 0]
             self._watch_add((np.array(t, np.int64) << _SHIFT) | slot)
 
